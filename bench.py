@@ -3,9 +3,9 @@
 The harness logic lives in cuvite_tpu.workloads.bench (warm-up,
 compile-count==0 guard on the first timed run, best-of-N, budget
 handling, shared JSON schema); this shim keeps the historical
-`python bench.py` invocation and BENCH_* env knobs working for the
-driver and the TPU ladder.  Prints ONE JSON line on success; exits 3
-WITHOUT a JSON when the compile guard trips.
+`python bench.py` invocation and BENCH_* env knobs working.  It runs on
+JAX's default backend, and the record names the device.  Prints ONE JSON
+line on success; exits 3 WITHOUT a JSON when the compile guard trips.
 """
 
 import os

@@ -103,9 +103,8 @@ def device_coarsen_slab(src, dst, w, comm, real_mask, *, nv_pad: int,
     of the SAME ``(comm, real_mask)`` — the fused driver reuses the one
     it already ran for label composition instead of renumbering twice.
 
-    ``coalesce`` (static): the segmented-coalesce engine — 'pallas' /
-    'xla' (the dense dst-tile bin-accumulate,
-    kernels/seg_coalesce.py; no sorted slab copy) or 'sort' (the packed
+    ``coalesce`` (static): the segmented-coalesce engine — 'xla' (the
+    dense bin-accumulate, kernels/seg_coalesce.py; no sorted slab copy) or 'sort' (the packed
     sort fallback).  None resolves via
     ``seg_coalesce.coalesce_engine(nv_pad, accum_dtype)`` AT TRACE TIME
     — callers that want env toggles honored per call (the drivers do)
@@ -177,9 +176,8 @@ def batched_coarsen_slab(src, dst, w, comm, real_mask, dense_map, nc, *,
     """[B, ne_pad] lift of :func:`device_coarsen_slab` (precomputed
     per-row ``dense_map``/``nc`` required — the batched driver always
     has them from the label composition).  ``coalesce`` must be an
-    EXPLICIT engine and not ``'pallas'``: the Pallas grid does not lift
-    over a batch axis; the XLA twin, the packed sort, and the msd
-    two-pass sort all do.  (Not ``'hash'`` either: its per-row
+    EXPLICIT engine that lifts over a batch axis: the XLA dense engine,
+    the packed sort, or the msd two-pass sort.  (Not ``'hash'``: its per-row
     ``lax.cond`` retry would execute BOTH branches under vmap — the
     batched policy routes hash to 'msd' instead,
     louvain/batched.py::_batched_coalesce_engine.)"""

@@ -24,47 +24,6 @@ DCN_AXIS = "dcn"
 ICI_AXIS = "ici"
 
 
-def shard_map(f=None, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` across the jax versions this repo meets.
-
-    jax >= 0.6 ships the top-level API with the replication check named
-    ``check_vma``; 0.4.x only has ``jax.experimental.shard_map.shard_map``
-    with the same semantics under ``check_rep``.  Every SPMD factory
-    (louvain/step.py, louvain/bucketed.py) routes through this wrapper so
-    the engines run on both — the image's TPU toolchain pins one version,
-    CI containers another.
-
-    ``check_vma`` defaults to True to MATCH jax's own default (losing
-    the replication check silently would let a dropped psum ship
-    per-shard-divergent "replicated" outputs); the engine factories all
-    opt out explicitly, as they did against the raw API.
-
-    Like the new API, callable with or without ``f``: omitting it returns
-    a decorator (``@shard_map(mesh=..., in_specs=..., out_specs=...)``).
-    """
-    if f is None:
-        return lambda fn: shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                    out_specs=out_specs,
-                                    check_vma=check_vma)
-    if hasattr(jax, "shard_map"):
-        impl = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as impl
-    # The check kwarg is detected from the signature, not from which
-    # module served the function: releases between the top-level export
-    # and the check_rep->check_vma rename ship jax.shard_map with the
-    # OLD kwarg, so keying the name on attribute presence alone would
-    # TypeError exactly in that window.
-    import inspect
-
-    try:
-        has_vma = "check_vma" in inspect.signature(impl).parameters
-    except (TypeError, ValueError):  # builtins/odd wrappers: assume new
-        has_vma = True
-    kw = {"check_vma" if has_vma else "check_rep": check_vma}
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def make_mesh(n_devices: int | None = None, devices=None) -> Mesh:
     if devices is None:
         devices = jax.devices()

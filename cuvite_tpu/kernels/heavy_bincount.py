@@ -6,16 +6,20 @@ bincounts neighbor weights into a 20M-entry dense per-block scratch
 indexed by dense community id (distGetMaxIndex_large_new,
 /root/reference/louvain_cuda.cu:878-1022).  An O(nv) dense scratch cannot
 live in VMEM (~16 MB on v5e), so this kernel tiles the COMMUNITY RANGE
-(tools/heavy_kernel_design.md): for each tile [t*C, (t+1)*C) it one-hot
-matmuls the row's weights against `eq(c, cand)` — duplicate aggregation
-IS the bincount — and carries a running (best_gain, best_c) across tiles.
+(tools/heavy_kernel_design.md): for each tile [t*C, (t+1)*C) it
+accumulates the row's weights into the tile's [C] bins by comparing
+each neighbor community with the tile's candidates — duplicate
+aggregation IS the bincount — and carries a running (best_gain, best_c)
+across tiles.
 
-Layout: transposed [D, H] rows (H = heavy vertices, D = max heavy degree,
-rows padded with c = pad id >= n_tiles*C and w = 0), one vertex per grid
-row.  The neighbor-community axis is reduced in Dc-sized chunks inside a
-fori_loop so VMEM holds only [Dc, C] one-hot blocks; `comm_deg` (the ay
-gather of the narrow kernel) arrives as a contiguous [1, C] block per
-community tile — a community-RANGE tile needs no gather at all.
+Layout: transposed [D, H] rows (H = heavy vertices, D = max heavy
+degree, rows padded with c = pad id >= n_tiles*C and w = 0).  The grid
+is (hub tile, community tile, neighbor chunk): 128 hubs ride the lanes,
+each step reads a lane-aligned [Dc, 128] block and walks its rows
+through the refs (``pl.ds``), accumulating a [C, 128] bincount in VMEM
+scratch across chunks; `comm_deg` (the ay gather of the narrow kernel)
+arrives as a contiguous [1, C] block per community tile — a
+community-RANGE tile needs no gather at all.
 
 Tie-break matches the narrow kernel (`row_argmax.py`) and the reference
 (`louvain.cpp:2230-2238`): max gain, ties -> smaller community id.  Tiles
@@ -23,18 +27,16 @@ ascend in community id, so a strict `>` merge keeps the earlier (smaller)
 id on cross-tile ties, and the in-tile rule picks the smallest candidate
 among equal gains.
 
-Status (ISSUE 8): PROMOTED from interpret-only/default-off.  The
-single-shard bucketed/pallas engines route the heavy residual through
-this kernel by default on the TPU backend (``heavy_kernel_enabled``;
-CUVITE_HEAVY_KERNEL=0 is the kill switch, =1 forces interpret mode on
-other backends — how tier-1 pins the compiled-path parity on CPU), with
-the per-phase [D, H] row layout built by ``build_heavy_layout`` and the
-XLA sorted path kept as the degrade-with-coverage fallback when the
-layout exceeds its element budget (CUVITE_HEAVY_ELEMS), when the
-exchange is sparse (the kernel has no attached-size channel), or on a
-mesh (the layout is single-shard).  Eliminating the per-iteration heavy
-sort is the move-phase half of killing the sort tax; the coalesce half
-is kernels/seg_coalesce.py.
+Status (PR 21): OPT-IN (CUVITE_HEAVY_KERNEL=1; interpret mode off the
+TPU, which is how tier-1 pins its parity with the sorted path).  It
+compiles for the v5e, but its cost grows with D x nv_ceil per 128-hub
+tile: on the chip, at the golden graph's phase-0 hub geometry (D =
+10240, 128 hubs, nv_ceil = 2^23) one call took 15.5 s against 23.3 ms
+for the sorted XLA path on the same rows (tools/heavy_ab.py; PERF.md).
+The sorted path is the default on every backend.  The [D, H] layout is
+built per phase by ``build_heavy_layout``; layouts over its element
+budget (CUVITE_HEAVY_ELEMS), the sparse exchange and meshes keep the
+sorted path.
 """
 
 from __future__ import annotations
@@ -62,19 +64,14 @@ DEFAULT_MAX_LAYOUT_ELEMS = 1 << 24
 
 
 def heavy_kernel_enabled() -> bool:
-    """Default-on policy for the heavy (> 8192 neighbors) degree class
-    (ISSUE 8 promotion): the community-range-tile kernel replaces the
-    per-iteration heavy sort on the TPU backend.  CUVITE_HEAVY_KERNEL=0
-    retains the historical sorted path (the kill switch / A/B lever);
-    =1 forces the kernel in interpret mode on other backends — tier-1
-    runs the full driver this way to pin parity without a chip.  Read
-    per PhaseRunner construction, not at import."""
+    """Opt-in policy for the heavy (> 8192 neighbors) degree class:
+    CUVITE_HEAVY_KERNEL=1 routes the heavy residual through the
+    community-range-tile kernel (interpret mode off the TPU — how tier-1
+    runs the full driver to pin parity).  Off by default: the sorted XLA
+    path is 664x faster per call on the chip at real hub geometry (module
+    docstring).  Read per PhaseRunner construction, not at import."""
     v = os.environ.get("CUVITE_HEAVY_KERNEL", "").strip().lower()
-    if v in ("0", "false", "off"):
-        return False
-    if v in ("1", "true", "on"):
-        return True
-    return jax.default_backend() == "tpu"
+    return v in ("1", "true", "on")
 
 
 def _layout_budget() -> int:
@@ -136,61 +133,76 @@ def build_heavy_layout(heavy_src, heavy_dst, heavy_w, *, nv_local: int,
 
 
 def _kernel(const_ref, cT_ref, wT_ref, ay_ref, curr_ref, vdeg_ref, sl_ref,
-            ax_ref, bc_ref, bg_ref, c0_ref, *, c_tile: int, d_chunk: int):
+            ax_ref, bc_ref, bg_ref, c0_ref, wagg_ref, cnt_ref, *,
+            c_tile: int, d_chunk: int):
+    # Grid (hub tile r, community tile t, neighbor chunk k), k fastest.
+    # Blocks: cT/wT [Dc, L] (L = 128 hubs on the lanes), ay [1, C]
+    # (lane-dense; transposed in-kernel to the [C, 1] candidate column —
+    # an [nv_ceil, 1] operand would pad every community to 128 lanes in
+    # HBM), per-hub vectors and outputs [1, L].  The outputs stay resident across (t, k) as the running
+    # (best_gain, best_c) and counter0 accumulators; the [C, L] bincount
+    # of one community tile accumulates in VMEM scratch across k.
     t = pl.program_id(1)
-    c = cT_ref[:]          # [D, 1] int32 neighbor communities (one vertex)
-    w = wT_ref[:]          # [D, 1] f32 edge weights (0 on padding)
-    ay = ay_ref[:]         # [1, C] f32 comm_deg of this community tile
-    curr = curr_ref[0, 0]  # scalars of the vertex
-    vdeg = vdeg_ref[0, 0]
-    sl = sl_ref[0, 0]
-    ax = ax_ref[0, 0]
-    const = const_ref[0]
-    wdt = w.dtype
+    k = pl.program_id(2)
+    wdt = wagg_ref.dtype
+    idt = bc_ref.dtype
+    curr = curr_ref[...]                        # [1, L]
+    big = jnp.iinfo(idt).max
+
+    @pl.when((t == 0) & (k == 0))
+    def _init():
+        c0_ref[...] = jnp.zeros_like(c0_ref)
+        bg_ref[...] = jnp.full(bg_ref.shape, -jnp.inf, dtype=wdt)
+        bc_ref[...] = jnp.full(bc_ref.shape, big, dtype=idt)
 
     @pl.when(t == 0)
-    def _init():
+    def _counter0():
         # counter0 (weight into the current community, incl. self edges)
-        # is row-local — one elementwise pass, no tiles involved.
-        c0_ref[0, 0] = jnp.sum(jnp.where(c == curr, w, 0.0))
-        bg_ref[0, 0] = jnp.asarray(-jnp.inf, dtype=wdt)
-        bc_ref[0, 0] = jnp.asarray(jnp.iinfo(cT_ref.dtype).max,
-                                   dtype=cT_ref.dtype)
+        # is row-local: summed once, on the first community tile.
+        c0_ref[...] += jnp.sum(
+            jnp.where(cT_ref[...] == curr, wT_ref[...], 0.0), axis=0,
+            keepdims=True)
 
-    eix = c0_ref[0, 0] - sl
-    cand = t * c_tile + jax.lax.broadcasted_iota(jnp.int32, (1, c_tile), 1)
+    @pl.when(k == 0)
+    def _zero():
+        wagg_ref[...] = jnp.zeros_like(wagg_ref)
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    def chunk(k, carry):
-        wagg, cnt = carry
-        ck = jax.lax.dynamic_slice_in_dim(c, k * d_chunk, d_chunk, axis=0)
-        wk = jax.lax.dynamic_slice_in_dim(w, k * d_chunk, d_chunk, axis=0)
-        eq = (ck == cand).astype(wdt)            # [Dc, C] one-hot
-        wagg = wagg + jax.lax.dot_general(        # [1, C] bincount slice
-            wk, eq, (((0,), (0,)), ((), ())),
-            preferred_element_type=wdt)
-        # Presence COUNT, not weight: zero-weight edges are candidates
-        # exactly as in the XLA paths (bucketed.py `_row_argmax` — 'No
-        # w>0 filter').  Padding slots carry c >= n_tiles*c_tile so eq
-        # never matches them.
-        cnt = cnt + jnp.sum(eq, axis=0, keepdims=True)
-        return wagg, cnt
+    cand = t * c_tile + jax.lax.broadcasted_iota(jnp.int32, (c_tile, 1), 0)
 
-    n_chunks = cT_ref.shape[0] // d_chunk
-    zero = jnp.zeros((1, c_tile), dtype=wdt)
-    wagg, cnt = jax.lax.fori_loop(0, n_chunks, chunk, (zero, zero))
+    def row(d, carry):
+        # One neighbor slot of every hub in the tile: its community row
+        # [1, L] against the tile's candidates [C, 1].  Presence COUNT,
+        # not weight: zero-weight edges are candidates exactly as in the
+        # XLA paths (bucketed.py `_row_argmax` — 'No w>0 filter').
+        # Padding slots carry c >= n_tiles*c_tile, so eq never matches.
+        eq = cT_ref[pl.ds(d, 1), :] == cand       # [C, L]
+        wagg_ref[...] += jnp.where(eq, wT_ref[pl.ds(d, 1), :], 0.0)
+        cnt_ref[...] += eq.astype(wdt)
+        return carry
 
-    valid = (cnt > 0) & (cand != curr)
-    # Operand order matches the XLA paths exactly (bucketed.py:546/633):
-    # 2*(wagg-eix) - ((2*vdeg)*(ay-ax))*const.
-    gain = 2.0 * (wagg - eix) - 2.0 * vdeg * (ay - ax) * const
-    gain = jnp.where(valid, gain, -jnp.inf)
-    tile_bg = jnp.max(gain)
-    big = jnp.asarray(jnp.iinfo(cT_ref.dtype).max, dtype=cand.dtype)
-    tile_bc = jnp.min(jnp.where(gain == tile_bg, cand, big))
-    better = tile_bg > bg_ref[0, 0]               # strict: earlier tile
-    bc_ref[0, 0] = jnp.where(
-        better, tile_bc.astype(cT_ref.dtype), bc_ref[0, 0])
-    bg_ref[0, 0] = jnp.where(better, tile_bg, bg_ref[0, 0])
+    jax.lax.fori_loop(0, d_chunk, row, 0)
+
+    @pl.when(k == pl.num_programs(2) - 1)
+    def _argmax():
+        eix = c0_ref[...] - sl_ref[...]
+        # [1, C] -> [C, 1] via an (8, C) -> (C, 8) transpose, the aligned
+        # shape Mosaic transposes natively.
+        ay = jnp.transpose(jnp.broadcast_to(ay_ref[...], (8, c_tile)))
+        ay = ay[:, :1]
+        valid = (cnt_ref[...] > 0) & (cand != curr)
+        # Operand order matches the XLA paths exactly (bucketed.py
+        # `_row_argmax`): 2*(wagg-eix) - ((2*vdeg)*(ay-ax))*const.
+        gain = (2.0 * (wagg_ref[...] - eix)
+                - 2.0 * vdeg_ref[...] * (ay - ax_ref[...])
+                * const_ref[0])
+        gain = jnp.where(valid, gain, -jnp.inf)
+        tile_bg = jnp.max(gain, axis=0, keepdims=True)          # [1, L]
+        tile_bc = jnp.min(jnp.where(gain == tile_bg, cand, big), axis=0,
+                          keepdims=True).astype(idt)
+        better = tile_bg > bg_ref[...]            # strict: earlier tile
+        bc_ref[...] = jnp.where(better, tile_bc, bc_ref[...])
+        bg_ref[...] = jnp.where(better, tile_bg, bg_ref[...])
 
 
 @functools.partial(
@@ -216,18 +228,25 @@ def heavy_argmax_pallas(cT, wT, comm_deg, curr, vdeg, sl, ax, constant, *,
     (nv_ceil,) = comm_deg.shape
     assert D % d_chunk == 0, (D, d_chunk)
     assert nv_ceil % c_tile == 0, (nv_ceil, c_tile)
-    grid = (H, nv_ceil // c_tile)
+    # Hubs ride the 128 lanes: pad H to a lane multiple with all-padding
+    # columns (c never a candidate, w = 0), dropped from the outputs.
+    hp = -(-H // LANE) * LANE
+    cT = jnp.pad(cT, ((0, 0), (0, hp - H)), constant_values=nv_ceil)
+    wT = jnp.pad(wT, ((0, 0), (0, hp - H)))
+    vecs = [jnp.pad(v, (0, hp - H)).reshape(1, hp)
+            for v in (curr, vdeg, sl, ax)]
+    grid = (hp // LANE, nv_ceil // c_tile, D // d_chunk)
 
-    row_spec = pl.BlockSpec((D, 1), lambda r, t: (0, r),
+    row_spec = pl.BlockSpec((d_chunk, LANE), lambda r, t, k: (k, r),
                             memory_space=pltpu.VMEM)
-    ay_spec = pl.BlockSpec((1, c_tile), lambda r, t: (0, t),
+    ay_spec = pl.BlockSpec((1, c_tile), lambda r, t, k: (0, t),
                            memory_space=pltpu.VMEM)
-    scalar_spec = pl.BlockSpec((1, 1), lambda r, t: (0, r),
-                               memory_space=pltpu.VMEM)
+    vec_spec = pl.BlockSpec((1, LANE), lambda r, t, k: (0, r),
+                            memory_space=pltpu.VMEM)
     out_shapes = (
-        jax.ShapeDtypeStruct((1, H), cT.dtype),
-        jax.ShapeDtypeStruct((1, H), wT.dtype),
-        jax.ShapeDtypeStruct((1, H), wT.dtype),
+        jax.ShapeDtypeStruct((1, hp), cT.dtype),
+        jax.ShapeDtypeStruct((1, hp), wT.dtype),
+        jax.ShapeDtypeStruct((1, hp), wT.dtype),
     )
     kernel = functools.partial(_kernel, c_tile=c_tile, d_chunk=d_chunk)
     bc, bg, c0 = pl.pallas_call(
@@ -236,15 +255,15 @@ def heavy_argmax_pallas(cT, wT, comm_deg, curr, vdeg, sl, ax, constant, *,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             row_spec, row_spec, ay_spec,
-            scalar_spec, scalar_spec, scalar_spec, scalar_spec,
+            vec_spec, vec_spec, vec_spec, vec_spec,
         ],
-        out_specs=(scalar_spec, scalar_spec, scalar_spec),
+        out_specs=(vec_spec, vec_spec, vec_spec),
         out_shape=out_shapes,
+        scratch_shapes=[pltpu.VMEM((c_tile, LANE), wT.dtype),
+                        pltpu.VMEM((c_tile, LANE), wT.dtype)],
         interpret=interpret,
     )(
         jnp.reshape(constant, (1,)).astype(wT.dtype),
-        cT, wT, comm_deg.reshape(1, nv_ceil),
-        curr.reshape(1, H), vdeg.reshape(1, H), sl.reshape(1, H),
-        ax.reshape(1, H),
+        cT, wT, comm_deg.reshape(1, nv_ceil), *vecs,
     )
-    return bc.reshape(H), bg.reshape(H), c0.reshape(H)
+    return bc[0, :H], bg[0, :H], c0[0, :H]

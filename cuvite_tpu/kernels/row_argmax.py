@@ -55,6 +55,10 @@ UNROLL_MAX_WIDTH = 32
 # outputs), used to shrink the row tile for wide classes: the f32/int32
 # blocks of D x tile_n must fit comfortably under ~16 MB v5e VMEM.
 VMEM_BUDGET_BYTES = 6 << 20
+# Scoped VMEM the compiler may give one kernel.  Mosaic's 16 MiB default
+# is too tight for the widest class (D = 2048 at the 128-lane minimum
+# tile needs ~17 MiB with its [D, T] loop temporaries); v5e has 128 MiB.
+VMEM_LIMIT_BYTES = 64 << 20
 
 
 def _kernel(const_ref, cT_ref, wT_ref, ayT_ref, curr_ref, vdeg_ref, sl_ref,
@@ -124,34 +128,29 @@ def _kernel(const_ref, cT_ref, wT_ref, ayT_ref, curr_ref, vdeg_ref, sl_ref,
             bc, bg, bs = step_j(cj, ay[j : j + 1, :], szj, eq, dup_j,
                                 bc, bg, bs)
     else:
-        # Wide classes: loop over candidate slots with dynamic sublane
-        # slices (compile time O(1) in width).  The duplicate-leader test
-        # uses a row-index mask (rows k < j) on the full eq matrix.
+        # Wide classes: loop over candidate slots (compile time O(1) in
+        # width).  Slot j's row is read from the REFS (a dynamic sublane
+        # window, which Mosaic lowers) — a dynamic_slice of a loaded value
+        # has no TPU lowering.  The duplicate-leader test uses a row-index
+        # mask (rows k < j) on the full eq matrix.
         D, T = c.shape
         row_idx = jax.lax.broadcasted_iota(jnp.int32, (D, T), 0)
 
-        if with_size:
-            def body(j, carry):
-                bc, bg, bs = carry
-                cj = jax.lax.dynamic_slice_in_dim(c, j, 1, axis=0)
-                ayj = jax.lax.dynamic_slice_in_dim(ay, j, 1, axis=0)
-                szj = jax.lax.dynamic_slice_in_dim(sz, j, 1, axis=0)
-                eq = c == cj
-                dup_j = jnp.any(eq & (row_idx < j), axis=0, keepdims=True)
-                return step_j(cj, ayj, szj, eq, dup_j, bc, bg, bs)
+        def body(j, carry):
+            bc, bg, bs = carry
+            cj = cT_ref[pl.ds(j, 1), :]
+            szj = szT_ref[pl.ds(j, 1), :] if with_size else None
+            eq = c == cj
+            dup_j = jnp.any(eq & (row_idx < j), axis=0, keepdims=True)
+            return step_j(cj, ayT_ref[pl.ds(j, 1), :], szj, eq, dup_j,
+                          bc, bg, bs)
 
+        if with_size:
             bc, bg, bs = jax.lax.fori_loop(0, width, body, (bc0, bg0, bs0))
         else:
-            def body(j, carry):
-                bc, bg = carry
-                cj = jax.lax.dynamic_slice_in_dim(c, j, 1, axis=0)
-                ayj = jax.lax.dynamic_slice_in_dim(ay, j, 1, axis=0)
-                eq = c == cj
-                dup_j = jnp.any(eq & (row_idx < j), axis=0, keepdims=True)
-                bc, bg, _ = step_j(cj, ayj, None, eq, dup_j, bc, bg, None)
-                return bc, bg
-
-            bc, bg = jax.lax.fori_loop(0, width, body, (bc0, bg0))
+            bc, bg = jax.lax.fori_loop(
+                0, width, lambda j, cr: body(j, cr + (None,))[:2],
+                (bc0, bg0))
             bs = None
     bc_ref[:] = bc
     bg_ref[:] = bg
@@ -222,6 +221,8 @@ def row_argmax_pallas(cT, wT, ayT, curr, vdeg, sl, ax, constant, *,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*operands)
     if with_size:

@@ -144,7 +144,7 @@ def _bucketed_phase_body(buckets, heavy, self_loop, perm, src, dst, w,
     (identity start, on-device convergence check, the degree-bucketed
     dense row formulation of Naim et al., arXiv:1805.10904) — vmapped,
     so per-tenant labels stay bit-identical to a B=1 run.  Engine
-    degradations under vmap: no Pallas row-argmax flags and no promoted
+    degradations under vmap: no Pallas row-argmax flags and no opt-in
     heavy-kernel layout (their grids do not lift over a batch axis; the
     XLA paths they degrade to are bit-identical, the batched-coalesce
     precedent), and the heavy residual runs the sorted path on its
@@ -407,10 +407,8 @@ def _coarse_class(nv_pad: int, ne_pad: int) -> tuple:
 
 def _batched_coalesce_engine(nv_pad: int, adt: str) -> str:
     """The coalesce engine of a batched phase at one slab class: the
-    env-resolved per-graph policy, with 'pallas' downgraded to its
-    bit-identical XLA twin — the Pallas seg-coalesce grid does not lift
-    over vmap (kernels/seg_coalesce.py) — and 'hash' downgraded to
-    'msd': the hash engine's collision retry is a ``lax.cond`` whose
+    env-resolved per-graph policy, with 'hash' downgraded to 'msd': the
+    hash engine's collision retry is a ``lax.cond`` whose
     branches BOTH execute under vmap, so its fallback path would run
     for every row of every batch (coarsen/device.py).  One definition
     for the phase-0 class and the serving-coarse class, so the
@@ -418,7 +416,7 @@ def _batched_coalesce_engine(nv_pad: int, adt: str) -> str:
     from cuvite_tpu.kernels.seg_coalesce import coalesce_engine
 
     eng = coalesce_engine(nv_pad, "ds32" if adt == "ds32" else None)
-    return {"pallas": "xla", "hash": "msd"}.get(eng, eng)
+    return "msd" if eng == "hash" else eng
 
 
 @functools.partial(jax.jit, static_argnames=("cnv", "cne"))
@@ -506,8 +504,6 @@ def _get_batched_phase(mesh, nv_pad, accum_dtype, coalesce, max_iters,
     else:
         from jax.sharding import PartitionSpec as P
 
-        from cuvite_tpu.comm.mesh import shard_map
-
         b = P(BATCH_AXIS)
         # Row-independent SPMD: every batched operand/output splits on
         # the batch axis, the threshold scalar replicates, and the body
@@ -518,7 +514,7 @@ def _get_batched_phase(mesh, nv_pad, accum_dtype, coalesce, max_iters,
             in_specs = (bspec, (b, b, b)) + (b,) * 10 + (P(),)
         else:
             in_specs = (b,) * 8 + (P(),)
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=in_specs,
             out_specs=(b,) * 14,

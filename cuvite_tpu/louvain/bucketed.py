@@ -37,7 +37,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from cuvite_tpu.comm.mesh import shard_map
 from cuvite_tpu.ops import segment as seg
 
 # Width ladder: ~1.5-2x steps bound the padded-slot inflation (a row of
@@ -81,7 +80,7 @@ QUADRATIC_MAX_WIDTH = _env_int("CUVITE_QUAD_MAX", 32)
 # switches from an unrolled candidate loop to lax.fori_loop above
 # kernels.row_argmax.UNROLL_MAX_WIDTH and shrinks its row tile to honor
 # VMEM; 2048 keeps the [D, tile] blocks comfortably resident.  Knob for
-# on-chip A/B ladders.
+# on-chip A/B runs.
 PALLAS_MAX_WIDTH = _env_int("CUVITE_PALLAS_MAX", 2048)
 ROW_CHUNK = _env_int("CUVITE_ROW_CHUNK", 8192)  # rows/lax.map step (quad)
 # rows*width per lax.map step for the sorted dedup classes:
@@ -963,7 +962,7 @@ def bucketed_step(bucket_arrays, heavy_arrays, self_loop, comm, vdeg,
     hs, hd, hw = heavy_arrays
     use_heavy_kernel = heavy_kernel is not None
     if use_heavy_kernel:
-        # Promoted heavy path (ISSUE 8): ONE community-range-tile kernel
+        # Opt-in heavy path (ISSUE 8): ONE community-range-tile kernel
         # pass per iteration — no heavy sort, no per-iteration triples
         # gather.  Replicated/single-shard only: the kernel consumes the
         # dense comm_deg table (and the sparse singleton guard needs an
@@ -1182,7 +1181,7 @@ def make_sharded_class_step(mesh, axis_name: str, n_buckets: int,
         nshards, budget = 1, 0
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=out_specs,
@@ -1230,7 +1229,7 @@ def make_sharded_bucketed_mod(mesh, axis_name: str, n_buckets: int,
         out_specs = P()
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=out_specs,
@@ -1289,7 +1288,7 @@ def make_sharded_bucketed_step(mesh, axis_name: str, n_buckets: int,
         nshards, budget = 1, 0
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=out_specs,

@@ -93,6 +93,9 @@ class PhaseStats:
     num_vertices: int
     num_edges: int
     seconds: float
+    # Fraction of this phase's edges in Pallas-kernel classes (the
+    # runner's coverage accounting); None where no kernel engaged.
+    pallas_coverage: float | None = None
 
 
 @dataclasses.dataclass
@@ -437,7 +440,7 @@ def _bucketed_call(nv_total, sentinel, accum_dtype, pallas_flags=(),
                    pallas_interpret=False):
     def call(comm, extra):
         # The trailing heavy_kernel slot is None (sorted heavy path) or
-        # the (verts, dstT, wT) layout of the promoted heavy kernel —
+        # the (verts, dstT, wT) layout of the opt-in heavy kernel —
         # pytree structure, so each engagement state traces separately.
         buckets, heavy, self_loop, vdeg, constant, perm, hk = extra
         return bucketed_step(
@@ -862,8 +865,8 @@ class PhaseRunner:
                     np.asarray(sh.w), nv_local=dg.nv_pad, base=0,
                 )
                 use_pallas = engine == "pallas" and not class_sched
-                # Promoted heavy-class kernel policy (ISSUE 8), decided up
-                # front: it engages on the plain bucketed engine too, and a
+                # Opt-in heavy-class kernel policy, decided up front: it
+                # engages on the plain bucketed engine too, and a
                 # run that executes ANY Pallas kernel must carry coverage
                 # accounting (the engage-with-coverage convention).
                 from cuvite_tpu.kernels.heavy_bincount import (
@@ -921,13 +924,12 @@ class PhaseRunner:
                         verts_np.append(b.verts)
                 buckets = tuple(buckets)
                 flags = tuple(flags)
-                # Promoted heavy-class kernel (ISSUE 8): replace the
-                # per-iteration heavy SORT with the community-range-tile
-                # bincount kernel whenever the phase has a heavy residual,
-                # the policy says on (default: TPU backend;
-                # CUVITE_HEAVY_KERNEL=1 forces interpret mode — how tier-1
-                # pins parity on CPU) and the [D, H] layout fits its element
-                # budget.  Class-scheduled phases sweep per-class plans (the
+                # Opt-in heavy-class kernel: replace the per-iteration
+                # heavy SORT with the community-range-tile bincount kernel
+                # whenever the phase has a heavy residual, the policy says
+                # on (CUVITE_HEAVY_KERNEL=1; interpret mode off the TPU —
+                # how tier-1 pins parity on CPU) and the [D, H] layout fits
+                # its element budget.  Class-scheduled phases sweep per-class plans (the
                 # main plan never runs), so the layout would be dead weight.
                 hk_dev = None
                 if hk_wanted:
@@ -955,7 +957,7 @@ class PhaseRunner:
                 if want_cov:
                     n_heavy = int(deg_all.sum()) - sum(c[1] for c in cov)
                     if n_heavy:
-                        # width 0 = heavy class; kernelized when the promoted
+                        # width 0 = heavy class; kernelized when the opt-in
                         # heavy kernel engaged for this phase.
                         cov.append((0, n_heavy, hk_dev is not None))
                     # The low-coverage warning is a pallas-engine contract
@@ -1122,7 +1124,7 @@ class PhaseRunner:
         kernelized) with width 0 standing for the heavy class; shared by
         the single-shard and SPMD upload paths so the report means the
         same thing on any mesh.  ``warn=False``: the bucketed engine with
-        the promoted heavy kernel engaged reports coverage too (ISSUE 8 —
+        the opt-in heavy kernel engaged reports coverage too (ISSUE 8 —
         any run executing a Pallas kernel must carry the accounting), but
         its XLA classes are the engine, not a fallback to warn about."""
         total = max(sum(c[1] for c in cov), 1)
@@ -2198,6 +2200,7 @@ def louvain_phases(
         # Capture BEFORE the slabless branch drops the runner; gained is
         # stamped (and the event emitted) once it is known below.
         phase_conv = getattr(runner, "convergence", None)
+        phase_cov = getattr(runner, "pallas_coverage", None)
         tracer.event("exchange", mode=phase_exchange,
                      nshards=dg.nshards, budget=runner.budget,
                      plan=runner.xplan_stats)
@@ -2289,7 +2292,7 @@ def louvain_phases(
             phases.append(PhaseStats(
                 phase=phase, modularity=curr_mod, iterations=iters,
                 num_vertices=g_nv, num_edges=g_ne,
-                seconds=t2 - t1,
+                seconds=t2 - t1, pallas_coverage=phase_cov,
             ))
             if verbose:
                 print(f"Level {phase}, Modularity: {curr_mod:.6f}, "

@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from cuvite_tpu.comm.mesh import shard_map
 from cuvite_tpu.ops import segment as seg
 
 
@@ -144,7 +143,7 @@ def make_sharded_step(mesh: Mesh, axis_name: str, nv_total: int,
     ``axis_name``, modularity replicated."""
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(axis_name), P(axis_name),
                   P(axis_name), P()),

@@ -7,15 +7,22 @@ point has a bit-identical pure-numpy fallback in the rest of the package, so
 the library is an accelerator, never a requirement: ``available()`` gates
 every use.
 
-Build: ``make -C native`` at the repo root, or implicitly on first import
-(disable with CUVITE_NO_NATIVE=1).
+Build: implicitly on first use, from the committed source
+``native/cuvite_native.cpp`` (disable with CUVITE_NO_NATIVE=1).  The
+built file's name carries a hash of the source, the compiler command and
+the machine type, so a stale library, or one built for another host's
+CPU, is never loaded: a copied tree rebuilds instead.  A failed build
+warns and falls back to numpy.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
+import warnings
 
 import numpy as np
 
@@ -27,26 +34,46 @@ _LIB = None  # None = not tried; False = unavailable; else CDLL
 MIN_NATIVE_EDGES = 1 << 16
 
 
-def _repo_root() -> str:
-    return os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
+# Portable target: no -march=native, so the library runs on any host of
+# the machine type it was built for.
+CXXFLAGS = ("-O3", "-fopenmp", "-fPIC", "-shared", "-std=c++17", "-Wall")
+
+
+def _src_path() -> str:
+    return os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), "native", "cuvite_native.cpp")
+
+
+def _compiler() -> tuple:
+    return (os.environ.get("CXX", "g++"),) + CXXFLAGS
 
 
 def _so_path() -> str:
+    """The library built from the current source with the current
+    compiler command for this machine type."""
+    h = hashlib.sha256()
+    with open(_src_path(), "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_compiler()).encode())
+    h.update(platform.machine().encode())
     return os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "libcuvite_native.so")
+                        f"libcuvite_native-{h.hexdigest()[:16]}.so")
 
 
-def _try_build() -> bool:
-    src_dir = os.path.join(_repo_root(), "native")
-    if not os.path.isfile(os.path.join(src_dir, "cuvite_native.cpp")):
-        return False
+def _build(so: str) -> str | None:
+    """Compile the library to ``so``; returns None or the error text.
+    Written to a private temp name, then renamed: concurrent builders
+    (pytest-xdist workers) never load a half-written file."""
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
-        r = subprocess.run(["make", "-C", src_dir], capture_output=True,
-                           timeout=180)
-        return r.returncode == 0
-    except (OSError, subprocess.TimeoutExpired):
-        return False
+        r = subprocess.run([*_compiler(), "-o", tmp, _src_path()],
+                           capture_output=True, text=True, timeout=300)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return repr(e)
+    if r.returncode != 0:
+        return r.stderr[-2000:]
+    os.replace(tmp, so)
+    return None
 
 
 def _bind(lib: ctypes.CDLL) -> None:
@@ -107,29 +134,22 @@ def _load():
     global _LIB
     if _LIB is not None:
         return _LIB or None
-    if os.environ.get("CUVITE_NO_NATIVE"):
-        _LIB = False
+    _LIB = False
+    if os.environ.get("CUVITE_NO_NATIVE") or not os.path.isfile(
+            _src_path()):
         return None
     so = _so_path()
-    src = os.path.join(_repo_root(), "native", "cuvite_native.cpp")
-    stale = (not os.path.isfile(so)
-             or (os.path.isfile(src)
-                 and os.path.getmtime(src) > os.path.getmtime(so)))
-    if stale and not _try_build():
-        # Never load a stale library: its output may no longer match the
-        # current numpy fallbacks, silently breaking reproducibility.
-        _LIB = False
-        return None
-    try:
-        lib = ctypes.CDLL(so)
-        _bind(lib)
-        _LIB = lib
-    except (OSError, AttributeError):
-        # AttributeError: a library built from older sources (but with a
-        # newer mtime, e.g. preserved-time copies) lacking newly added
-        # symbols — fall back to numpy rather than crash ("accelerator,
-        # never a requirement").
-        _LIB = False
+    err = None if os.path.isfile(so) else _build(so)
+    if err is None:
+        try:
+            lib = ctypes.CDLL(so)
+            _bind(lib)
+            _LIB = lib
+        except OSError as e:
+            err = repr(e)
+    if err is not None:
+        warnings.warn(f"native library unavailable, using the numpy "
+                      f"fallbacks: {err}", stacklevel=3)
         return None
     return _LIB
 

@@ -259,7 +259,7 @@ def _runs_from_sorted(src_s, ckey_s, w_s, *, nv_pad, accum_dtype):
 
 
 def coalesced_runs(src, ckey, w, *, nv_pad, accum_dtype=None,
-                   engine="sort", interpret=None):
+                   engine="sort"):
     """Segmented coalesce of an edge slab by (src, ckey): one output row
     per distinct real (src, ckey) pair, rows in ascending (src, ckey)
     order COMPACTED into the slab prefix, duplicate weights summed.
@@ -269,7 +269,7 @@ def coalesced_runs(src, ckey, w, *, nv_pad, accum_dtype=None,
     padding rows carry src == nv_pad and w == 0 — but the contract is the
     COALESCED result, not a sorted copy, which frees the engine choice:
 
-    * ``engine='pallas'`` / ``'xla'`` — the dense dst-tile bin-accumulate
+    * ``engine='xla'`` — the dense bin-accumulate
       (cuvite_tpu/kernels/seg_coalesce.py): no sorted copy of the slab is
       ever materialized.  Static eligibility (nv_pad within the
       accumulator budget, no ds32) is the CALLER's job via
@@ -315,7 +315,7 @@ def coalesced_runs(src, ckey, w, *, nv_pad, accum_dtype=None,
             f"= {SLAB_NE_MAX}: the int32 run-id/compaction cumsums "
             "would overflow (wrong labels, not a crash) — shard the "
             "slab below the ceiling first")
-    if engine in ("pallas", "xla"):
+    if engine == "xla":
         # The dense accumulators sum in the weight dtype only: a caller
         # that requested ANY explicit accumulator (ds32 pairs or a wider
         # plain dtype) must take the sort path — silently narrowing the
@@ -327,8 +327,7 @@ def coalesced_runs(src, ckey, w, *, nv_pad, accum_dtype=None,
             "engines accumulate in the weight dtype only)"
         from cuvite_tpu.kernels.seg_coalesce import coalesce_slab
 
-        return coalesce_slab(src, ckey, w, nv_pad=nv_pad, engine=engine,
-                             interpret=interpret)
+        return coalesce_slab(src, ckey, w, nv_pad=nv_pad)
 
     if engine == "hash":
         # Hash-slot tables sum in the weight dtype (slab order): an

@@ -206,7 +206,7 @@ def main(argv=None) -> int:
             for sig in (signal.SIGTERM, signal.SIGINT):
                 signal.signal(sig, lambda *_a: daemon.request_drain())
             # The readiness line tells harnesses (tests, the load
-            # generator, the TPU ladder) when to connect and where.
+            # generator) when to connect and where.
             print(json.dumps({"ready": {
                 "socket": args.socket, "port": daemon.port,
                 "b_max": config.b_max, "engine": config.engine,

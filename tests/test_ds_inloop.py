@@ -9,7 +9,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from cuvite_tpu.comm.mesh import shard_map
 from cuvite_tpu.ops import segment as seg
 from cuvite_tpu.ops.exactsum import ds_psum, ds_tree_sum
 
@@ -89,7 +88,7 @@ def test_ds_psum_exact_across_shards():
     vals = np.tile(np.array([2.0 ** 25, 1.0], np.float32), 4)  # 8 shards
 
     @jax.jit
-    @shard_map(mesh=mesh, in_specs=P("x"), out_specs=P(),
+    @jax.shard_map(mesh=mesh, in_specs=P("x"), out_specs=P(),
                check_vma=False)
     def f(x):
         pair = ds_tree_sum(x)   # per-shard scalar pair

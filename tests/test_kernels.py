@@ -281,14 +281,12 @@ def test_build_heavy_layout_contract():
 
 
 def test_heavy_kernel_policy(monkeypatch):
-    import jax
-
     from cuvite_tpu.kernels.heavy_bincount import heavy_kernel_enabled
 
     monkeypatch.delenv("CUVITE_HEAVY_KERNEL", raising=False)
-    # tier-1 runs on CPU: the default engages on the TPU backend only.
-    assert heavy_kernel_enabled() == (jax.default_backend() == "tpu")
-    monkeypatch.setenv("CUVITE_HEAVY_KERNEL", "0")   # kill switch
+    # Opt-in on every backend: the sorted path won on the chip (PR 21).
+    assert heavy_kernel_enabled() is False
+    monkeypatch.setenv("CUVITE_HEAVY_KERNEL", "0")
     assert heavy_kernel_enabled() is False
     monkeypatch.setenv("CUVITE_HEAVY_KERNEL", "1")   # forced (interpret)
     assert heavy_kernel_enabled() is True
@@ -316,9 +314,9 @@ def hub_graph():
     ["bucketed", pytest.param("pallas", marks=pytest.mark.slow)])
 def test_heavy_kernel_full_run_bit_identical(hub_graph, engine,
                                              monkeypatch):
-    """The promoted heavy path (CUVITE_HEAVY_KERNEL=1 forces the kernel
-    in interpret mode on CPU — the same jitted driver path the TPU
-    default runs) must cluster bit-identically to the sorted heavy
+    """The opt-in heavy path (CUVITE_HEAVY_KERNEL=1 runs the kernel in
+    interpret mode on CPU — the same jitted driver path the chip runs
+    compiled) must cluster bit-identically to the sorted heavy
     path it replaces."""
     from cuvite_tpu.louvain.driver import louvain_phases
 

@@ -38,7 +38,7 @@ BUDGET = os.path.join(REPO, "tools", "replication_budget.json")
 # ---------------------------------------------------------------------------
 # THE tier-1 gate: the full audit on the forced-CPU 8-virtual-device
 # shape (conftest pins the device count; the same audit tools/
-# mesh_audit.py runs standalone and ladder stage I runs on real chips).
+# mesh_audit.py runs standalone).
 
 
 def test_mesh_audit_green_on_current_tree():
@@ -95,7 +95,7 @@ def test_missing_budget_fails_closed(tmp_path):
 def test_conditional_psum_trips_m001():
     from jax.sharding import PartitionSpec as P
 
-    from cuvite_tpu.comm.mesh import make_mesh, shard_map
+    from cuvite_tpu.comm.mesh import make_mesh
 
     mesh = make_mesh(8)
 
@@ -106,7 +106,7 @@ def test_conditional_psum_trips_m001():
             lambda v: v,
             x)
 
-    wrapped = jax.jit(shard_map(bad, mesh=mesh, in_specs=P("v"),
+    wrapped = jax.jit(jax.shard_map(bad, mesh=mesh, in_specs=P("v"),
                                 out_specs=P("v"), check_vma=False))
     jaxpr = jax.make_jaxpr(wrapped)(np.zeros(8, np.float32))
     findings = mc.lint_collective_jaxpr(jaxpr, "sabotage_cond_psum")
@@ -119,7 +119,7 @@ def test_conditional_psum_trips_m001():
             lambda v: jax.lax.psum(v * 0.0, "v"),
             x)
 
-    wrapped_ok = jax.jit(shard_map(good, mesh=mesh, in_specs=P("v"),
+    wrapped_ok = jax.jit(jax.shard_map(good, mesh=mesh, in_specs=P("v"),
                                    out_specs=P(), check_vma=False))
     jaxpr_ok = jax.make_jaxpr(wrapped_ok)(np.zeros(8, np.float32))
     assert not mc.lint_collective_jaxpr(jaxpr_ok, "balanced_cond")

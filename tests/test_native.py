@@ -553,3 +553,23 @@ def test_build_csr_w32_radix_branch_large_nv():
     g2 = Graph.from_edges(nv, src, dst, weights=w, symmetrize=True)
     assert np.array_equal(g2.weights, g.weights)
     assert np.array_equal(g2.tails, g.tails)
+
+
+def test_library_name_keys_source_compiler_and_machine(monkeypatch):
+    """A stale or foreign build is never loaded: the library's file name
+    hashes the source, the compiler command and the machine type, and
+    the target is portable (no -march=native)."""
+    import os
+
+    from cuvite_tpu import native
+
+    assert not any(f.startswith("-march") for f in native.CXXFLAGS)
+    base = native._so_path()
+    assert os.path.basename(base).startswith("libcuvite_native-")
+    if native.available():
+        assert native._LIB._name == base
+    monkeypatch.setenv("CXX", "some-other-c++")
+    assert native._so_path() != base
+    monkeypatch.delenv("CXX")
+    monkeypatch.setattr(native.platform, "machine", lambda: "other-arch")
+    assert native._so_path() != base

@@ -2,8 +2,7 @@
 ops/segment.coalesced_runs + the device_coarsen_slab dispatch.
 
 The packed-sort path is the bit-parity oracle: the dense dst-tile
-engines (Pallas kernel, interpret mode on CPU, and its XLA scatter
-twin) must reproduce its compacted (src, dst, w) prefix BIT-for-bit —
+engine (the XLA scatter bin-accumulate) must reproduce its compacted (src, dst, w) prefix BIT-for-bit —
 offsets/tails always (run presence is exact in every mode), weights on
 the documented exactness domain (unit/dyadic run sums).  The
 packed-sort key-width contract of ops/segment.py is pinned at its
@@ -61,11 +60,10 @@ def test_dense_engines_bit_identical_to_sort(nv_pad, ne_pad, gapped):
     arrs = _slab(nv_pad, ne_pad, seed=nv_pad + ne_pad, gapped=gapped)
     ref = jax.device_get(coalesced_runs(*arrs, nv_pad=nv_pad,
                                         engine="sort"))
-    for engine in ("xla", "pallas"):
-        got = jax.device_get(coalesced_runs(*arrs, nv_pad=nv_pad,
-                                            engine=engine))
-        for r, g, name in zip(ref, got, ("src", "dst", "w", "n")):
-            assert np.array_equal(r, g), (engine, name)
+    got = jax.device_get(coalesced_runs(*arrs, nv_pad=nv_pad,
+                                        engine="xla"))
+    for r, g, name in zip(ref, got, ("src", "dst", "w", "n")):
+        assert np.array_equal(r, g), name
     # Tail sentinel contract: padding after the compacted prefix.
     src_c, dst_c, w_c, n = ref
     n = int(n)
@@ -88,7 +86,7 @@ def test_zero_weight_runs_emitted_by_presence():
     dst[:3] = [6, 8, 10]
     w[:3] = [1.0, 0.0, 2.0]  # the (7, 8) run weighs exactly 0
     arrs = tuple(jnp.asarray(x) for x in (src, dst, w))
-    for engine in ("sort", "xla", "pallas"):
+    for engine in ("sort", "xla"):
         src_c, dst_c, w_c, n = jax.device_get(
             coalesced_runs(*arrs, nv_pad=nv_pad, engine=engine))
         assert int(n) == 3, engine
@@ -112,11 +110,10 @@ def test_device_coarsen_slab_dense_vs_sort_bitwise(two_cliques):
             jnp.asarray(dg.vertex_mask()))
     ref = jax.device_get(device_coarsen_slab(*args, nv_pad=dg.nv_pad,
                                              coalesce="sort"))
-    for engine in ("xla", "pallas"):
-        got = jax.device_get(device_coarsen_slab(*args, nv_pad=dg.nv_pad,
-                                                 coalesce=engine))
-        for r, g in zip(ref, got):
-            assert np.array_equal(r, g), engine
+    got = jax.device_get(device_coarsen_slab(*args, nv_pad=dg.nv_pad,
+                                             coalesce="xla"))
+    for r, g in zip(ref, got):
+        assert np.array_equal(r, g)
 
 
 def test_coalesce_engine_policy(monkeypatch):
@@ -135,8 +132,10 @@ def test_coalesce_engine_policy(monkeypatch):
     assert coalesce_engine(4096) == "sort"
     assert coalesce_engine(1024) == "xla"
     monkeypatch.delenv("CUVITE_SEG_COALESCE_MAX_NV")
+    # The Pallas mode is gone: it warns and keeps the default.
     monkeypatch.setenv("CUVITE_SEG_COALESCE", "pallas")
-    assert coalesce_engine(4096) == "pallas"
+    with pytest.warns(UserWarning, match="unrecognized"):
+        assert coalesce_engine(4096) == "sort"
     monkeypatch.setenv("CUVITE_SEG_COALESCE", "0")
     assert coalesce_engine(1024) == "sort"
     # A typo'd pin warns and keeps the default instead of silently

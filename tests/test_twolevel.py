@@ -231,12 +231,12 @@ def test_exchange_table_bytes_counts_replicating_collectives_only():
     scalar psums do not."""
     from functools import partial
 
-    from cuvite_tpu.comm.mesh import make_mesh, shard_map
+    from cuvite_tpu.comm.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
 
     mesh = make_mesh(8)
 
-    @partial(shard_map, mesh=mesh, in_specs=P("v"), out_specs=P(),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("v"), out_specs=P(),
              check_vma=False)
     def body(x):
         g = jax.lax.all_gather(x, "v", tiled=True)      # 8*16*4 = 512 B  # graftlint: disable=R025 — hand-built fixture exercising the exchange_table_bytes metric, not a product table

@@ -401,11 +401,38 @@ def test_bench_main_emits_no_json_on_guard_trip(monkeypatch, capsys):
         raise wb.BenchCompileGuardError(["Compiling sabotage"])
 
     monkeypatch.setattr(wb, "run_bench", boom)
-    monkeypatch.setattr(wb, "_init_backend", lambda: "cpu")
     rc = wb.main(["--scale", "6", "--repeats", "1"])
     out = capsys.readouterr().out
     assert rc == 3
     assert not out.strip(), f"guard trip must emit NO json, got: {out!r}"
+
+
+def test_bench_record_names_the_device_it_ran_on(monkeypatch, capsys):
+    """No fallback: the bench runs on JAX's default backend and stamps
+    platform, device_kind and device_count into its record."""
+    import json
+
+    import jax
+
+    import cuvite_tpu.workloads.bench as wb
+
+    rec = {"metric": "louvain_teps_per_chip", "value": 1.0,
+           "unit": "traversed_edges/sec", "vs_baseline": 0.1,
+           "platform": "cpu", "graph": "x", "modularity": 0.1,
+           "phases": 1, "compile_guard": {"checked": True,
+                                          "new_compiles": 0},
+           "stages": {"coarsen_s": 0.0, "coalesce_s": 0.0,
+                      "rebin_s": 0.0, "upload_s": 0.0, "iterate_s": 1.0},
+           "engine": "bucketed", "schema": 4,
+           "convergence_summary": [{"phase": 0, "iterations": 3}],
+           "compile_events": [], "hbm_peak_by_buffer": {"slab": 1024}}
+    monkeypatch.setattr(wb, "run_bench", lambda *a, **k: dict(rec))
+    assert wb.main(["--scale", "6", "--repeats", "1"]) == 0
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    dev = jax.devices()
+    assert out["platform"] == dev[0].platform
+    assert out["device_kind"] == dev[0].device_kind
+    assert out["device_count"] == len(dev)
 
 
 def test_validate_record_rejects_unchecked_nonzero_compiles():

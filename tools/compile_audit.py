@@ -53,7 +53,7 @@ if "--xla_force_host_platform_device_count" not in _flags:
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms",
-                  os.environ.get("CUVITE_PLATFORM", "cpu"))
+                  os.environ.get("JAX_PLATFORMS", "cpu"))
 
 from cuvite_tpu.analysis.jaxpr_audit import (  # noqa: E402
     audit_entry,

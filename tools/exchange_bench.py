@@ -1,5 +1,8 @@
 """Sparse-vs-replicated exchange A/B on the virtual 8-device CPU mesh.
 
+CPU-only by default (JAX_PLATFORMS=cpu unless set).  The parent only
+spawns one child per config and never initializes a backend itself.
+
 Re-measures the gap after the round-3 collective packing (7 all_to_all
 per iteration -> 3, comm/exchange.py) — VERDICT r2 item 5.  The sparse
 plan is a MEMORY play (O(owned+ghosts) per-chip state vs O(nv_total)); a
@@ -21,10 +24,10 @@ _flags = os.environ.get("XLA_FLAGS", "")
 if "--xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
-os.environ.setdefault("CUVITE_PLATFORM", "cpu")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-import _common  # noqa: F401,E402  (backend pin + compile cache)
+import _common  # noqa: F401,E402  (repo path + compile cache)
 
 import jax  # noqa: E402
 
@@ -71,9 +74,11 @@ def main():
               flush=True)
         child_timeout = 7200.0
     one = os.environ.get("AB_EXCHANGE")  # subprocess mode: one config
-    print(f"# backend={jax.default_backend()} "
-          f"devices={len(jax.devices())} shards={nsh}", flush=True)
     if one:
+        # Only a child touches a backend: a parent holding the device
+        # would leave its children none.
+        print(f"# backend={jax.default_backend()} "
+              f"devices={len(jax.devices())} shards={nsh}", flush=True)
         for scale in scales:
             run_one(scale, nsh, one)
         return

@@ -112,7 +112,6 @@ def _two_axis(args, shape, plat) -> int:
         DCN_AXIS,
         ICI_AXIS,
         make_hybrid_mesh,
-        shard_map,
     )
 
     n_dcn, n_ici = shape
@@ -131,7 +130,7 @@ def _two_axis(args, shape, plat) -> int:
 
     def wrap(body, out=P()):
         return jax.jit(functools.partial(
-            shard_map, mesh=mesh, in_specs=spec, out_specs=out,
+            jax.shard_map, mesh=mesh, in_specs=spec, out_specs=out,
             check_vma=False)(body))
 
     @functools.lru_cache(maxsize=None)
@@ -233,7 +232,7 @@ def main(argv=None) -> int:
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from cuvite_tpu.comm.mesh import VERTEX_AXIS, make_mesh, shard_map
+    from cuvite_tpu.comm.mesh import VERTEX_AXIS, make_mesh
 
     S = args.devices
     plat = jax.devices()[0].platform
@@ -256,7 +255,7 @@ def main(argv=None) -> int:
     @functools.lru_cache(maxsize=None)
     def ag_fn():
         @jax.jit
-        @functools.partial(shard_map, mesh=mesh, in_specs=P(VERTEX_AXIS),
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(VERTEX_AXIS),
                            out_specs=P(), check_vma=False)
         def ag(x):
             return jax.lax.all_gather(x, VERTEX_AXIS, tiled=True)  # graftlint: replicated-ok=scope=bench; launch-latency microbenchmark measuring this collective itself, not a product table
@@ -265,7 +264,7 @@ def main(argv=None) -> int:
     @functools.lru_cache(maxsize=None)
     def psum_fn():
         @jax.jit
-        @functools.partial(shard_map, mesh=mesh, in_specs=P(VERTEX_AXIS),
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(VERTEX_AXIS),
                            out_specs=P(), check_vma=False)
         def ps(x):
             return jax.lax.psum(x, VERTEX_AXIS)
@@ -274,7 +273,7 @@ def main(argv=None) -> int:
     @functools.lru_cache(maxsize=None)
     def a2a_fn():
         @jax.jit
-        @functools.partial(shard_map, mesh=mesh, in_specs=P(VERTEX_AXIS),
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(VERTEX_AXIS),
                            out_specs=P(VERTEX_AXIS), check_vma=False)
         def a2a(x):
             return jax.lax.all_to_all(x, VERTEX_AXIS, 0, 0, tiled=True)

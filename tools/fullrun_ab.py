@@ -1,7 +1,7 @@
 """Full louvain_phases A/B (bench.py's timed body, minus the probe).
 
 One warm-up + one timed run at AB_SCALE (default 18) on the backend pinned
-by CUVITE_PLATFORM.  Prints phase breakdown and TEPS for the timed run.
+by JAX_PLATFORMS.  Prints phase breakdown and TEPS for the timed run.
 """
 
 import os

@@ -9,8 +9,8 @@ both over (D, nv_ceil) so the log records where the tile kernel wins.
 
 Sweep 2 (seg-coalesce, `python tools/heavy_ab.py seg`): the coalesce
 engines vs the packed-sort chokepoint on relabeled-slab workloads, per
-slab class — the dense dst-tile pair (kernels/seg_coalesce.py, 'xla'
-twin + 'pallas' kernel) on its budget-eligible classes, plus the
+slab class — the dense 'xla' engine (kernels/seg_coalesce.py) on its
+budget-eligible classes, plus the
 ISSUE-19 big-class arms on every class: 'msd' (two-pass int32 MSD
 src-partition sort) and 'hash' (hash-slot accumulate with device-side
 collision detection + sort retry).  The nv_pad >= 2^16 classes are the
@@ -22,7 +22,8 @@ before timing.  Appends to tools/logs/seg_coalesce_ab_r19.log.
 Usage:
     python tools/heavy_ab.py                   # both sweeps (chip)
     python tools/heavy_ab.py heavy|seg         # one sweep
-    CUVITE_PLATFORM=cpu python tools/heavy_ab.py   # interpret-mode smoke
+    AB_REPEATS=1 python tools/heavy_ab.py heavy 10240:128:8388608  # 1 case
+    JAX_PLATFORMS=cpu python tools/heavy_ab.py   # interpret-mode smoke
 
 Appends dated blocks to tools/logs/heavy_ab_r5.log (heavy) and
 tools/logs/seg_coalesce_ab_r10.log (coalesce).
@@ -86,13 +87,8 @@ def seg_coalesce_ab():
         # candidates against the 64-bit comparator tax.
         engines = ["sort", "msd", "hash"]
         if nv_pad <= 4096:
-            # Dense dst-tile classes (within the accumulator budget).
+            # Dense classes (within the accumulator budget).
             engines.insert(1, "xla")
-            if not (interpret and ne_pad > (1 << 18)):
-                # Interpret mode unrolls the kernel grid at trace time;
-                # the big slabs are chip cases.  The XLA twin still
-                # measures.
-                engines.insert(2, "pallas")
         engines = tuple(engines)
         n_real = ne_pad - ne_pad // 5
         src = np.full(ne_pad, nv_pad, np.int32)
@@ -135,90 +131,86 @@ def seg_coalesce_ab():
     _log_to(SEG_LOG, "seg-coalesce A/B done")
 
 
-def main():
+# (D, H, nv_ceil) per case: D neighbor slots per hub, H hubs, nv_ceil
+# the community range.  The last default case is the golden graph's
+# phase 0 (powerlaw-1e8: 127 hubs of degree <= 9839, nv_pad 2^23).
+DEFAULT_CASES = ((4096, 32, 8192), (4096, 32, 65536), (16384, 32, 1 << 20),
+                 (10240, 128, 1 << 23))
+
+
+def main(cases=DEFAULT_CASES, repeats=5):
     from cuvite_tpu.kernels.heavy_bincount import heavy_argmax_pallas
     from cuvite_tpu.louvain.bucketed import _row_argmax_sorted
 
     interpret = jax.default_backend() != "tpu"
     plat = jax.default_backend()
     log(f"heavy A/B start backend={plat} interpret={interpret}")
-    H = 32  # hub rows per case (hubs are <0.1% of vertices)
     rng = np.random.default_rng(7)
-    for D in (4096, 16384):
-        for nv_ceil in (8192, 65536, 1 << 20):
-            if interpret and (D, nv_ceil) != (4096, 8192):
-                # Interpret mode executes the grid in Python — the big
-                # cases would take hours; cpu is a correctness smoke only.
-                continue
-            nv = nv_ceil - 7
-            cmat = rng.integers(0, nv, size=(H, D)).astype(np.int32)
-            wmat = (rng.integers(1, 32, size=(H, D)) / 16.0).astype(
-                np.float32)
-            curr = rng.integers(0, nv, size=H).astype(np.int32)
-            vdeg = wmat.sum(axis=1)
-            sl = np.zeros(H, dtype=np.float32)
-            comm_deg = (rng.integers(1, 256, size=nv_ceil) / 8.0).astype(
-                np.float32)
-            ax = comm_deg[curr] - vdeg
-            const = np.float32(1.0 / vdeg.sum())
-            cT = jnp.asarray(np.ascontiguousarray(cmat.T))
-            wT = jnp.asarray(np.ascontiguousarray(wmat.T))
-            cd = jnp.asarray(comm_deg)
-            cu, vd, slj, axj = map(jnp.asarray, (curr, vdeg, sl, ax))
+    for D, H, nv_ceil in cases:
+        if interpret and (D, nv_ceil) != (4096, 8192):
+            # Interpret mode executes the grid in Python — the big
+            # cases would take hours; cpu is a correctness smoke only.
+            continue
+        nv = nv_ceil - 7
+        cmat = rng.integers(0, nv, size=(H, D)).astype(np.int32)
+        wmat = (rng.integers(1, 32, size=(H, D)) / 16.0).astype(np.float32)
+        curr = rng.integers(0, nv, size=H).astype(np.int32)
+        vdeg = wmat.sum(axis=1)
+        sl = np.zeros(H, dtype=np.float32)
+        comm_deg = (rng.integers(1, 256, size=nv_ceil) / 8.0).astype(
+            np.float32)
+        ax = comm_deg[curr] - vdeg
+        const = jnp.asarray(np.float32(1.0 / vdeg.sum()))
+        cT = jnp.asarray(np.ascontiguousarray(cmat.T))
+        wT = jnp.asarray(np.ascontiguousarray(wmat.T))
+        cd = jnp.asarray(comm_deg)
+        cu, vd, slj, axj = map(jnp.asarray, (curr, vdeg, sl, ax))
+        # XLA twin: the per-row packed single-key sort path the heavy
+        # residual rides by default, on identical rows.
+        cm = jnp.asarray(cmat)
+        wm = jnp.asarray(wmat)
+        ay = jnp.asarray(comm_deg[cmat])
+        out = {}
 
-            def run_kernel():
-                bc, bg, c0 = heavy_argmax_pallas(
-                    cT, wT, cd, cu, vd, slj, axj, jnp.asarray(const),
-                    interpret=interpret)
-                return float(bg[0])
+        def run_kernel():
+            out["k"] = jax.block_until_ready(heavy_argmax_pallas(
+                cT, wT, cd, cu, vd, slj, axj, const, interpret=interpret))
 
-            # XLA twin: the per-row packed single-key sort path the heavy
-            # residual rides today, on identical rows.
-            cm = jnp.asarray(cmat)
-            wm = jnp.asarray(wmat)
-            ay = jnp.asarray(comm_deg[cmat])
+        def run_sorted():
+            out["s"] = jax.block_until_ready(_row_argmax_sorted(
+                cm, wm, ay, None, cu, vd, slj, axj, const,
+                np.iinfo(np.int32).max, id_bound=nv_ceil))
 
-            def run_sorted():
-                res = _row_argmax_sorted(
-                    cm, wm, ay, None, cu, vd, slj, axj,
-                    jnp.asarray(const), np.iinfo(np.int32).max,
-                    id_bound=nv_ceil)
-                return float(res.best_gain[0])
-
-            try:
-                tk = time_best(run_kernel)
-            except Exception as e:  # mosaic lowering can reject shapes
-                log(f"D={D} nv_ceil={nv_ceil}: kernel FAILED {e!r:.200}")
-                continue
-            ts = time_best(run_sorted)
-            # Semantic identity on the A/B inputs: best_c/counter0 must be
-            # bitwise equal.  best_gain is compared to 1-2 ulp: const here
-            # is 1/sum(w) (not a power of two like the unit tests use), so
-            # XLA's FMA contraction rounds the gain's second term once
-            # where the non-contracted form rounds twice — measured 1 ulp
-            # on ~half the rows, never changing the argmax.
-            bk = heavy_argmax_pallas(cT, wT, cd, cu, vd, slj, axj,
-                                     jnp.asarray(const),
-                                     interpret=interpret)
-            br = _row_argmax_sorted(cm, wm, ay, None, cu, vd, slj, axj,
-                                    jnp.asarray(const),
-                                    np.iinfo(np.int32).max,
-                                    id_bound=nv_ceil)
-            gk, gr = np.asarray(bk[1]), np.asarray(br.best_gain)
-            fin = np.isfinite(gk) & np.isfinite(gr)
-            same = (np.array_equal(np.asarray(bk[0]),
-                                   np.asarray(br.best_c))
-                    and np.array_equal(fin, np.isfinite(gr))
-                    and np.allclose(gk[fin], gr[fin], rtol=3e-7, atol=0))
-            log(f"D={D} nv_ceil={nv_ceil} H={H}: kernel {tk*1e3:.1f} ms  "
-                f"sorted {ts*1e3:.1f} ms  ratio {tk/ts:.2f}x  "
-                f"semantically_identical={same}")
+        try:
+            tk = time_best(run_kernel, repeats)
+        except Exception as e:  # mosaic lowering can reject shapes
+            log(f"D={D} nv_ceil={nv_ceil}: kernel FAILED {e!r:.200}")
+            continue
+        ts = time_best(run_sorted, repeats)
+        # Semantic identity on the A/B inputs: best_c/counter0 must be
+        # bitwise equal.  best_gain is compared to 1-2 ulp: const here
+        # is 1/sum(w) (not a power of two like the unit tests use), so
+        # XLA's FMA contraction rounds the gain's second term once
+        # where the non-contracted form rounds twice — measured 1 ulp
+        # on ~half the rows, never changing the argmax.
+        bk, br = out["k"], out["s"]
+        gk, gr = np.asarray(bk[1]), np.asarray(br.best_gain)
+        fin = np.isfinite(gk) & np.isfinite(gr)
+        same = (np.array_equal(np.asarray(bk[0]), np.asarray(br.best_c))
+                and np.array_equal(fin, np.isfinite(gr))
+                and np.allclose(gk[fin], gr[fin], rtol=3e-7, atol=0))
+        log(f"D={D} nv_ceil={nv_ceil} H={H}: kernel {tk*1e3:.1f} ms  "
+            f"sorted {ts*1e3:.1f} ms  ratio {tk/ts:.2f}x  "
+            f"semantically_identical={same}")
     log("heavy A/B done")
 
 
 if __name__ == "__main__":
     which = sys.argv[1] if len(sys.argv) > 1 else "both"
     if which in ("heavy", "both"):
-        main()
+        # Optional D:H:NV_CEIL cases after the sweep name.
+        cases = [tuple(int(x) for x in a.split(":")) for a in sys.argv[2:]]
+        main(cases or DEFAULT_CASES,
+             repeats=int(os.environ.get("AB_REPEATS", "5")))
     if which in ("seg", "both"):
         seg_coalesce_ab()

@@ -28,7 +28,6 @@ Usage:
     python tools/mesh_audit.py --json             # machine-readable
     python tools/mesh_audit.py --inventory        # R025 replicated-ok sites
     python tools/mesh_audit.py --out FILE.json    # checkpoint the report
-                                                  # (ladder stage I)
 
 Dynamic results are never cached; the audit re-runs the entries every
 time.  The tier-1 test (tests/test_meshcheck.py) runs the same audit
@@ -50,7 +49,7 @@ BUDGET = os.path.join(REPO_ROOT, "tools", "replication_budget.json")
 
 # Tier-1's backend shape, replicated for standalone runs (the
 # compile_audit precedent): the mesh shapes need 8 devices.  On a real
-# TPU slice (ladder stage I) the flag is a no-op — the chips are real.
+# TPU slice the flag is a no-op — the chips are real.
 _flags = os.environ.get("XLA_FLAGS", "")
 if "--xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
@@ -59,7 +58,7 @@ if "--xla_force_host_platform_device_count" not in _flags:
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms",
-                  os.environ.get("CUVITE_PLATFORM", "cpu"))
+                  os.environ.get("JAX_PLATFORMS", "cpu"))
 
 from cuvite_tpu.analysis.meshcheck import (  # noqa: E402
     ENTRIES,
@@ -72,7 +71,7 @@ from cuvite_tpu.analysis.meshcheck import (  # noqa: E402
 # --smoke: one exchange per engine family at a fixed pair of shapes —
 # the fast pre-commit self-check lint.sh --mesh-smoke runs (still
 # cross-shape, so M001/M002/M003 all have teeth; the full gate runs in
-# tier-1 and on the ladder).
+# tier-1).
 SMOKE_ENTRIES = ("bucketed_replicated", "bucketed_sparse")
 SMOKE_SHAPES = ((4, 2), (2, 4))
 
@@ -122,8 +121,7 @@ def main(argv=None) -> int:
     ap.add_argument("--json", action="store_true")
     ap.add_argument("--out", default=None, metavar="FILE",
                     help="write the JSON report to FILE (per-shape "
-                         "ledger rows + findings; ladder stage I "
-                         "checkpoints these)")
+                         "ledger rows + findings)")
     ap.add_argument("--inventory", action="store_true",
                     help="print the R025 replicated-ok inventory and "
                          "exit (static tier only)")

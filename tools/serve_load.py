@@ -13,7 +13,6 @@ Four subcommands around the open-loop generator (serve/loadgen.py):
 
     # drive a SPAWNED `python -m cuvite_tpu.serve daemon` over its
     # socket at a fixed rate, then SIGTERM it and check the clean drain
-    # (the TPU ladder's stage H path)
     python tools/serve_load.py daemon --b-max 8 --rate 20 --jobs 64
 
     # skewed-mix packing A/B (ISSUE 20): 90:10 small:big open-loop mix,
@@ -311,7 +310,8 @@ def _read_ready(proc, timeout_s: float) -> dict:
 
 def cmd_daemon(args) -> int:
     """Spawn the daemon, drive an open-loop synth load over its socket,
-    SIGTERM it, and verify the graceful drain (exit 0 + summary)."""
+    SIGTERM it, and verify the graceful drain (exit 0 + summary).  This
+    parent never imports JAX: the device belongs to the daemon child."""
     cmd = [sys.executable, "-m", "cuvite_tpu.serve", "daemon",
            "--port", "0", "--b-max", str(args.b_max),
            "--linger-ms", str(args.linger_ms),

@@ -26,7 +26,6 @@ Usage:
     python tools/width_audit.py --json            # machine-readable
     python tools/width_audit.py --inventory       # width-ok annotated sites
     python tools/width_audit.py --out FILE.json   # checkpoint the report
-                                                  # (ladder stage J)
     python tools/width_audit.py --write-budget    # regenerate the manifest
 
 Dynamic results are never cached; the audit re-runs the traces every
@@ -50,7 +49,7 @@ BUDGET = os.path.join(REPO_ROOT, "tools", "width_budget.json")
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms",
-                  os.environ.get("CUVITE_PLATFORM", "cpu"))
+                  os.environ.get("JAX_PLATFORMS", "cpu"))
 
 from cuvite_tpu.analysis.widthaudit import (  # noqa: E402
     ENTRIES,
@@ -63,7 +62,7 @@ from cuvite_tpu.analysis.widthaudit import (  # noqa: E402
 # --smoke: the packed-sort slab entry plus the boundary probes at ONE
 # workload — the fast pre-commit self-check lint.sh --width-smoke
 # runs (the probes carry most of W002's teeth; the full two-workload
-# sweep runs in tier-1 and on the ladder).
+# sweep runs in tier-1).
 SMOKE_ENTRIES = ("solo_sort_step", "coarsen_coalesce")
 SMOKE_WORKLOADS = ("rmat_s28",)
 
@@ -106,8 +105,7 @@ def main(argv=None) -> int:
     ap.add_argument("--json", action="store_true")
     ap.add_argument("--out", default=None, metavar="FILE",
                     help="write the JSON report to FILE (per-workload "
-                         "sort facts + findings; ladder stage J "
-                         "checkpoints these)")
+                         "sort facts + findings)")
     ap.add_argument("--inventory", action="store_true",
                     help="print the closed width-ok inventory and "
                          "exit (static tier only)")
