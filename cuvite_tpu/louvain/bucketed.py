@@ -912,35 +912,37 @@ def bucketed_step(bucket_arrays, heavy_arrays, self_loop, comm, vdeg,
 
     use_sparse = sparse_plan is not None
     red_axes = (axis_name if ici_axis is None else (axis_name, ici_axis))
-    if use_sparse:
-        from cuvite_tpu.comm.exchange import (
-            sparse_env, sparse_modularity, twolevel_env)
+    with jax.named_scope("cuvite.tables"):
+        if use_sparse:
+            from cuvite_tpu.comm.exchange import (
+                sparse_env, sparse_modularity, twolevel_env)
 
-        assert axis_name is not None, "sparse exchange requires a mesh axis"
-        if ici_axis is not None:
-            env = twolevel_env(comm, vdeg, sparse_plan[0], sparse_plan[1],
-                               axis_name, ici_axis, n_dcn=nshards,
-                               budget=budget, info=info_comm)
+            assert axis_name is not None, \
+                "sparse exchange requires a mesh axis"
+            if ici_axis is not None:
+                env = twolevel_env(comm, vdeg, sparse_plan[0], sparse_plan[1],
+                                   axis_name, ici_axis, n_dcn=nshards,
+                                   budget=budget, info=info_comm)
+            else:
+                env = sparse_env(comm, vdeg, sparse_plan[0], sparse_plan[1],
+                                 axis_name, nshards=nshards, budget=budget,
+                                 info=info_comm)
+            comm_ref = env.comm_ext      # gather table for dst indices
+
+            def gsum(x):
+                return jax.lax.psum(x, red_axes)
+
+            overflow = jax.lax.psum(env.overflow.astype(jnp.int32),
+                                    red_axes) > 0
         else:
-            env = sparse_env(comm, vdeg, sparse_plan[0], sparse_plan[1],
-                             axis_name, nshards=nshards, budget=budget,
-                             info=info_comm)
-        comm_ref = env.comm_ext      # gather table for dst indices
-
-        def gsum(x):
-            return jax.lax.psum(x, red_axes)
-
-        overflow = jax.lax.psum(env.overflow.astype(jnp.int32),
-                                red_axes) > 0
-    else:
-        env = None
-        comm_ref, gsum = seg.spmd_env(comm, axis_name)
-        info = comm if info_comm is None else info_comm
-        comm_deg = gsum(seg.segment_sum(vdeg, info, num_segments=nv_total))  # graftlint: replicated-ok=scope=ici; replicated-exchange community degree table, flat-mesh-only (one ICI group); sparse/two-level modes ride the ghost plan instead
-        comm_size = gsum(seg.segment_sum(  # graftlint: replicated-ok=scope=ici; replicated-exchange community size table, flat-mesh-only (one ICI group); sparse/two-level modes attach sizes to ghosts instead
-            jnp.ones((nv_local,), dtype=vdt), info, num_segments=nv_total
-        ))
-        overflow = jnp.zeros((), dtype=bool)  # replicated: can't overflow
+            env = None
+            comm_ref, gsum = seg.spmd_env(comm, axis_name)
+            info = comm if info_comm is None else info_comm
+            comm_deg = gsum(seg.segment_sum(vdeg, info, num_segments=nv_total))  # graftlint: replicated-ok=scope=ici; replicated-exchange community degree table, flat-mesh-only (one ICI group); sparse/two-level modes ride the ghost plan instead
+            comm_size = gsum(seg.segment_sum(  # graftlint: replicated-ok=scope=ici; replicated-exchange community size table, flat-mesh-only (one ICI group); sparse/two-level modes attach sizes to ghosts instead
+                jnp.ones((nv_local,), dtype=vdt), info, num_segments=nv_total
+            ))
+            overflow = jnp.zeros((), dtype=bool)  # replicated: can't overflow
 
     # Community-info lookups.  Sparse mode reads values ATTACHED to the
     # referenced vertex (indexed by dst in the extended-local table);
@@ -959,42 +961,43 @@ def bucketed_step(bucket_arrays, heavy_arrays, self_loop, comm, vdeg,
     neg_inf = jnp.array(-jnp.inf, dtype=wdt)
 
     # Heavy-vertex current-community weight (also their e_ix source).
-    hs, hd, hw = heavy_arrays
-    use_heavy_kernel = heavy_kernel is not None
-    if use_heavy_kernel:
-        # Opt-in heavy path (ISSUE 8): ONE community-range-tile kernel
-        # pass per iteration — no heavy sort, no per-iteration triples
-        # gather.  Replicated/single-shard only: the kernel consumes the
-        # dense comm_deg table (and the sparse singleton guard needs an
-        # attached-size channel it does not have).
-        assert not use_sparse and axis_name is None, \
-            "heavy_kernel is a single-shard replicated-path layout"
-        from cuvite_tpu.kernels.heavy_bincount import heavy_argmax_pallas
+    with jax.named_scope("cuvite.heavy"):
+        hs, hd, hw = heavy_arrays
+        use_heavy_kernel = heavy_kernel is not None
+        if use_heavy_kernel:
+            # Opt-in heavy path: ONE community-range-tile kernel
+            # pass per iteration — no heavy sort, no per-iteration triples
+            # gather.  Replicated/single-shard only: the kernel consumes the
+            # dense comm_deg table (and the sparse singleton guard needs an
+            # attached-size channel it does not have).
+            assert not use_sparse and axis_name is None, \
+                "heavy_kernel is a single-shard replicated-path layout"
+            from cuvite_tpu.kernels.heavy_bincount import heavy_argmax_pallas
 
-        hk_verts, hk_dT, hk_wT = heavy_kernel
-        safe_hv = jnp.minimum(hk_verts, nv_local - 1)
-        curr_h = jnp.take(comm, safe_hv)
-        vdeg_h = jnp.take(vdeg, safe_hv)
-        # Padding slots (dst == pad id >= nv_local) mask to nv_total: >=
-        # every candidate tile's range, so they are never candidates and
-        # never touch counter0 (w == 0 there anyway).
-        hk_pad = hk_dT >= jnp.asarray(nv_local, hk_dT.dtype)
-        cT = jnp.where(
-            hk_pad, jnp.asarray(nv_total, hk_dT.dtype),
-            jnp.take(comm_ref, jnp.minimum(hk_dT, nv_local - 1)))
-        hk_bc, hk_bg, hk_c0 = heavy_argmax_pallas(
-            cT, hk_wT.astype(wdt), comm_deg, curr_h, vdeg_h,
-            jnp.take(self_loop, safe_hv), own_deg(safe_hv) - vdeg_h,
-            constant, interpret=pallas_interpret)
-        c0_heavy = jnp.zeros((nv_local,), dtype=wdt).at[hk_verts].set(
-            hk_c0, mode="drop")
-    else:
-        ckey_h = jnp.take(comm_ref, hd)
-        csrc_h = jnp.take(comm, jnp.minimum(hs, nv_local - 1))
-        c0_heavy = seg.segment_sum(
-            jnp.where(ckey_h == csrc_h, hw, jnp.zeros_like(hw)), hs,
-            num_segments=nv_local,
-        )
+            hk_verts, hk_dT, hk_wT = heavy_kernel
+            safe_hv = jnp.minimum(hk_verts, nv_local - 1)
+            curr_h = jnp.take(comm, safe_hv)
+            vdeg_h = jnp.take(vdeg, safe_hv)
+            # Padding slots (dst == pad id >= nv_local) mask to nv_total: >=
+            # every candidate tile's range, so they are never candidates and
+            # never touch counter0 (w == 0 there anyway).
+            hk_pad = hk_dT >= jnp.asarray(nv_local, hk_dT.dtype)
+            cT = jnp.where(
+                hk_pad, jnp.asarray(nv_total, hk_dT.dtype),
+                jnp.take(comm_ref, jnp.minimum(hk_dT, nv_local - 1)))
+            hk_bc, hk_bg, hk_c0 = heavy_argmax_pallas(
+                cT, hk_wT.astype(wdt), comm_deg, curr_h, vdeg_h,
+                jnp.take(self_loop, safe_hv), own_deg(safe_hv) - vdeg_h,
+                constant, interpret=pallas_interpret)
+            c0_heavy = jnp.zeros((nv_local,), dtype=wdt).at[hk_verts].set(
+                hk_c0, mode="drop")
+        else:
+            ckey_h = jnp.take(comm_ref, hd)
+            csrc_h = jnp.take(comm, jnp.minimum(hs, nv_local - 1))
+            c0_heavy = seg.segment_sum(
+                jnp.where(ckey_h == csrc_h, hw, jnp.zeros_like(hw)), hs,
+                num_segments=nv_local,
+            )
 
     # One pass per bucket: e_ix is row-local (every edge of a bucket vertex
     # lives in its row), so dedup + counter0 + gain + argmax all happen in a
@@ -1003,50 +1006,54 @@ def bucketed_step(bucket_arrays, heavy_arrays, self_loop, comm, vdeg,
                  else [False] * len(bucket_arrays))
     parts = []   # (verts, best_c, best_gain, counter0, best_size|None)
     for i, (verts, dst_mat, w_mat) in enumerate(bucket_arrays):
-        safe_v = jnp.minimum(verts, nv_local - 1)
-        curr = jnp.take(comm, safe_v)
-        if is_pallas[i]:
-            # Kernel classes arrive TRANSPOSED [D, Nb]; the gathers below
-            # stay index-shaped, so the community/ay/size matrices come out
-            # [D, Nb] too.  Works identically single-shard and inside the
-            # shard_map body: replicated mode looks candidate info up in
-            # the psum'd full tables, sparse mode reads the values ATTACHED
-            # to the referenced vertex (extended-local dst indices) and the
-            # kernel additionally tracks the winning community's size for
-            # the singleton guard.
-            from cuvite_tpu.kernels.row_argmax import row_argmax_pallas
+        # Kernel classes arrive transposed [D, Nb]: the width is D.
+        width = dst_mat.shape[0 if is_pallas[i] else 1]
+        with jax.named_scope(f"cuvite.class_w{width}"):
+            safe_v = jnp.minimum(verts, nv_local - 1)
+            curr = jnp.take(comm, safe_v)
+            if is_pallas[i]:
+                # Kernel classes arrive TRANSPOSED [D, Nb]; the gathers
+                # below stay index-shaped, so the community/ay/size
+                # matrices come out [D, Nb] too.  Works identically
+                # single-shard and inside the shard_map body: replicated
+                # mode looks candidate info up in the psum'd full tables,
+                # sparse mode reads the values ATTACHED to the referenced
+                # vertex (extended-local dst indices) and the kernel
+                # additionally tracks the winning community's size for
+                # the singleton guard.
+                from cuvite_tpu.kernels.row_argmax import row_argmax_pallas
 
-            if w_mat.dtype != wdt:   # uint8-compressed unit weights
-                w_mat = w_mat.astype(wdt)
-            cmat_t = jnp.take(comm_ref, dst_mat)   # [D, Nb]
+                if w_mat.dtype != wdt:   # uint8-compressed unit weights
+                    w_mat = w_mat.astype(wdt)
+                cmat_t = jnp.take(comm_ref, dst_mat)   # [D, Nb]
+                vdeg_v = jnp.take(vdeg, safe_v)
+                ayT = (jnp.take(env.cdeg_ext, dst_mat) if use_sparse
+                       else jnp.take(comm_deg, cmat_t))
+                szT = jnp.take(env.csize_ext, dst_mat) if use_sparse else None
+                out = row_argmax_pallas(
+                    cmat_t, w_mat, ayT,
+                    curr, vdeg_v, jnp.take(self_loop, safe_v),
+                    own_deg(safe_v) - vdeg_v, constant, szT=szT,
+                    sentinel=sentinel, interpret=pallas_interpret,
+                )
+                if use_sparse:
+                    bc, bg, c0_rows, bs = out
+                    parts.append((verts, bc.astype(vdt), bg, c0_rows,
+                                  bs.astype(vdt)))
+                else:
+                    bc, bg, c0_rows = out
+                    parts.append((verts, bc.astype(vdt), bg, c0_rows, None))
+                continue
             vdeg_v = jnp.take(vdeg, safe_v)
-            ayT = (jnp.take(env.cdeg_ext, dst_mat) if use_sparse
-                   else jnp.take(comm_deg, cmat_t))
-            szT = jnp.take(env.csize_ext, dst_mat) if use_sparse else None
-            out = row_argmax_pallas(
-                cmat_t, w_mat, ayT,
-                curr, vdeg_v, jnp.take(self_loop, safe_v),
-                own_deg(safe_v) - vdeg_v, constant, szT=szT,
-                sentinel=sentinel, interpret=pallas_interpret,
-            )
-            if use_sparse:
-                bc, bg, c0_rows, bs = out
-                parts.append((verts, bc.astype(vdt), bg, c0_rows,
-                              bs.astype(vdt)))
-            else:
-                bc, bg, c0_rows = out
-                parts.append((verts, bc.astype(vdt), bg, c0_rows, None))
-            continue
-        vdeg_v = jnp.take(vdeg, safe_v)
-        res = _rows_chunked(w_mat, dst_mat,
-                            curr, vdeg_v, jnp.take(self_loop, safe_v),
-                            own_deg(safe_v) - vdeg_v,
-                            constant, sentinel,
-                            lambda dm: jnp.take(comm_ref, dm),
-                            slot_ay, slot_size, wdt,
-                            id_bound=nv_total)
-        parts.append((verts, res.best_c, res.best_gain, res.counter0,
-                      res.best_size))
+            res = _rows_chunked(w_mat, dst_mat,
+                                curr, vdeg_v, jnp.take(self_loop, safe_v),
+                                own_deg(safe_v) - vdeg_v,
+                                constant, sentinel,
+                                lambda dm: jnp.take(comm_ref, dm),
+                                slot_ay, slot_size, wdt,
+                                id_bound=nv_total)
+            parts.append((verts, res.best_c, res.best_gain, res.counter0,
+                          res.best_size))
 
     # Assemble per-vertex results from the per-bucket row vectors.  Bucket
     # membership is phase-static and disjoint, so with ``assemble_perm``
@@ -1054,101 +1061,110 @@ def bucketed_step(bucket_arrays, heavy_arrays, self_loop, comm, vdeg,
     # holds the no-bucket default) assembly is three pure gathers — the
     # scatter-free path.  Without a perm (class-restricted plans) fall back
     # to scatters.
-    if assemble_perm is not None and parts:
-        cat = lambda xs, d: jnp.concatenate(xs + [d])  # noqa: E731
-        d1 = lambda v, dt: jnp.full((1,), v, dtype=dt)  # noqa: E731
-        best_c = jnp.take(
-            cat([p[1] for p in parts], d1(sentinel, vdt)), assemble_perm)
-        best_gain = jnp.take(
-            cat([p[2] for p in parts], neg_inf[None]), assemble_perm)
-        counter0 = c0_heavy + jnp.take(
-            cat([p[3] for p in parts], d1(0, wdt)), assemble_perm)
-        if use_sparse:
-            best_size = jnp.take(
-                cat([p[4] for p in parts], d1(0, vdt)), assemble_perm)
+    with jax.named_scope("cuvite.assemble"):
+        if assemble_perm is not None and parts:
+            cat = lambda xs, d: jnp.concatenate(xs + [d])  # noqa: E731
+            d1 = lambda v, dt: jnp.full((1,), v, dtype=dt)  # noqa: E731
+            best_c = jnp.take(
+                cat([p[1] for p in parts], d1(sentinel, vdt)), assemble_perm)
+            best_gain = jnp.take(
+                cat([p[2] for p in parts], neg_inf[None]), assemble_perm)
+            counter0 = c0_heavy + jnp.take(
+                cat([p[3] for p in parts], d1(0, wdt)), assemble_perm)
+            if use_sparse:
+                best_size = jnp.take(
+                    cat([p[4] for p in parts], d1(0, vdt)), assemble_perm)
+            else:
+                best_size = None
         else:
-            best_size = None
-    else:
-        best_c = jnp.full((nv_local,), sentinel, dtype=vdt)
-        best_gain = jnp.full((nv_local,), neg_inf, dtype=wdt)
-        counter0 = c0_heavy
-        best_size = jnp.zeros((nv_local,), dtype=vdt) if use_sparse else None
-        for verts, bc, bg, c0, bs in parts:
-            best_c = best_c.at[verts].set(bc, mode="drop")
-            best_gain = best_gain.at[verts].set(bg, mode="drop")
-            counter0 = counter0.at[verts].add(c0, mode="drop")
-            if use_sparse and bs is not None:
-                best_size = best_size.at[verts].set(bs, mode="drop")
-    eix = counter0 - self_loop
+            best_c = jnp.full((nv_local,), sentinel, dtype=vdt)
+            best_gain = jnp.full((nv_local,), neg_inf, dtype=wdt)
+            counter0 = c0_heavy
+            best_size = (jnp.zeros((nv_local,), dtype=vdt) if use_sparse
+                         else None)
+            for verts, bc, bg, c0, bs in parts:
+                best_c = best_c.at[verts].set(bc, mode="drop")
+                best_gain = best_gain.at[verts].set(bg, mode="drop")
+                counter0 = counter0.at[verts].add(c0, mode="drop")
+                if use_sparse and bs is not None:
+                    best_size = best_size.at[verts].set(bs, mode="drop")
+        eix = counter0 - self_loop
 
     # ---- heavy vertices ---------------------------------------------------
-    if use_heavy_kernel:
-        # Kernel results scatter to their vertices; everything else keeps
-        # -inf/sentinel so the merge below is a no-op there.  The kernel's
-        # no-candidate sentinel (int max of the id dtype) IS `sentinel`.
-        hg = jnp.full((nv_local,), neg_inf, dtype=wdt).at[hk_verts].set(
-            hk_bg, mode="drop")
-        hc = jnp.full((nv_local,), sentinel, dtype=vdt).at[hk_verts].set(
-            hk_bc.astype(vdt), mode="drop")
-    else:
-        # Sort-based candidates on the heavy edges only (the historical
-        # path; sparse exchange and oversized layouts stay here).
-        if use_sparse:
-            src_s, ckey_s, w_s, ay_s, ts_s = seg.sort_edges_by_vertex_comm(
-                hs, ckey_h, hw, jnp.take(env.cdeg_ext, hd),
-                jnp.take(env.csize_ext, hd),
-                src_bound=nv_local + 1, key_bound=nv_total)
+    with jax.named_scope("cuvite.heavy"):
+        if use_heavy_kernel:
+            # Kernel results scatter to their vertices; everything else keeps
+            # -inf/sentinel so the merge below is a no-op there.  The kernel's
+            # no-candidate sentinel (int max of the id dtype) IS `sentinel`.
+            hg = jnp.full((nv_local,), neg_inf, dtype=wdt).at[hk_verts].set(
+                hk_bg, mode="drop")
+            hc = jnp.full((nv_local,), sentinel, dtype=vdt).at[hk_verts].set(
+                hk_bc.astype(vdt), mode="drop")
         else:
-            src_s, ckey_s, w_s = seg.sort_edges_by_vertex_comm(
-                hs, ckey_h, hw, src_bound=nv_local + 1, key_bound=nv_total)
-        starts = seg.run_starts(src_s, ckey_s)
-        eiy, _ = seg.run_totals(w_s, starts)
-        i_s = jnp.minimum(src_s, nv_local - 1)
-        comm_i = jnp.take(comm, i_s)
-        valid = starts & (src_s < nv_local) & (ckey_s != comm_i)
-        k_i = jnp.take(vdeg, i_s)
-        a_y = ay_s if use_sparse else jnp.take(comm_deg, ckey_s)
-        a_x = own_deg(i_s) - k_i
-        gain = 2.0 * (eiy - jnp.take(eix, i_s)) \
-            - 2.0 * k_i * (a_y - a_x) * constant
-        gain = jnp.where(valid, gain, neg_inf)
-        hg = seg.segment_max(gain, src_s, num_segments=nv_local,
-                             sorted_ids=True)
-        at_best = valid & (gain == jnp.take(hg, i_s))
-        cand_c = jnp.where(at_best, ckey_s, jnp.full_like(ckey_s, sentinel))
-        hc = seg.segment_min(cand_c, src_s, num_segments=nv_local,
-                             sorted_ids=True)
-    heavy_better = hg > best_gain
-    best_gain = jnp.where(heavy_better, hg, best_gain)
-    best_c = jnp.where(heavy_better, hc, best_c)
-    if use_sparse:
-        chosen = at_best & (ckey_s == jnp.take(hc, i_s))
-        ts_cand = jnp.where(chosen, ts_s, jnp.full_like(ts_s, sentinel))
-        h_tsize = seg.segment_min(ts_cand, src_s, num_segments=nv_local,
-                                  sorted_ids=True)
-        best_size = jnp.where(heavy_better, h_tsize, best_size)
+            # Sort-based candidates on the heavy edges only (the historical
+            # path; sparse exchange and oversized layouts stay here).
+            if use_sparse:
+                src_s, ckey_s, w_s, ay_s, ts_s = \
+                    seg.sort_edges_by_vertex_comm(
+                        hs, ckey_h, hw, jnp.take(env.cdeg_ext, hd),
+                        jnp.take(env.csize_ext, hd),
+                        src_bound=nv_local + 1, key_bound=nv_total)
+            else:
+                src_s, ckey_s, w_s = seg.sort_edges_by_vertex_comm(
+                    hs, ckey_h, hw, src_bound=nv_local + 1,
+                    key_bound=nv_total)
+            starts = seg.run_starts(src_s, ckey_s)
+            eiy, _ = seg.run_totals(w_s, starts)
+            i_s = jnp.minimum(src_s, nv_local - 1)
+            comm_i = jnp.take(comm, i_s)
+            valid = starts & (src_s < nv_local) & (ckey_s != comm_i)
+            k_i = jnp.take(vdeg, i_s)
+            a_y = ay_s if use_sparse else jnp.take(comm_deg, ckey_s)
+            a_x = own_deg(i_s) - k_i
+            gain = 2.0 * (eiy - jnp.take(eix, i_s)) \
+                - 2.0 * k_i * (a_y - a_x) * constant
+            gain = jnp.where(valid, gain, neg_inf)
+            hg = seg.segment_max(gain, src_s, num_segments=nv_local,
+                                 sorted_ids=True)
+            at_best = valid & (gain == jnp.take(hg, i_s))
+            cand_c = jnp.where(at_best, ckey_s,
+                               jnp.full_like(ckey_s, sentinel))
+            hc = seg.segment_min(cand_c, src_s, num_segments=nv_local,
+                                 sorted_ids=True)
+        heavy_better = hg > best_gain
+        best_gain = jnp.where(heavy_better, hg, best_gain)
+        best_c = jnp.where(heavy_better, hc, best_c)
+        if use_sparse:
+            chosen = at_best & (ckey_s == jnp.take(hc, i_s))
+            ts_cand = jnp.where(chosen, ts_s, jnp.full_like(ts_s, sentinel))
+            h_tsize = seg.segment_min(ts_cand, src_s, num_segments=nv_local,
+                                      sorted_ids=True)
+            best_size = jnp.where(heavy_better, h_tsize, best_size)
 
     # ---- select + singleton guard (louvain.cpp:2230-2241) ----------------
-    move = best_gain > 0.0
-    best_c_safe = jnp.minimum(best_c, jnp.array(nv_total - 1, dtype=vdt))
-    if use_sparse:
-        t_size = best_size               # propagated from the winning slot
-        c_size = env.csize_v
-    else:
-        t_size = jnp.take(comm_size, best_c_safe)
-        c_size = jnp.take(comm_size, comm)
-    guard = (t_size == 1) & (c_size == 1) & (best_c_safe > comm)
-    move = move & ~guard
-    target = jnp.where(move, best_c_safe, comm)
+    with jax.named_scope("cuvite.select"):
+        move = best_gain > 0.0
+        best_c_safe = jnp.minimum(best_c, jnp.array(nv_total - 1, dtype=vdt))
+        if use_sparse:
+            t_size = best_size           # propagated from the winning slot
+            c_size = env.csize_v
+        else:
+            t_size = jnp.take(comm_size, best_c_safe)
+            c_size = jnp.take(comm_size, comm)
+        guard = (t_size == 1) & (c_size == 1) & (best_c_safe > comm)
+        move = move & ~guard
+        target = jnp.where(move, best_c_safe, comm)
+        n_moved = gsum(jnp.sum(move.astype(jnp.int32)))
 
-    if use_sparse:
-        modularity = sparse_modularity(counter0, env.deg_local, constant,
-                                       red_axes, accum_dtype,
-                                       deg_axis_name=axis_name)
-    else:
-        modularity = seg.modularity_terms(counter0, comm_deg, constant, gsum,
-                                          accum_dtype, axis_name=axis_name)
-    n_moved = gsum(jnp.sum(move.astype(jnp.int32)))
+    with jax.named_scope("cuvite.modularity"):
+        if use_sparse:
+            modularity = sparse_modularity(counter0, env.deg_local, constant,
+                                           red_axes, accum_dtype,
+                                           deg_axis_name=axis_name)
+        else:
+            modularity = seg.modularity_terms(counter0, comm_deg, constant,
+                                              gsum, accum_dtype,
+                                              axis_name=axis_name)
     return target, modularity, n_moved, overflow
 
 

@@ -305,19 +305,21 @@ def _run_phase_loop(extra, comm0, threshold, lower, *, call, max_iters):
         # The overflow flag (sparse-exchange budget) accumulates so the host
         # detects an invalid phase with ONE sync at the end.
         target, mod, moved, step_ovf = call(comm, extra)
-        mod = mod.astype(wdt)
-        no_gain = (mod - prev_mod) < threshold
-        # The no-gain sweep's proposals are rolled back below (new_comm
-        # keeps comm): its row records 0 applied moves, not the
-        # discarded proposal count — moved_total() must equal real
-        # label churn.
-        conv = _conv_push(conv, iters, mod,
-                          jnp.where(no_gain, 0, moved), step_ovf)
-        iters1 = iters + 1
-        stop = no_gain | (iters1 >= max_iters)
-        new_prev = jnp.where(no_gain, prev_mod, jnp.maximum(mod, lower))
-        new_past = jnp.where(no_gain, past, comm)
-        new_comm = jnp.where(no_gain, comm, target)
+        with jax.named_scope("cuvite.select"):
+            mod = mod.astype(wdt)
+            no_gain = (mod - prev_mod) < threshold
+            # The no-gain sweep's proposals are rolled back below
+            # (new_comm keeps comm): its row records 0 applied moves,
+            # not the discarded proposal count — moved_total() must
+            # equal real label churn.
+            conv = _conv_push(conv, iters, mod,
+                              jnp.where(no_gain, 0, moved), step_ovf)
+            iters1 = iters + 1
+            stop = no_gain | (iters1 >= max_iters)
+            new_prev = jnp.where(no_gain, prev_mod,
+                                 jnp.maximum(mod, lower))
+            new_past = jnp.where(no_gain, past, comm)
+            new_comm = jnp.where(no_gain, comm, target)
         return (new_past, new_comm, new_prev, iters1, stop, ovf | step_ovf,
                 conv)
 
@@ -354,43 +356,45 @@ def _run_phase_loop_et(extra, comm0, threshold, lower, active0, et_delta,
     def body(c):
         past, comm, prev_mod, iters, _, ovf, active, p_act, conv = c
         target, mod, _, step_ovf = call(comm, extra)
-        target = jnp.where(active, target, comm)
-        mod = mod.astype(wdt)
-        # Recount APPLIED moves after the freeze mask: the step's n_moved
-        # counts proposals, including frozen vertices whose moves the
-        # mask just discarded — the telemetry rows must reflect real
-        # label churn (non-movers keep target == comm in every step, so
-        # the recount equals sum(active & move)).
-        moved = jnp.sum((target != comm).astype(jnp.int32))
-        iters1 = iters + 1
-        if et_stop:
-            frozen = nv_real - jnp.sum(active.astype(jnp.int32))
-            frozen_stop = frozen.astype(wdt) >= wdt.type(ET_CUTOFF * nv_real)
-        else:
-            frozen_stop = jnp.bool_(False)
-        no_gain = (mod - prev_mod) < threshold
-        stop = no_gain | frozen_stop | (iters1 >= max_iters)
-        cont = ~(no_gain | frozen_stop)
-        # Like the default loop: a stopping sweep's proposals are rolled
-        # back (new_comm keeps comm), so its row records 0 applied moves.
-        conv = _conv_push(conv, iters, mod,
-                          jnp.where(cont, moved, 0), step_ovf)
-        upd = cont & (iters1 > 2)
-        if prob:
-            decayed = active & (comm == past)
-            p_new = jnp.where(upd & decayed, p_act * (1.0 - et_delta),
-                              p_act)
-            freeze = decayed & (p_new <= P_CUTOFF)
-            active_new = jnp.where(upd, active & ~freeze, active)
-            p_act = p_new
-        else:
-            stable = (target == comm) & (comm == past)
-            active_new = jnp.where(upd, active & ~stable, active)
-        new_prev = jnp.where(cont, jnp.maximum(mod, lower), prev_mod)
-        new_past = jnp.where(cont, comm, past)
-        new_comm = jnp.where(cont, target, comm)
-        return (new_past, new_comm, new_prev, iters1, stop,
-                ovf | step_ovf, active_new, p_act, conv)
+        with jax.named_scope("cuvite.select"):
+            target = jnp.where(active, target, comm)
+            mod = mod.astype(wdt)
+            # Recount APPLIED moves after the freeze mask: the step's n_moved
+            # counts proposals, including frozen vertices whose moves the
+            # mask just discarded — the telemetry rows must reflect real
+            # label churn (non-movers keep target == comm in every step, so
+            # the recount equals sum(active & move)).
+            moved = jnp.sum((target != comm).astype(jnp.int32))
+            iters1 = iters + 1
+            if et_stop:
+                frozen = nv_real - jnp.sum(active.astype(jnp.int32))
+                frozen_stop = (frozen.astype(wdt)
+                               >= wdt.type(ET_CUTOFF * nv_real))
+            else:
+                frozen_stop = jnp.bool_(False)
+            no_gain = (mod - prev_mod) < threshold
+            stop = no_gain | frozen_stop | (iters1 >= max_iters)
+            cont = ~(no_gain | frozen_stop)
+            # Like the default loop: a stopping sweep's proposals are rolled
+            # back (new_comm keeps comm), so its row records 0 applied moves.
+            conv = _conv_push(conv, iters, mod,
+                              jnp.where(cont, moved, 0), step_ovf)
+            upd = cont & (iters1 > 2)
+            if prob:
+                decayed = active & (comm == past)
+                p_new = jnp.where(upd & decayed, p_act * (1.0 - et_delta),
+                                  p_act)
+                freeze = decayed & (p_new <= P_CUTOFF)
+                active_new = jnp.where(upd, active & ~freeze, active)
+                p_act = p_new
+            else:
+                stable = (target == comm) & (comm == past)
+                active_new = jnp.where(upd, active & ~stable, active)
+            new_prev = jnp.where(cont, jnp.maximum(mod, lower), prev_mod)
+            new_past = jnp.where(cont, comm, past)
+            new_comm = jnp.where(cont, target, comm)
+            return (new_past, new_comm, new_prev, iters1, stop,
+                    ovf | step_ovf, active_new, p_act, conv)
 
     p0 = jnp.ones_like(comm0, dtype=wdt)
     init = (comm0, comm0, lower, jnp.int32(0), jnp.bool_(False),
@@ -618,9 +622,11 @@ class PhaseRunner:
                     budget = max(128, xplan.nv_pad // 4)
                 budget = min(int(budget), xplan.nv_pad)
                 self.budget = budget
-                plan = build_stacked_plans(dg, exchange_plan=xplan,
-                                           pallas_widths=pallas_widths,
-                                           count_width_edges=use_pallas)
+                with tracer.stage("bucket"):
+                    plan = build_stacked_plans(
+                        dg, exchange_plan=xplan,
+                        pallas_widths=pallas_widths,
+                        count_width_edges=use_pallas)
                 with tracer.stage("upload"):
                     self._send_idx = shard_outer(mesh, xplan.send_idx.reshape(
                         n_dcn * n_dcn, xplan.block))
@@ -647,9 +653,11 @@ class PhaseRunner:
                     budget = max(128, dg.nv_pad // 4)
                 budget = min(int(budget), dg.nv_pad)
                 self.budget = budget
-                plan = build_stacked_plans(dg, exchange_plan=xplan,
-                                           pallas_widths=pallas_widths,
-                                           count_width_edges=use_pallas)
+                with tracer.stage("bucket"):
+                    plan = build_stacked_plans(
+                        dg, exchange_plan=xplan,
+                        pallas_widths=pallas_widths,
+                        count_width_edges=use_pallas)
                 self._send_idx = _place(
                     xplan.send_idx.reshape(S_rows * S, xplan.block))
                 self._ghost_sel = _place(
@@ -660,8 +668,10 @@ class PhaseRunner:
                        len(plan.buckets), nv_total, sentinel, adt_np,
                        budget, plan.pallas_flags, interp)
             else:
-                plan = build_stacked_plans(dg, pallas_widths=pallas_widths,
-                                           count_width_edges=use_pallas)
+                with tracer.stage("bucket"):
+                    plan = build_stacked_plans(
+                        dg, pallas_widths=pallas_widths,
+                        count_width_edges=use_pallas)
                 sparse_cfg = None
                 key = ("bucketed", tuple(d.id for d in mesh.devices.flat),
                        len(plan.buckets), nv_total, sentinel, adt_np,
@@ -760,8 +770,10 @@ class PhaseRunner:
                 self._class_plans = []
                 xp = xplan if use_sparse else None
                 for c in range(n_color_classes):
-                    pc = build_stacked_plans(dg, class_of=color_local,
-                                             class_id=c, exchange_plan=xp)
+                    with tracer.stage("bucket"):
+                        pc = build_stacked_plans(dg, class_of=color_local,
+                                                 class_id=c,
+                                                 exchange_plan=xp)
                     bk = tuple(
                         (_place(v.astype(vdt)), _place(d.astype(vdt)),
                          _place(ww.astype(
@@ -860,10 +872,11 @@ class PhaseRunner:
                 hk_dev = None
                 self._heavy_kernel = None
             else:
-                plan = BucketPlan.build(
-                    np.asarray(sh.src), np.asarray(sh.dst),
-                    np.asarray(sh.w), nv_local=dg.nv_pad, base=0,
-                )
+                with tracer.stage("bucket"):
+                    plan = BucketPlan.build(
+                        np.asarray(sh.src), np.asarray(sh.dst),
+                        np.asarray(sh.w), nv_local=dg.nv_pad, base=0,
+                    )
                 use_pallas = engine == "pallas" and not class_sched
                 # Opt-in heavy-class kernel policy, decided up front: it
                 # engages on the plain bucketed engine too, and a
@@ -980,8 +993,9 @@ class PhaseRunner:
                              _up(plan.heavy_dst, vdt),
                              _up(plan.heavy_w, wdt))
                 self_loop = _up(plan.self_loop, wdt)
-                perm_dev = _up(
-                    build_assemble_perm(verts_np, dg.nv_pad))
+                with tracer.stage("bucket"):
+                    perm_np = build_assemble_perm(verts_np, dg.nv_pad)
+                perm_dev = _up(perm_np)
             adt_np = adt
 
             def _step(src_, dst_, w_, comm, vdeg_, constant):
@@ -1016,8 +1030,9 @@ class PhaseRunner:
                 for c in range(n_color_classes):
                     src_c = np.where(src_cls == c, src_np,
                                      dg.nv_pad).astype(src_np.dtype)
-                    pc = BucketPlan.build(src_c, dst_np, w_np,
-                                          nv_local=dg.nv_pad, base=0)
+                    with tracer.stage("bucket"):
+                        pc = BucketPlan.build(src_c, dst_np, w_np,
+                                              nv_local=dg.nv_pad, base=0)
                     bk = tuple((_up(b.verts, vdt),
                                 _up(b.dst, vdt),
                                 _up(b.w, wdt))
@@ -2061,12 +2076,13 @@ def louvain_phases(
             elif g_is_dv:
                 dg = g
             else:
-                dg = DistGraph.build(
-                    g, nshards, balanced=balanced,
-                    min_nv_pad=max(1, 4096 // nshards),
-                    min_ne_pad=max(1, 16384 // nshards),
-                    pad_edges=not slabless,
-                )
+                with tracer.stage("partition"):
+                    dg = DistGraph.build(
+                        g, nshards, balanced=balanced,
+                        min_nv_pad=max(1, 4096 // nshards),
+                        min_ne_pad=max(1, 16384 // nshards),
+                        pad_edges=not slabless,
+                    )
         if exchange == "auto":
             # Per PHASE: coarse phases of a huge graph shrink back under
             # the cutover and get the cheaper replicated exchange.

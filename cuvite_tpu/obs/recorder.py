@@ -74,7 +74,13 @@ class FlightRecorder:
             import jax
 
             os.makedirs(self.profile_dir, exist_ok=True)
-            jax.profiler.start_trace(self.profile_dir)
+            # No Python-level tracing: it would swamp the trace with
+            # interpreter events and stretch the host stages read
+            # against the device.
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(self.profile_dir,
+                                     profiler_options=opts)
             self._profiling = True
             if self.emitter is not None:
                 self.emitter.event("profiler_start", dir=self.profile_dir)

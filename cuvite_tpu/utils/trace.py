@@ -11,6 +11,13 @@ Here that collapses into one Tracer object: named accumulating stage
 timers (wall clock; device work is timed around blocking host syncs, the
 only boundaries that exist under jit), RSS high-water, TEPS accounting
 (main.cpp:448, :509), and optional per-shard diag files.
+
+An enabled Tracer also writes every span it opens into the JAX
+profiler's trace (``jax.profiler.TraceAnnotation``): ``stage.<name>``
+for a stage, the bare name for an envelope span (``phase``, ``pack``,
+...).  Both sinks, the profiler's and the flight recorder's, are fed
+from one call, so they bracket the same code, and a profile of the run
+puts the host stages on the device trace's clock.
 """
 
 from __future__ import annotations
@@ -20,6 +27,18 @@ import math
 import os
 import resource
 import time
+
+# Profiler annotation of a stage; the benchmark's trace reduction reads
+# spans with this prefix as stages.
+STAGE_PREFIX = "stage."
+
+
+def _annotation(name: str):
+    """An unopened ``jax.profiler.TraceAnnotation``.  jax is imported
+    here, not at the top, so this module imports without it."""
+    import jax.profiler
+
+    return jax.profiler.TraceAnnotation(name)
 
 
 def rss_high_water_mb() -> float:
@@ -39,7 +58,8 @@ class Tracer:
     drivers' telemetry (convergence rows, exchange-plan stats, memory
     snapshots) lands in the record stream.  Without a recorder those
     calls are no-ops — the drivers thread them unconditionally at zero
-    cost.
+    cost — except that an enabled tracer's spans still reach the
+    profiler.
 
     Usage::
 
@@ -66,16 +86,17 @@ class Tracer:
             yield
             return
         em = self.emitter
-        sid = em.begin(name) if em is not None else None
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            if em is not None:
-                em.end(sid, dur_s=dt)
-            self.times[name] = self.times.get(name, 0.0) + dt
-            self.calls[name] = self.calls.get(name, 0) + 1
+        with _annotation(STAGE_PREFIX + name):
+            sid = em.begin(name) if em is not None else None
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                dt = time.perf_counter() - t0
+                if em is not None:
+                    em.end(sid, dur_s=dt)
+                self.times[name] = self.times.get(name, 0.0) + dt
+                self.calls[name] = self.calls.get(name, 0) + 1
 
     def count(self, name: str, value: float = 1) -> None:
         if self.enabled:
@@ -91,14 +112,25 @@ class Tracer:
     def begin_span(self, name: str, **attrs):
         """Open a span whose extent cannot be a ``with`` block (the
         driver's per-phase envelope spans a loop body with breaks).
-        Returns an opaque handle for :meth:`end_span`."""
-        if self.emitter is not None:
-            return self.emitter.begin(name, **attrs)
-        return None
+        Returns an opaque handle for :meth:`end_span`: the emitter's span
+        id and the open profiler annotation (None when disabled)."""
+        if not self.enabled:
+            return None
+        ann = _annotation(name)
+        ann.__enter__()
+        sid = self.emitter.begin(name, **attrs) \
+            if self.emitter is not None else None
+        return sid, ann
 
     def end_span(self, handle, **attrs) -> None:
-        if self.emitter is not None and handle is not None:
-            self.emitter.end(handle, **attrs)
+        if handle is None:
+            return
+        sid, ann = handle
+        try:
+            if self.emitter is not None and sid is not None:
+                self.emitter.end(sid, **attrs)
+        finally:
+            ann.__exit__(None, None, None)
 
     def set_phase(self, phase) -> None:
         """Tag subsequent records with the running phase index."""
@@ -133,7 +165,14 @@ class Tracer:
     # upload_s — host->device placement of slabs/plans; iterate_s — the
     # jitted phase loops.  Note upload runs NESTED inside the driver's
     # plan stage on the per-phase engine path, so there plan_s CONTAINS
-    # upload_s (the fused driver's stages are disjoint).
+    # upload_s (the fused driver's stages are disjoint).  Two more
+    # stages nest in the per-phase plan and are not canonical (absent
+    # on the fused driver): partition — the host DistGraph.build — and
+    # bucket — the host BucketPlan.build / build_stacked_plans degree
+    # bucketing and its assembly permutation.  So on that path plan_s
+    # CONTAINS partition_s, bucket_s, upload_s and rebin_s, all four
+    # disjoint.  evaluate (the per-phase modularity recompute) stands
+    # beside iterate and coarsen.
     CANONICAL_STAGES = ("coarsen", "coalesce", "rebin", "upload",
                         "iterate")
 

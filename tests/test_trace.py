@@ -3,6 +3,7 @@
 import os
 
 import numpy as np
+import pytest
 
 from cuvite_tpu.louvain.driver import louvain_phases
 from cuvite_tpu.utils.trace import NullTracer, Tracer, rss_high_water_mb
@@ -171,3 +172,139 @@ def test_cli_dist_stats_flag(tmp_path, karate, capsys):
           "--quiet"])
     out = capsys.readouterr().out
     assert "Graph edge distribution characteristics" in out
+
+
+class _CountingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records each
+    name as it opens and closes."""
+
+    def __init__(self, log, name):
+        self.log, self.name = log, name
+
+    def __enter__(self):
+        self.log.append(("open", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("close", self.name))
+        return False
+
+
+def _count_annotations(monkeypatch):
+    import jax.profiler
+
+    log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda name: _CountingAnnotation(log, name))
+    return log
+
+
+def test_stage_opens_one_profiler_annotation(monkeypatch):
+    log = _count_annotations(monkeypatch)
+    with Tracer().stage("x"):
+        assert log == [("open", "stage.x")]
+    assert log == [("open", "stage.x"), ("close", "stage.x")]
+    log.clear()
+    with NullTracer().stage("x"):
+        pass
+    assert log == []
+
+
+def test_stage_annotation_closes_on_exception(monkeypatch):
+    log = _count_annotations(monkeypatch)
+    tr = Tracer()
+    try:
+        with tr.stage("x"):
+            raise RuntimeError("boom")
+    except RuntimeError:
+        pass
+    assert log == [("open", "stage.x"), ("close", "stage.x")]
+    assert tr.calls["x"] == 1
+
+
+def test_envelope_span_is_an_unprefixed_annotation(monkeypatch):
+    """begin_span/end_span open and close one annotation named after the
+    span (no ``stage.`` prefix), on the normal and the exception path,
+    with and without a flight recorder; a disabled tracer opens none."""
+    from cuvite_tpu.obs import FlightRecorder, spans_of, validate_trace
+
+    log = _count_annotations(monkeypatch)
+    with FlightRecorder() as rec:
+        for tr in (Tracer(), Tracer(recorder=rec)):
+            h = tr.begin_span("phase", index=0)
+            with tr.stage("plan"):
+                pass
+            tr.end_span(h, gained=True)
+            try:
+                h = tr.begin_span("pack")
+                raise ValueError("mid-span")
+            except ValueError:
+                pass
+            finally:
+                tr.end_span(h, error="mid-span")
+    assert log == [("open", "phase"), ("open", "stage.plan"),
+                   ("close", "stage.plan"), ("close", "phase"),
+                   ("open", "pack"), ("close", "pack")] * 2
+    assert validate_trace(rec.records) == []
+    assert len(spans_of(rec.records, "phase")) == 1
+    assert len(spans_of(rec.records, "pack")) == 1
+    log.clear()
+    tr = NullTracer()
+    tr.end_span(tr.begin_span("phase"))
+    assert log == []
+
+
+def test_plan_build_leaf_stages(karate):
+    """The per-phase plan stage holds the partition (DistGraph.build) and
+    the host degree bucketing as stages of their own, strictly inside it;
+    tracing leaves the labels as they are."""
+    from cuvite_tpu.obs import FlightRecorder, spans_of, validate_trace
+
+    with FlightRecorder() as rec:
+        tr = Tracer(recorder=rec)
+        res = louvain_phases(karate, engine="bucketed", tracer=tr)
+    plain = louvain_phases(karate, engine="bucketed")
+    np.testing.assert_array_equal(res.communities, plain.communities)
+    assert res.modularity == plain.modularity
+    assert validate_trace(rec.records) == []
+    plans = {s["id"]: s for s in spans_of(rec.records, "plan")}
+    for leaf in ("partition", "bucket"):
+        spans = spans_of(rec.records, leaf)
+        assert spans, leaf
+        for s in spans:
+            parent = plans[s["begin"]["parent"]]
+            assert s["end"]["dur_s"] < parent["end"]["dur_s"], leaf
+    assert tr.times["partition"] + tr.times["bucket"] < tr.times["plan"]
+
+
+SCOPES = ("cuvite.tables", "cuvite.heavy", "cuvite.assemble",
+          "cuvite.select", "cuvite.modularity")
+
+
+@pytest.mark.parametrize("engine", ["bucketed", "pallas"])
+def test_phase_loop_carries_named_scopes(karate, engine):
+    """The lowered bucketed phase loop names each part of the step: the
+    community tables, one scope per degree class of the plan (XLA rows
+    and Pallas kernel classes alike), the heavy residual, assembly,
+    select and the modularity pass."""
+    import re
+
+    from cuvite_tpu.core.distgraph import DistGraph
+    from cuvite_tpu.louvain.driver import (
+        MAX_TOTAL_ITERATIONS, PhaseRunner, _run_phase_loop)
+
+    dg = DistGraph.build(karate, 1, min_nv_pad=4096, min_ne_pad=16384)
+    runner = PhaseRunner(dg, engine=engine)
+    wdt = np.dtype(runner.constant.dtype)
+    text = _run_phase_loop.lower(
+        runner._extra, runner.comm0, np.asarray(1e-6, dtype=wdt),
+        np.asarray(-1.0, dtype=wdt), call=runner._call,
+        max_iters=MAX_TOTAL_ITERATIONS).as_text(debug_info=True)
+    for scope in SCOPES:
+        assert scope in text, scope
+    # Kernel classes are stored transposed, [width, rows].
+    widths = [d.shape[0] if d.shape[1] == len(v) else d.shape[1]
+              for v, d, _w in runner._bucket_extra[0]]
+    assert len(widths) >= 2
+    assert {int(w) for w in re.findall(r"cuvite\.class_w(\d+)", text)} \
+        == set(widths)
