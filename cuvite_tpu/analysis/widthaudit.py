@@ -203,8 +203,7 @@ def sort_facts(jaxpr) -> list:
     equation in the trace — the observable that proves which comparator
     was selected.  ``key_ndim`` separates the 1-D edge-slab sort (the
     kbits+sbits pack under audit) from the bucketed row-argmax's 2-D
-    ``(cmat << bits) | iota`` sort, which packs over the ROW width
-    under its own ``(id_bound << bits) <= 2^31`` predicate."""
+    per-row sort, whose int32 key is the community id itself."""
     facts = []
     for eqn in _walk_eqns(jaxpr):
         if eqn.primitive.name != "sort":

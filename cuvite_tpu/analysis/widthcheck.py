@@ -122,7 +122,6 @@ PARAM_BOUND_SYMBOLS = {
     "sbits": "sbits",
     "key_bound": "nv_pad",
     "src_bound": "nv_pad",
-    "id_bound": "nv_pad",
     "sentinel": "nv_pad",
     "b": "B",
 }

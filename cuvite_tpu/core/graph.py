@@ -33,6 +33,11 @@ class Graph:
         self.offsets = np.ascontiguousarray(self.offsets, dtype=np.int64)
         self.tails = np.ascontiguousarray(self.tails, dtype=self.policy.vertex_dtype)
         self.weights = np.ascontiguousarray(self.weights, dtype=self.policy.weight_dtype)
+        # Every path into the Louvain step starts from a Graph, and the
+        # step's sorted dedup reads run sums off suffix sums that must not
+        # increase along a row (louvain/bucketed.py::_row_argmax_sorted).
+        if not np.all(self.weights >= 0):
+            raise ValueError("edge weights must be non-negative numbers")
 
     @property
     def num_vertices(self) -> int:

@@ -70,8 +70,8 @@ def _env_int(name: str, default: int) -> int:
 
 # Dedup-kernel cutover (env-tunable for on-chip A/B): rows of width <=
 # QUADRATIC_MAX_WIDTH dedup by the all-pairs compare (VPU/MXU-friendly
-# O(D^2) with zero sorts/scans/gathers); wider rows take the packed
-# per-row sort.  The crossover is hardware-dependent — the TPU vector
+# O(D^2) with zero sorts/scans/gathers); wider rows take the per-row sort
+# (_row_argmax_sorted).  The crossover is hardware-dependent — the TPU vector
 # units tolerate much larger D^2 than a scalar CPU does — so it is a
 # load-time knob rather than a constant.
 QUADRATIC_MAX_WIDTH = _env_int("CUVITE_QUAD_MAX", 32)
@@ -624,46 +624,35 @@ def _row_argmax(cmat, wmat, aymat, smat, curr_comm, vdeg_v, sl_v, ax_v,
 
 
 def _row_argmax_sorted(cmat, wmat, aymat, smat, curr_comm, vdeg_v, sl_v,
-                       ax_v, constant, sentinel, id_bound=None):
+                       ax_v, constant, sentinel):
     """Dedup + dQ + argmax for wide rows via a per-row sort.
 
     O(D log^2 D) per row instead of the all-pairs O(D^2): sort each row by
-    community id, detect runs, and compute run sums with a reverse cumsum +
-    next-leader index (reverse cummin) — all lane-parallel scans.  This is
+    community id, detect runs, and compute run sums with a reverse cumsum
+    and a reverse running max — all lane-parallel scans.  This is
     the TPU counterpart of the reference's medium/large GPU kernels
     (/root/reference/louvain_cuda.cu:1024-1346).
 
-    When every community id provably fits in ``31 - bits(D)`` bits
-    (``id_bound``, static), the sort runs on ONE packed int32 key
-    ``(c << bits) | slot`` and the payloads follow by take_along_axis —
-    measured 4-5x faster than the multi-operand comparator sort, with
-    bit-identical results (packed keys are unique, so the stable order by
-    (c, slot) equals the stable order by c).
+    No per-row gather: the payloads ride the sort as operands of ONE
+    stable ``lax.sort(..., num_keys=1)``, and the run sums need no
+    next-leader index.  A packed ``(c << bits) | slot`` key with
+    ``take_along_axis`` payload gathers gives the same permutation, but on
+    a TPU v5e it cost 45 ns per slot-iteration against 25 for the sorted
+    operands (PERF.md section 5).
+
+    Needs non-negative weights (``Graph`` refuses others).  The run sums
+    equal the next-leader-gather formulation's bit for bit wherever the
+    suffix sums are exact, as they are for integer weights and every
+    coarsening of them.
     """
     wdt = wmat.dtype
-    D = cmat.shape[1]
     # counter0 in UNSORTED slot order (the historical outer-pass order, so
     # modularity and e_ix stay bit-identical to the two-pass formulation).
     counter0 = jnp.sum(
         jnp.where(cmat == curr_comm[:, None], wmat, 0.0), axis=1
     ).astype(wdt)
     eix_v = counter0 - sl_v
-    bits = (D - 1).bit_length()
-    packable = (
-        id_bound is not None
-        and cmat.dtype == jnp.int32
-        and (int(id_bound) << bits) <= (1 << 31)
-    )
-    if packable:
-        iota = jax.lax.broadcasted_iota(jnp.int32, cmat.shape, 1)
-        k_s = jax.lax.sort((cmat << bits) | iota, dimension=1)
-        slot = k_s & ((1 << bits) - 1)
-        c_s = k_s >> bits
-        w_s = jnp.take_along_axis(wmat, slot, axis=1)
-        ay_s = jnp.take_along_axis(aymat, slot, axis=1)
-        s_s = (jnp.take_along_axis(smat, slot, axis=1)
-               if smat is not None else None)
-    elif smat is not None:
+    if smat is not None:
         c_s, w_s, ay_s, s_s = jax.lax.sort(
             (cmat, wmat, aymat, smat), dimension=1, num_keys=1)
     else:
@@ -673,17 +662,18 @@ def _row_argmax_sorted(cmat, wmat, aymat, smat, curr_comm, vdeg_v, sl_v,
         [jnp.ones_like(c_s[:, :1], dtype=bool), c_s[:, 1:] != c_s[:, :-1]],
         axis=1,
     )
-    pos = jax.lax.broadcasted_iota(jnp.int32, c_s.shape, 1)
-    leaderpos = jnp.where(leader, pos, D)
-    # next leader strictly to the right of j (D if none)
-    nxt = jnp.flip(jax.lax.cummin(jnp.flip(leaderpos, 1), axis=1), 1)
-    nxt = jnp.concatenate(
-        [nxt[:, 1:], jnp.full_like(nxt[:, :1], D)], axis=1
-    )
-    # suffix sums S[j] = sum_{k >= j} w; S_ext has trailing 0 column
+    # suffix sums S[j] = sum_{k >= j} w
     suf = jnp.flip(jnp.cumsum(jnp.flip(w_s, 1), axis=1), 1)
-    suf_ext = jnp.concatenate([suf, jnp.zeros_like(suf[:, :1])], axis=1)
-    run_sum = suf - jnp.take_along_axis(suf_ext, nxt, axis=1)
+    # S at the next leader strictly right of j (0 if none).  Weights are
+    # non-negative, so S never increases along the row and that is the
+    # largest S over the leaders right of j: a reverse running max, with
+    # the appended 0 standing in past the row's end.
+    lead_suf = jnp.where(leader, suf, jnp.array(-jnp.inf, dtype=wdt))
+    nxt_suf = jax.lax.cummax(
+        jnp.concatenate([lead_suf[:, 1:], jnp.zeros_like(suf[:, :1])],
+                        axis=1),
+        axis=1, reverse=True)
+    run_sum = suf - nxt_suf
 
     is_cc = c_s == curr_comm[:, None]
     # No w>0 filter — see _row_argmax; padding self-slots are is_cc-masked.
@@ -727,7 +717,7 @@ def _map_chunks(fn, nb, chunk, row_arrays):
 
 def _rows_chunked(w_mat, dst_mat, curr, vdeg_v, sl_v, ax_v,
                   constant, sentinel, gather_cm, gather_ay, gather_sz,
-                  wdt, id_bound=None):
+                  wdt):
     """Dispatch rows to the right dedup variant, chunked with lax.map to
     bound intermediate memory.  Every O(rows x D) operand that is not a
     phase-static plan constant is produced INSIDE the chunk body:
@@ -741,7 +731,7 @@ def _rows_chunked(w_mat, dst_mat, curr, vdeg_v, sl_v, ax_v,
     ``gather_sz`` may return None in replicated mode."""
     nb, width = dst_mat.shape
     kernel = (_row_argmax if width <= QUADRATIC_MAX_WIDTH
-              else functools.partial(_row_argmax_sorted, id_bound=id_bound))
+              else _row_argmax_sorted)
 
     def run(wm, dm, cu, vd, sl, ax):
         if wm.dtype != wdt:  # uint8-compressed unit weights
@@ -1050,8 +1040,7 @@ def bucketed_step(bucket_arrays, heavy_arrays, self_loop, comm, vdeg,
                                 own_deg(safe_v) - vdeg_v,
                                 constant, sentinel,
                                 lambda dm: jnp.take(comm_ref, dm),
-                                slot_ay, slot_size, wdt,
-                                id_bound=nv_total)
+                                slot_ay, slot_size, wdt)
             parts.append((verts, res.best_c, res.best_gain, res.counter0,
                           res.best_size))
 

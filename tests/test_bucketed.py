@@ -1,6 +1,8 @@
 """The bucketed engine must be step-for-step identical to the sort engine
 (and therefore to the reference-semantics oracle)."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -249,3 +251,182 @@ def test_build_assemble_perm_properties():
     # not in any bucket -> default slot
     for v in (0, 4, 6, 8, 9):
         assert perm[v] == total, (v, perm[v])
+
+
+def _row_argmax_packed(cmat, wmat, aymat, smat, curr_comm, vdeg_v, sl_v,
+                       ax_v, constant, sentinel, id_bound):
+    """The earlier sorted dedup, kept as the parity oracle: one packed
+    int32 key ``(c << bits) | slot``, the payloads and the suffix sum at
+    the next leader read back by per-row ``take_along_axis``."""
+    import jax
+    import jax.numpy as jnp
+    from cuvite_tpu.louvain.bucketed import RowResult
+
+    wdt = wmat.dtype
+    D = cmat.shape[1]
+    counter0 = jnp.sum(
+        jnp.where(cmat == curr_comm[:, None], wmat, 0.0), axis=1
+    ).astype(wdt)
+    eix_v = counter0 - sl_v
+    bits = (D - 1).bit_length()
+    assert (int(id_bound) << bits) <= (1 << 31)
+    iota = jax.lax.broadcasted_iota(jnp.int32, cmat.shape, 1)
+    k_s = jax.lax.sort((cmat << bits) | iota, dimension=1)
+    slot = k_s & ((1 << bits) - 1)
+    c_s = k_s >> bits
+    w_s = jnp.take_along_axis(wmat, slot, axis=1)
+    ay_s = jnp.take_along_axis(aymat, slot, axis=1)
+    s_s = (jnp.take_along_axis(smat, slot, axis=1)
+           if smat is not None else None)
+    leader = jnp.concatenate(
+        [jnp.ones_like(c_s[:, :1], dtype=bool), c_s[:, 1:] != c_s[:, :-1]],
+        axis=1)
+    pos = jax.lax.broadcasted_iota(jnp.int32, c_s.shape, 1)
+    nxt = jnp.flip(jax.lax.cummin(
+        jnp.flip(jnp.where(leader, pos, D), 1), axis=1), 1)
+    nxt = jnp.concatenate([nxt[:, 1:], jnp.full_like(nxt[:, :1], D)], axis=1)
+    suf = jnp.flip(jnp.cumsum(jnp.flip(w_s, 1), axis=1), 1)
+    suf_ext = jnp.concatenate([suf, jnp.zeros_like(suf[:, :1])], axis=1)
+    run_sum = suf - jnp.take_along_axis(suf_ext, nxt, axis=1)
+    valid = leader & (c_s != curr_comm[:, None])
+    gain = 2.0 * (run_sum - eix_v[:, None]) \
+        - 2.0 * vdeg_v[:, None] * (ay_s - ax_v[:, None]) * constant
+    gain = jnp.where(valid, gain, jnp.array(-jnp.inf, dtype=wdt))
+    best_gain = jnp.max(gain, axis=1)
+    at_best = valid & (gain == best_gain[:, None])
+    best_c = jnp.min(jnp.where(at_best, c_s, sentinel), axis=1)
+    best_size = None
+    if smat is not None:
+        best_size = jnp.min(
+            jnp.where(c_s == best_c[:, None], s_s, sentinel), axis=1)
+    return RowResult(best_c=best_c, best_gain=best_gain, counter0=counter0,
+                     best_size=best_size)
+
+
+_NV_ROWS = 1000   # community ids of the parity rows
+
+
+def _dedup_rows(width, weights, sparse, seed=0):
+    """Rows for the dedup parity: repeated communities (runs), the row's own
+    community (and rows made only of it: no candidate), zero-weight slots,
+    and a three-valued degree table so candidate gains tie.  Weights are
+    uint8 {0, 1} widened as the step widens them, or quarter-valued floats:
+    every sum of them is exact in float32, so the all-pairs einsum and the
+    suffix-sum difference agree bit for bit.  ``vdeg_v``/``ax_v`` are small
+    dyadics so the gain's product rounds nowhere."""
+    rng = np.random.default_rng(seed + width)
+    rows = 3 if width > 1024 else 9
+    own = rng.integers(0, _NV_ROWS, size=rows).astype(np.int32)
+    pool = rng.integers(0, _NV_ROWS, size=(rows, 6)).astype(np.int32)
+    pick = rng.integers(0, pool.shape[1], size=(rows, width))
+    cmat = np.take_along_axis(pool, pick, axis=1)
+    fresh = rng.random((rows, width)) < 0.4
+    cmat = np.where(fresh, rng.integers(0, _NV_ROWS, (rows, width)), cmat)
+    cmat = np.where(rng.random((rows, width)) < 0.15, own[:, None], cmat)
+    cmat[0] = own[0]
+    cmat = cmat.astype(np.int32)
+    if weights == "unit":
+        w = (rng.random((rows, width)) < 0.8).astype(np.uint8)
+    else:
+        w = rng.choice(np.float32([0.0, 0.25, 0.5, 1.5, 2.0, 3.75]),
+                       size=(rows, width))
+    deg_of = rng.choice(np.float32([1.0, 2.0, 3.0]), size=_NV_ROWS)
+    size_of = rng.integers(1, 4, size=_NV_ROWS).astype(np.int32)
+    return dict(
+        cmat=cmat, wmat=w.astype(np.float32), aymat=deg_of[cmat],
+        smat=size_of[cmat] if sparse else None, curr_comm=own,
+        vdeg_v=rng.choice(np.float32([0.5, 1.0, 2.25]), size=rows),
+        sl_v=rng.choice(np.float32([0.0, 0.25]), size=rows),
+        ax_v=rng.choice(np.float32([0.0, 1.0, 2.0]), size=rows),
+        constant=np.float32(2.0 ** -6), sentinel=np.iinfo(np.int32).max)
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.uint32) if x.dtype == np.float32 else x
+
+
+@pytest.mark.parametrize("sparse", [False, True], ids=["replicated", "sparse"])
+@pytest.mark.parametrize("weights", ["unit", "float"])
+@pytest.mark.parametrize("width", [64, 128, 384, 4096])
+def test_sorted_dedup_matches_all_pairs_and_packed(width, weights, sparse):
+    """The sorted dedup (payloads as sort operands, running-max run sums)
+    equals the all-pairs dedup and the earlier packed-key formulation bit
+    for bit in every output."""
+    import jax
+    import jax.numpy as jnp
+    from cuvite_tpu.louvain.bucketed import _row_argmax, _row_argmax_sorted
+
+    a = _dedup_rows(width, weights, sparse)
+    arrays = {k: (None if v is None else jnp.asarray(v))
+              for k, v in a.items() if k not in ("constant", "sentinel")}
+    order = ("cmat", "wmat", "aymat", "smat", "curr_comm", "vdeg_v", "sl_v",
+             "ax_v")
+
+    def call(fn, **kw):
+        return jax.jit(lambda *xs: fn(*xs, a["constant"], a["sentinel"],
+                                      **kw))(*(arrays[k] for k in order))
+
+    got = call(_row_argmax_sorted)
+    for name, want in (
+            ("all-pairs", call(_row_argmax)),
+            ("packed", call(_row_argmax_packed, id_bound=_NV_ROWS))):
+        for field in ("best_c", "best_gain", "counter0", "best_size"):
+            g, w = getattr(got, field), getattr(want, field)
+            if not sparse and field == "best_size":
+                assert g is None and w is None
+                continue
+            np.testing.assert_array_equal(
+                _bits(g), _bits(w), err_msg=f"{field} differs from {name}")
+    # The rows exercise what they claim: a row with no candidate, and ties.
+    assert int(got.best_c[0]) == a["sentinel"]
+    assert np.isneginf(float(got.best_gain[0]))
+
+
+def _gather_operand_ranks(fn, args):
+    import jax
+    from cuvite_tpu.analysis.widthaudit import _walk_eqns
+
+    # A fresh wrapper per trace: make_jaxpr caches by function object.
+    jaxpr = jax.make_jaxpr(lambda *xs: fn(*xs))(*args)
+    return [len(e.invars[0].aval.shape) for e in _walk_eqns(jaxpr)
+            if e.primitive.name == "gather"]
+
+
+@pytest.mark.parametrize("sparse", [False, True], ids=["replicated", "sparse"])
+def test_sorted_dedup_gathers_only_tables(sparse):
+    """Traced through the step's chunk dispatch, the sorted dedup gathers
+    from the 1-D community tables only: no gather reads a [rows, D] row
+    matrix.  The packed oracle, traced the same way, does (the guard
+    sees row gathers)."""
+    import jax.numpy as jnp
+    import cuvite_tpu.louvain.bucketed as bk
+
+    rows, width, nv = 64, 256, 4096
+    comm = jnp.arange(nv, dtype=jnp.int32)
+    cdeg = jnp.ones((nv,), jnp.float32)
+    csize = jnp.ones((nv,), jnp.int32)
+
+    def step(w, dst, curr, vdeg_v, sl_v, ax_v):
+        return bk._rows_chunked(
+            w, dst, curr, vdeg_v, sl_v, ax_v, jnp.float32(1.0),
+            np.iinfo(np.int32).max, lambda dm: jnp.take(comm, dm),
+            lambda dm, cm: jnp.take(cdeg, cm),
+            (lambda dm, cm: jnp.take(csize, dm)) if sparse else
+            (lambda dm, cm: None), jnp.float32)
+
+    args = (jnp.ones((rows, width), jnp.uint8),
+            jnp.zeros((rows, width), jnp.int32),
+            jnp.zeros((rows,), jnp.int32), jnp.ones((rows,), jnp.float32),
+            jnp.zeros((rows,), jnp.float32), jnp.zeros((rows,), jnp.float32))
+    ranks = _gather_operand_ranks(step, args)
+    assert ranks and set(ranks) == {1}, ranks
+    assert len(ranks) == (3 if sparse else 2)
+
+    sorted_fn = bk._row_argmax_sorted
+    try:
+        bk._row_argmax_sorted = functools.partial(_row_argmax_packed,
+                                                  id_bound=nv)
+        assert 2 in _gather_operand_ranks(step, args)
+    finally:
+        bk._row_argmax_sorted = sorted_fn

@@ -1,6 +1,7 @@
 """Unit tests: CSR construction, invariants, Vite I/O round-trip."""
 
 import numpy as np
+import pytest
 
 from cuvite_tpu.core.distgraph import DistGraph, balanced_parts, uniform_parts
 from cuvite_tpu.core.graph import Graph
@@ -41,6 +42,17 @@ def test_duplicate_edges_coalesce():
     g = Graph.from_edges(2, [0, 0], [1, 1])
     assert g.num_edges == 2  # one per direction
     np.testing.assert_allclose(g.weights, [2.0, 2.0])
+
+
+@pytest.mark.parametrize("bad", [-1.0, np.nan])
+def test_negative_or_nan_weight_refused(bad):
+    """The step's sorted dedup needs non-negative weights; a Graph, which
+    every path into it starts from, refuses anything else."""
+    with pytest.raises(ValueError, match="non-negative"):
+        Graph.from_edges(3, [0, 1], [1, 2], weights=[1.0, bad])
+    with pytest.raises(ValueError, match="non-negative"):
+        Graph(np.array([0, 1, 1, 1]), np.array([1]), np.array([bad]))
+    assert Graph.from_edges(3, [0, 1], [1, 2], weights=[1.0, 0.0]).num_edges
 
 
 def test_vite_roundtrip(tmp_path, karate):

@@ -165,8 +165,8 @@ def main(cases=DEFAULT_CASES, repeats=5):
         wT = jnp.asarray(np.ascontiguousarray(wmat.T))
         cd = jnp.asarray(comm_deg)
         cu, vd, slj, axj = map(jnp.asarray, (curr, vdeg, sl, ax))
-        # XLA twin: the per-row packed single-key sort path the heavy
-        # residual rides by default, on identical rows.
+        # XLA twin: the per-row sorted dedup (payloads as sort
+        # operands), on identical rows.
         cm = jnp.asarray(cmat)
         wm = jnp.asarray(wmat)
         ay = jnp.asarray(comm_deg[cmat])
@@ -179,7 +179,7 @@ def main(cases=DEFAULT_CASES, repeats=5):
         def run_sorted():
             out["s"] = jax.block_until_ready(_row_argmax_sorted(
                 cm, wm, ay, None, cu, vd, slj, axj, const,
-                np.iinfo(np.int32).max, id_bound=nv_ceil))
+                np.iinfo(np.int32).max))
 
         try:
             tk = time_best(run_kernel, repeats)
