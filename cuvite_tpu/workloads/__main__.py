@@ -2,6 +2,7 @@
 
     python -m cuvite_tpu.workloads fetch com-orkut --dest workloads_data
     python -m cuvite_tpu.workloads synth --edges 1e8 --profile powerlaw
+    python -m cuvite_tpu.workloads synth --edges 1e7 --profile lfr --mu 0.4
     python -m cuvite_tpu.workloads convert in.txt.gz --out out.vite
     python -m cuvite_tpu.workloads bench --file out.vite
     python -m cuvite_tpu.workloads verify-golden --dataset powerlaw-1e8 \
@@ -39,6 +40,12 @@ def _cmd_fetch(args) -> int:
     return 0
 
 
+def _lfr_args(args) -> dict:
+    return {"gamma": args.gamma, "beta": args.beta,
+            "mean_degree": args.mean_degree, "max_degree": args.max_degree,
+            "cmin": args.cmin, "cmax": args.cmax}
+
+
 def _cmd_synth(args) -> int:
     import os
 
@@ -58,7 +65,7 @@ def _cmd_synth(args) -> int:
             profile=args.profile, seed=args.seed, alpha=args.alpha,
             mu=args.mu, overlap=args.overlap,
             edge_factor=args.edge_factor, bits64=args.bits64,
-            write_truth=not args.no_truth,
+            write_truth=not args.no_truth, **_lfr_args(args),
         )
         print(json.dumps({
             "out_prefix": prefix, "count": payload["count"],
@@ -69,10 +76,12 @@ def _cmd_synth(args) -> int:
         out, edges=int(args.edges), profile=args.profile, seed=args.seed,
         alpha=args.alpha, mu=args.mu, overlap=args.overlap,
         edge_factor=args.edge_factor, bits64=args.bits64,
-        write_truth=not args.no_truth,
+        write_truth=not args.no_truth, **_lfr_args(args),
     )
     line = {"out": out, "result": payload["result"],
             "sha256": payload["sha256"]}
+    if "lfr" in payload:
+        line["lfr"] = payload["lfr"]
     if args.churn:
         # Deterministic insert/delete stream against the graph just
         # written (read back, so the churn indexes the REALIZED edge
@@ -156,17 +165,37 @@ def build_parser() -> argparse.ArgumentParser:
                    help="edge count of the offline stand-in")
     f.add_argument("--keep-download", action="store_true")
 
-    s = sub.add_parser("synth", help="synthesize a power-law community "
-                                     "graph as a Vite file")
+    s = sub.add_parser("synth", help="synthesize a graph with planted "
+                                     "communities as a Vite file")
     s.add_argument("--edges", type=float, required=True,
-                   help="target directed edge records (e.g. 1e8)")
-    s.add_argument("--profile", default="powerlaw", choices=PROFILES)
+                   help="target directed edge records (e.g. 1e8); lfr "
+                        "takes edges / mean-degree vertices")
+    s.add_argument("--profile", default="powerlaw", choices=PROFILES,
+                   help="lfr: the LFR benchmark construction; powerlaw: "
+                        "the older stand-in with overlap")
     s.add_argument("--out", default=None)
     s.add_argument("--seed", type=int, default=1)
-    s.add_argument("--alpha", type=float, default=2.3)
-    s.add_argument("--mu", type=float, default=0.25)
-    s.add_argument("--overlap", type=float, default=0.05)
-    s.add_argument("--edge-factor", type=int, default=16)
+    s.add_argument("--mu", type=float, default=0.25,
+                   help="mixing: share of each vertex's edges that leave "
+                        "its community")
+    s.add_argument("--alpha", type=float, default=2.3,
+                   help="powerlaw: degree exponent")
+    s.add_argument("--overlap", type=float, default=0.05,
+                   help="powerlaw: share of vertices in two communities")
+    s.add_argument("--edge-factor", type=int, default=16,
+                   help="powerlaw: mean directed degree")
+    s.add_argument("--gamma", type=float, default=2.0,
+                   help="lfr: degree exponent")
+    s.add_argument("--beta", type=float, default=1.0,
+                   help="lfr: community-size exponent")
+    s.add_argument("--mean-degree", type=float, default=20,
+                   help="lfr: mean degree <k>")
+    s.add_argument("--max-degree", type=int, default=50,
+                   help="lfr: maximum degree k_max")
+    s.add_argument("--cmin", type=int, default=20,
+                   help="lfr: smallest community")
+    s.add_argument("--cmax", type=int, default=100,
+                   help="lfr: largest community")
     s.add_argument("--bits64", action="store_true")
     s.add_argument("--no-truth", action="store_true",
                    help="skip the ground-truth file (large graphs)")
