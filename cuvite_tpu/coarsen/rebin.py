@@ -18,10 +18,10 @@ label space, per-width class assignment against the static
 with NO host sync and NO ``lax.sort`` (this module sits inside
 graftlint R013's no-sort scope).
 
-Static geometry.  The compile-key set must stay bounded, so bucket
-shapes cannot depend on the phase's degree distribution (the host
-builder's data-dependent ``nb_pad`` would retrace every phase).
-:func:`rebin_geometry` derives a CLASS-static shape instead: every
+Geometry.  :func:`rebin_plan` takes its bucket shapes as a static
+``((width, rows), ...)`` tuple, and two callers fill it differently.
+
+:func:`rebin_geometry` derives a CLASS-static shape: every
 truncated-ladder width is kept (an empty class is all-padding rows),
 and class k's row count is the provable occupancy ceiling
 
@@ -31,7 +31,21 @@ and class k's row count is the provable occupancy ceiling
 ne_pad // (prev_k + 1) vertices fit the class, and pow2_ceil dominates
 the host builder's pow2 ``nb_pad`` (pow2_ceil is monotone), so every
 host bucket embeds as the device bucket's prefix.  One program per
-``(nv_pad, ne_pad)`` slab class, exactly like the slab kernels.
+``(nv_pad, ne_pad)`` slab class, exactly like the slab kernels.  The
+batched serving path uses it because it traces the plan inside one
+vmapped program per ``(class, B)``: it sees no histogram without a
+host sync, and its compile keys must stay stable across tenants.  The
+per-graph driver uses it at the floor slab class too, where the
+ceiling is a few hundred thousand slots and one program shared by
+every small tail phase is worth more than the padding it sweeps.
+
+:func:`sized_geometry` gives each non-empty class the host builder's
+``nb_pad`` rows, from the phase's own degree histogram.  The per-graph
+driver uses it above the floor class: it coarsens on the host and
+holds the coarse CSR, so the histogram is one O(V) pass over its
+offsets, and the class ceiling there pads a phase's plan 10-20x over
+what the phase holds (every slot of which the phase loop sweeps each
+iteration).  The device plan then has the host plan's shapes exactly.
 
 Eligibility (:func:`rebin_eligible`).  A coalesced slab's max degree is
 bounded by nv_pad (distinct neighbors), so nv_pad <= DEFAULT_BUCKETS[-1]
@@ -60,6 +74,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from cuvite_tpu.louvain.bucketed import DEFAULT_BUCKETS
 
@@ -107,6 +122,30 @@ def rebin_geometry(nv_pad: int, ne_pad: int,
     return tuple(geom)
 
 
+def sized_geometry(degrees, nv_pad: int,
+                   widths: tuple = DEFAULT_BUCKETS) -> tuple:
+    """The phase-sized bucket geometry: ``((width, rows), ...)`` for the
+    ladder classes that hold at least one vertex, ``rows`` the host
+    builder's ``nb_pad`` (``BucketPlan.build``: pow2 ceiling of the
+    class's vertex count, 1 for a single vertex), so the device plan
+    has the host plan's shapes.  ``degrees``: per-vertex edge counts of
+    the slab's label space (at most ``nv_pad`` entries, e.g. the CSR
+    offsets' differences).  A degree past the ladder top would be a
+    heavy residual, which the device plan does not hold: refused."""
+    deg = np.asarray(degrees)
+    if len(deg) > nv_pad:
+        raise ValueError(f"{len(deg)} degrees for nv_pad {nv_pad}")
+    deg = deg[deg > 0]
+    if len(deg) and int(deg.max()) > widths[-1]:
+        raise ValueError(f"degree {int(deg.max())} past the ladder top "
+                         f"{widths[-1]}: a heavy residual needs the host "
+                         "plan")
+    counts = np.bincount(np.searchsorted(widths, deg, side="left"),
+                         minlength=len(widths))
+    return tuple((width, 1 << int(n - 1).bit_length())
+                 for width, n in zip(widths, counts.tolist()) if n)
+
+
 def rebin_eligible(nv_pad: int, ne_pad: int,
                    widths: tuple = DEFAULT_BUCKETS) -> bool:
     """True when the class can be re-binned on device with NO heavy
@@ -129,6 +168,14 @@ def rebin_plan(src, dst, w, *, nv_pad: int, base: int, geometry: tuple):
     the prefix, padding == nv_pad; ``dst``: [ne_pad] padded-space tail
     ids (padding 0, w 0); ``base``: the shard's first global id (self-
     loop detection, same convention as ``BucketPlan.build``).
+
+    ``geometry``: ``((width, rows), ...)`` in ladder order, from
+    :func:`rebin_geometry` or :func:`sized_geometry`.  Class k takes the
+    vertices of degree in ``(prev, width]``, ``prev`` the geometry's
+    previous width; a geometry that leaves out a ladder class no vertex
+    falls in keeps that test right for the classes it keeps, since no
+    vertex has a degree in the interval it drops.  ``rows`` must hold
+    the class's vertices (the scatter drops any past it).
 
     Returns ``(buckets, heavy, self_loop, perm)``: ``buckets`` a tuple
     of ``(verts [R], dmat [R, W], wmat [R, W])`` triples in geometry
@@ -200,9 +247,9 @@ def rebin_plan(src, dst, w, *, nv_pad: int, base: int, geometry: tuple):
 def device_rebin_plan(src, dst, w, *, nv_pad: int, base: int,
                       geometry: tuple):
     """The jitted eager entry point (per-graph driver): one device
-    dispatch per phase, statics = the slab class (``geometry`` comes
-    from :func:`rebin_geometry`, so the compile-key set is one program
-    per class).  The batched path traces :func:`rebin_plan` directly
-    inside its phase program instead."""
+    dispatch per phase, statics = the geometry (one program per slab
+    class at the floor, where it comes from :func:`rebin_geometry`; one
+    per sized geometry above it).  The batched path traces
+    :func:`rebin_plan` directly inside its phase program instead."""
     return rebin_plan(src, dst, w, nv_pad=nv_pad, base=base,
                       geometry=geometry)

@@ -43,6 +43,7 @@ from cuvite_tpu.coarsen.rebin import (
     device_rebin_plan,
     rebin_eligible,
     rebin_geometry,
+    sized_geometry,
 )
 from cuvite_tpu.core.types import (
     CONV_ROWS_CAP,
@@ -825,14 +826,19 @@ class PhaseRunner:
             # bucketed engine build the plan ON DEVICE (coarsen/rebin.py)
             # — no host histogram, no per-phase BucketPlan.build, no
             # per-bucket uploads.  The slab is padded to a pow2 edge
-            # class (floor = louvain_phases' min_ne_pad) so the jitted
-            # builder compiles once per class across phases.  The
-            # pallas / heavy-kernel / coloring paths need the host
-            # plan's data-dependent layouts, and ineligible classes
-            # (possible heavy residual, element budget) keep the host
-            # oracle.
+            # class (floor = louvain_phases' min_ne_pad).  At the floor
+            # class the plan takes the class-static geometry, so the
+            # jitted builder and the phase loop compile once for every
+            # small tail phase; above it the class ceiling pads the plan
+            # 10-20x over what the phase holds, so the geometry is sized
+            # from the coarse graph's degree histogram (its CSR offsets,
+            # O(V)), the host plan's shapes.  The pallas / heavy-kernel
+            # / coloring paths need the host plan's data-dependent
+            # layouts, and ineligible classes (possible heavy residual,
+            # element budget) keep the host oracle.
             src_np = np.asarray(sh.src)
-            ne_class = max(next_pow2(max(len(src_np), 1)), 16384)
+            ne_floor = 16384
+            ne_class = max(next_pow2(max(len(src_np), 1)), ne_floor)
             use_dev_rebin = (device_rebin and engine == "bucketed"
                              and not class_sched
                              and device_rebin_enabled()
@@ -858,7 +864,12 @@ class PhaseRunner:
                         [dst_np, np.zeros(pad, dtype=dst_np.dtype)])
                     w_np = np.concatenate(
                         [w_np, np.zeros(pad, dtype=w_np.dtype)])
-                geom = rebin_geometry(dg.nv_pad, ne_class)
+                if ne_class > ne_floor:
+                    geom = sized_geometry(dg.graph.degrees(), dg.nv_pad)
+                    tracer.count("rebin_sized_phases", 1)
+                else:
+                    geom = rebin_geometry(dg.nv_pad, ne_class)
+                tracer.count("rebin_slots", sum(r * wd for wd, r in geom))
                 src_d = _up(src_np, vdt)
                 dst_d = _up(dst_np, vdt)
                 w_d = _up(w_np, wdt)

@@ -24,9 +24,14 @@ from cuvite_tpu.coarsen.rebin import (
     device_rebin_plan,
     rebin_eligible,
     rebin_geometry,
+    sized_geometry,
 )
 from cuvite_tpu.io.generate import generate_rmat
-from cuvite_tpu.louvain.bucketed import DEFAULT_BUCKETS, BucketPlan
+from cuvite_tpu.louvain.bucketed import (
+    DEFAULT_BUCKETS,
+    BucketPlan,
+    build_assemble_perm,
+)
 from cuvite_tpu.louvain.driver import louvain_phases
 from cuvite_tpu.ops.segment import coalesced_runs
 
@@ -77,18 +82,42 @@ def _coalesced_slab(rng, nv_pad, ne_pad, *, base=0, gapped=False,
     (1024, 32768, {"hubs": 4, "max_deg": 40}),        # widths up to 1024
     (8192, 1 << 17, {"hubs": 3, "gapped": True,
                      "max_deg": 12}),                 # full ladder to 8192
-], ids=["tiny", "gapped", "based", "hubby", "ladder-top"])
+    # Above the floor slab class, the per-graph driver's sized geometry:
+    # a gapped label space, degrees up to 40 and one hub of degree 1500
+    # (a class of exactly one vertex), so 128 to 1024 and 2048 up are
+    # empty ladder classes.
+    (2048, 1 << 15, {"sized": True, "gapped": True, "hubs": 1,
+                     "hub_deg": 1500, "max_deg": 40}),
+], ids=["tiny", "gapped", "based", "hubby", "ladder-top", "sized"])
 def test_device_plan_matches_host(nv_pad, ne_pad, kw):
     rng = np.random.default_rng(nv_pad + ne_pad)
+    kw = dict(kw)
+    sized = kw.pop("sized", False)
     base = kw.get("base", 0)
     src, dst, w = _coalesced_slab(rng, nv_pad, ne_pad, **kw)
     assert rebin_eligible(nv_pad, ne_pad)
-    geom = rebin_geometry(nv_pad, ne_pad)
+    deg = np.bincount(src[src < nv_pad], minlength=nv_pad)
+    geom = (sized_geometry(deg, nv_pad) if sized
+            else rebin_geometry(nv_pad, ne_pad))
     plan = BucketPlan.build(src, dst, w, nv_local=nv_pad, base=base)
     assert not plan.has_heavy
     bks, heavy, self_loop, perm = jax.device_get(device_rebin_plan(
         jnp.asarray(src), jnp.asarray(dst), jnp.asarray(w),
         nv_pad=nv_pad, base=base, geometry=geom))
+
+    if sized:
+        # The sized plan IS the host plan: same classes, and every
+        # bucket equal in shape and content, as is the assembly perm.
+        assert [(b.width, len(b.verts)) for b in plan.buckets] \
+            == list(geom)
+        assert len(geom) < len(rebin_geometry(nv_pad, ne_pad))
+        for hb, (verts, dmat, wmat) in zip(plan.buckets, bks):
+            assert np.array_equal(verts, np.asarray(hb.verts))
+            assert np.array_equal(dmat, np.asarray(hb.dst))
+            assert wmat.shape == hb.w.shape
+            assert np.array_equal(wmat, np.asarray(hb.w, wmat.dtype))
+        assert np.array_equal(perm, build_assemble_perm(
+            [b.verts for b in plan.buckets], nv_pad))
 
     host = {b.width: b for b in plan.buckets}
     for (width, rows), (verts, dmat, wmat) in zip(geom, bks):
@@ -116,7 +145,6 @@ def test_device_plan_matches_host(nv_pad, ne_pad, kw):
     # in the concatenated bucket space, deg==0 at the trailing default.
     total = sum(r for _, r in geom)
     allverts = np.concatenate([np.asarray(b[0]) for b in bks])
-    deg = np.bincount(src[src < nv_pad], minlength=nv_pad)
     assert (perm[deg == 0] == total).all()
     touched = np.flatnonzero(deg > 0)
     assert np.array_equal(allverts[perm[touched]], touched)
@@ -133,6 +161,37 @@ def test_rebin_geometry_static_and_truncated():
     assert geom == rebin_geometry(16, 64)
     widths = [wd for wd, _ in rebin_geometry(4096, 16384)]
     assert widths == [wd for wd in DEFAULT_BUCKETS if wd <= 4096]
+
+
+def test_sized_geometry_rows_are_host_nb_pad():
+    """The sized geometry keeps only the classes that hold a vertex, and
+    gives each the host builder's nb_pad rows (pow2 ceiling of the
+    count, 1 for a single vertex); its last class covers the maximum
+    degree.  A degree past the ladder top, or more degrees than the
+    label space holds, is refused."""
+    deg = np.array([0, 3, 8, 9, 16, 17, 0, 100, 5, 2, 12, 14, 15],
+                   np.int64)
+    geom = sized_geometry(deg, 16)
+    # w8: 3, 8, 5, 2 -> 4 rows; w16: 9, 16, 12, 14, 15 -> 8 rows;
+    # w32: 17 -> 1 row; w64 empty, dropped; w128: 100 -> 1 row.
+    assert geom == ((8, 4), (16, 8), (32, 1), (128, 1))
+    assert geom[-1][0] >= deg.max()
+    assert sized_geometry(np.zeros(16, np.int64), 16) == ()
+
+    rng = np.random.default_rng(11)
+    nv_pad = 1024
+    src, dst, w = _coalesced_slab(rng, nv_pad, 1 << 16, gapped=True,
+                                  hubs=2, hub_deg=600, max_deg=30)
+    deg = np.bincount(src[src < nv_pad], minlength=nv_pad)
+    plan = BucketPlan.build(src, dst, w, nv_local=nv_pad, base=0)
+    geom = sized_geometry(deg, nv_pad)
+    assert geom == tuple((b.width, len(b.verts)) for b in plan.buckets)
+    assert geom[-1][0] >= deg.max() > geom[-2][0]
+
+    with pytest.raises(ValueError, match="ladder top"):
+        sized_geometry(np.array([DEFAULT_BUCKETS[-1] + 1]), 16)
+    with pytest.raises(ValueError, match="nv_pad"):
+        sized_geometry(np.ones(17, np.int64), 16)
 
 
 def test_rebin_eligibility_bounds(monkeypatch):
@@ -283,6 +342,54 @@ def test_rebin_device_fraction_in_tracer(rmat10):
     # no-improvement attempt included, so >= recorded phases - 1).
     assert total >= len(res.phases) - 1
     assert dev == total  # the floor class is rebin-eligible
+
+
+def test_sized_rebin_lfr_identical_and_counted(monkeypatch):
+    """Above the floor slab class the driver sizes the device plan from
+    the coarse graph's degrees: on a small LFR graph whose phase 1
+    (about 200 communities, over 16384 coarse edges) re-bins on the
+    device above the floor, labels, Q and every phase's iteration count
+    equal the host-plan run, the sized phase is counted, and the plan
+    slots fall below the class-static geometry's for the same phases."""
+    import cuvite_tpu.louvain.driver as drv
+    from cuvite_tpu.core.graph import Graph
+    from cuvite_tpu.utils.trace import Tracer
+    from cuvite_tpu.workloads.synth import lfr_edges
+
+    nv, s, d, _ = lfr_edges(6000, 2.0, 1.0, 20, 30, 20, 40, 0.3, seed=7)
+    g = Graph.from_edges(nv, s, d)
+    seen = []  # (nv_pad, ne_class, geometry) per device re-binned phase
+    orig = drv.device_rebin_plan
+
+    def spy(src, dst, w, *, nv_pad, base, geometry):
+        seen.append((nv_pad, src.shape[0], geometry))
+        return orig(src, dst, w, nv_pad=nv_pad, base=base,
+                    geometry=geometry)
+
+    monkeypatch.setattr(drv, "device_rebin_plan", spy)
+    monkeypatch.delenv("CUVITE_DEVICE_REBIN", raising=False)
+    tr = Tracer(enabled=True)
+    r_on = louvain_phases(g, engine="bucketed", tracer=tr)
+    monkeypatch.setenv("CUVITE_DEVICE_REBIN", "0")
+    r_off = louvain_phases(g, engine="bucketed")
+
+    assert np.array_equal(r_on.communities, r_off.communities)
+    assert r_on.modularity == r_off.modularity
+    assert [p.iterations for p in r_on.phases] \
+        == [p.iterations for p in r_off.phases]
+    assert any(ne > 16384 for _, ne, _ in seen)
+    assert tr.counters["rebin_sized_phases"] \
+        == sum(ne > 16384 for _, ne, _ in seen) >= 1
+    assert tr.counters["rebin_device_phases"] == len(seen)
+    slots = tr.counters["rebin_slots"]
+    assert slots == sum(r * wd for _, _, geom in seen for wd, r in geom)
+    static = sum(r * wd for nv_pad, ne, _ in seen
+                 for wd, r in rebin_geometry(nv_pad, ne))
+    assert slots < static
+    # The floor class keeps the class-static geometry.
+    for nv_pad, ne, geom in seen:
+        if ne <= 16384:
+            assert geom == rebin_geometry(nv_pad, ne)
 
 
 # ---------------------------------------------------------------------------
