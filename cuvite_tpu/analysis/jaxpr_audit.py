@@ -178,7 +178,6 @@ def trace_phase_jaxprs(b: int = 2, nv: int = 256, ne: int = 1024,
     from cuvite_tpu.louvain.batched import (
         MAX_TOTAL_ITERATIONS,
         _batch_accum_name,
-        _batched_coalesce_engine,
         _coarse_class,
         _get_batched_phase,
         _shrink_batch,
@@ -189,7 +188,6 @@ def trace_phase_jaxprs(b: int = 2, nv: int = 256, ne: int = 1024,
     B = batch.b_pad
     wdt = np.dtype(np.float32)
     adt = _batch_accum_name(batch)
-    eng = _batched_coalesce_engine(nv_pad, adt)
     comm_all = np.broadcast_to(
         np.arange(nv_pad, dtype=np.int32)[None, :], (B, nv_pad)).copy()
     prev = np.full((B,), -1.0, dtype=wdt)
@@ -202,7 +200,7 @@ def trace_phase_jaxprs(b: int = 2, nv: int = 256, ne: int = 1024,
         "batched_coarse_shrink"}
     out = {}
     if "batched_fused_phase" in want:
-        fused = _get_batched_phase(mesh, nv_pad, adt, eng,
+        fused = _get_batched_phase(mesh, nv_pad, adt,
                                    MAX_TOTAL_ITERATIONS)
         out["batched_fused_phase"] = jax.make_jaxpr(fused)(*slab_args)
 
@@ -215,7 +213,7 @@ def trace_phase_jaxprs(b: int = 2, nv: int = 256, ne: int = 1024,
             bplan.self_loop,
             bplan.perm,
         )
-        bucketed = _get_batched_phase(mesh, nv_pad, adt, eng,
+        bucketed = _get_batched_phase(mesh, nv_pad, adt,
                                       MAX_TOTAL_ITERATIONS,
                                       engine="bucketed",
                                       n_buckets=len(bplan.buckets))

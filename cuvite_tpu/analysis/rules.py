@@ -934,16 +934,13 @@ class UnsyncedTimingWindow(Rule):
 # device-resident coarsening (coarsen_s 3.4 s -> 65.0 s at scale 20 on
 # CPU: above ~2^15 padded vertices the packed int32 key no longer fits
 # and lax.sort degrades to its slowest variadic comparator).  The ONLY
-# sanctioned full-slab sort for the coalesce is the fallback chokepoint
-# ops/segment.py::coalesced_runs (via sort_edges_by_vertex_comm or
-# sort_edges_msd), which reports its engagement as bench coverage
-# (`coalesce_kernel`).  A new direct sort in coarsen/ or kernels/ would
-# bypass both the dense seg_coalesce engines and the coverage
-# accounting — silently re-imposing the tax.  The scope deliberately
-# covers the ISSUE-19 modules: the device re-binner (coarsen/rebin.py)
-# and the sort-free hash coalesce (kernels/seg_coalesce.py::hash_emit)
-# exist precisely to AVOID per-phase sorts, so a lax.sort creeping into
-# either is the regression this rule is for.
+# sanctioned full-slab sort for the coalesce is the chokepoint
+# ops/segment.py::coalesced_runs (a packed sort via
+# sort_edges_by_vertex_comm).  A new direct sort in coarsen/ or kernels/
+# would bypass it — silently re-imposing the tax.  The scope
+# deliberately covers the device re-binner (coarsen/rebin.py, ISSUE 19),
+# which exists precisely to AVOID per-phase sorts, so a lax.sort
+# creeping into it is the regression this rule is for.
 
 _SLAB_SORT_SCOPE = (
     "cuvite_tpu/coarsen/",
@@ -962,7 +959,7 @@ class SlabSortOutsideChokepoint(Rule):
     id = "R013"
     severity = "high"
     title = "full-slab device sort in coarsen/ or kernels/ outside the " \
-            "sanctioned coalesce fallback chokepoint"
+            "sanctioned coalesce chokepoint"
 
     def check(self, sf):
         if not sf.rel.startswith(_SLAB_SORT_SCOPE):
@@ -977,8 +974,7 @@ class SlabSortOutsideChokepoint(Rule):
                     f"{fname}() in a coarsen/kernel module: full-slab "
                     "sorts are the round-7 coarsening tax and live ONLY "
                     "behind ops/segment.coalesced_runs (the sanctioned "
-                    "fallback chokepoint, whose engagement is reported "
-                    "as bench coverage); route through it — or carry an "
+                    "chokepoint); route through it — or carry an "
                     "inline '# graftlint: disable=R013' with a "
                     "justification for a genuinely non-slab sort")
 

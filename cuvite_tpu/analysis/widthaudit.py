@@ -24,10 +24,8 @@ three properties the AST walk cannot:
         the lexicographic two-key fallback at ``== 32`` (the
         segment.py contract), with the int64 single-key under
         ``jax_enable_x64``;
-      - ``coalesce_engine`` honoring its nv ceiling and the ds32
-        degrade even when the env knob demands the dense engine;
-      - the ``SLAB_NE_MAX`` / ``FLAT_NV_MAX`` raise-guards actually
-        raising one step past the ceiling (fail-loud, never wrap);
+      - the ``SLAB_NE_MAX`` raise-guard actually raising one step past
+        the ceiling (fail-loud, never wrap);
       - ``_accum_name`` switching to ds32 exactly at
         ``DS_MIN_TOTAL_WEIGHT``.
 
@@ -49,7 +47,6 @@ runs the same audit in-process.
 
 from __future__ import annotations
 
-import contextlib
 import gc
 import json
 import os
@@ -63,13 +60,10 @@ BUDGET_VERSION = 1
 
 DEFAULT_BUDGET_REL = os.path.join("tools", "width_budget.json")
 
-# The fixed classes of the small entries: batched serving multiplexes
-# B tenants of modest graphs; the dense coalesce is only ever offered
-# classes within its flat-key ceiling.
+# The fixed class of the batched entry: batched serving multiplexes
+# B tenants of modest graphs.
 BATCHED_NV = 1 << 12
 BATCHED_NE = 1 << 14
-DENSE_NV = 1 << 12
-DENSE_NE = 1 << 16
 
 # Jaxpr primitives whose integer outputs carry INDICES of their
 # operated extent (run ids, positions, slot numbers).  reduce_sum is
@@ -82,22 +76,6 @@ def _wfind(rule: str, entry: str, message: str,
            snippet: str = "") -> Finding:
     return Finding(rule=rule, severity="high", path=f"<width:{entry}>",
                    line=0, message=message, snippet=snippet)
-
-
-@contextlib.contextmanager
-def _env(name: str, value: str | None):
-    prior = os.environ.get(name)
-    try:
-        if value is None:
-            os.environ.pop(name, None)
-        else:
-            os.environ[name] = value
-        yield
-    finally:
-        if prior is None:
-            os.environ.pop(name, None)
-        else:
-            os.environ[name] = prior
 
 
 def live_device_bytes() -> int:
@@ -329,29 +307,12 @@ def _trace_coarsen(nv: int, ne: int):
     def entry(src, dst, w, comm, real_mask):
         return device_coarsen_slab(src, dst, w, comm, real_mask,
                                    nv_pad=nv,
-                                   accum_dtype=_accum_for(ne),
-                                   coalesce="sort")
+                                   accum_dtype=_accum_for(ne))
 
     return jax.make_jaxpr(entry)(
         _sds((ne,), jnp.int32), _sds((ne,), jnp.int32),
         _sds((ne,), jnp.float32), _sds((nv,), jnp.int32),
         _sds((nv,), jnp.bool_))
-
-
-def _trace_coalesce_dense(nv: int, ne: int):
-    import jax
-    import jax.numpy as jnp
-
-    from cuvite_tpu.kernels.seg_coalesce import seg_coalesce_xla
-
-    dnv, dne = DENSE_NV, DENSE_NE
-
-    def entry(src, dst, w):
-        return seg_coalesce_xla(src, dst, w, nv_pad=dnv)
-
-    return jax.make_jaxpr(entry)(
-        _sds((dne,), jnp.int32), _sds((dne,), jnp.int32),
-        _sds((dne,), jnp.float32))
 
 
 # name -> (tracer, sorts_expected): ``sorts_expected`` marks entries
@@ -363,7 +324,6 @@ ENTRIES = {
     "solo_bucketed_step": (_trace_solo_bucketed, True),
     "batched_execute": (_trace_batched, False),
     "coarsen_coalesce": (_trace_coarsen, True),
-    "coalesce_dense": (_trace_coalesce_dense, False),
 }
 
 
@@ -385,7 +345,6 @@ def boundary_probes(laws: dict) -> tuple:
     import jax
     import jax.numpy as jnp
 
-    from cuvite_tpu.kernels import seg_coalesce
     from cuvite_tpu.louvain.driver import DS_MIN_TOTAL_WEIGHT, _accum_name
     from cuvite_tpu.ops import segment
 
@@ -444,39 +403,13 @@ def boundary_probes(laws: dict) -> tuple:
             f"the sort traced {forced}, not the single-key int64 pack "
             "— the oracle mode lost the wide fast path"))
 
-    # coalesce_engine: the env knob must NOT override the nv ceiling or
-    # the ds32 degrade (ineligible classes go to 'sort' in every mode).
-    cap = int(laws.get("coalesce_max_nv", 32768))
-    with _env("CUVITE_SEG_COALESCE", "xla"):
-        eligible = seg_coalesce.coalesce_engine(DENSE_NV)
-        over = seg_coalesce.coalesce_engine(cap * 2)
-        ds = seg_coalesce.coalesce_engine(DENSE_NV, accum_dtype="ds32")
-    facts["coalesce"] = {"eligible": eligible, "over_cap": over,
-                         "ds32": ds}
-    if eligible != "xla":
-        findings.append(_wfind(
-            "W002", "coalesce_engine",
-            f"CUVITE_SEG_COALESCE=xla resolved nv_pad={DENSE_NV} to "
-            f"{eligible!r}, not 'xla' — the env knob is dead"))
-    if over != "sort":
-        findings.append(_wfind(
-            "W002", "coalesce_engine",
-            f"nv_pad={cap * 2} resolved to {over!r}, not 'sort': the "
-            "flat (src << kbits) | dst key would overflow int32 — the "
-            "nv ceiling is not enforced"))
-    if ds != "sort":
-        findings.append(_wfind(
-            "W002", "coalesce_engine",
-            f"accum_dtype='ds32' resolved to {ds!r}, not 'sort': the "
-            "dense engines have no double-single accumulator"))
-
-    # Raise-guards: legal shape traces; one past FAILS LOUD.
+    # Raise-guard: legal shape traces; one past FAILS LOUD.
     slab_max = int(laws.get("slab_ne_max", segment.SLAB_NE_MAX))
 
     def runs(ne_probe, nv_probe=1 << 12):
         jax.eval_shape(
             lambda s, c, w: segment.coalesced_runs(
-                s, c, w, nv_pad=nv_probe, engine="sort"),
+                s, c, w, nv_pad=nv_probe),
             _sds((ne_probe,), jnp.int32), _sds((ne_probe,), jnp.int32),
             _sds((ne_probe,), jnp.float32))
 
@@ -498,33 +431,6 @@ def boundary_probes(laws: dict) -> tuple:
             "would wrap silently — the raise-guard is gone"))
     except ValueError:
         facts["slab_one_past"] = "raised"
-
-    flat_max = int(laws.get("flat_nv_max", seg_coalesce.FLAT_NV_MAX))
-
-    def xla_probe(nv_probe):
-        jax.eval_shape(
-            lambda s, d, w: seg_coalesce.seg_coalesce_xla(
-                s, d, w, nv_pad=nv_probe),
-            _sds((1 << 12,), jnp.int32), _sds((1 << 12,), jnp.int32),
-            _sds((1 << 12,), jnp.float32))
-
-    try:
-        xla_probe(flat_max)
-        facts["flat_at_max"] = "traced"
-    except Exception as e:
-        findings.append(_wfind(
-            "W002", "flat_nv_max",
-            f"seg_coalesce_xla at nv_pad == FLAT_NV_MAX ({flat_max}) "
-            f"failed to trace: {type(e).__name__}: {e}"))
-    try:
-        xla_probe(flat_max * 2)
-        findings.append(_wfind(
-            "W002", "flat_nv_max",
-            f"seg_coalesce_xla accepted nv_pad == {flat_max * 2}: the "
-            "flat (src << kbits) | dst key wraps int32 — the "
-            "raise-guard is gone"))
-    except ValueError:
-        facts["flat_one_past"] = "raised"
 
     # ds32 cutover: exactly at DS_MIN_TOTAL_WEIGHT, via either gate
     # (weight mass or addend count).
@@ -568,7 +474,6 @@ def write_budget(path: str, doc: dict) -> None:
 def code_laws() -> dict:
     """The laws as the CODE declares them — what the manifest must
     match (W003 cross-check) and what --write-budget regenerates."""
-    from cuvite_tpu.kernels.seg_coalesce import FLAT_NV_MAX, _env_max_nv
     from cuvite_tpu.louvain.driver import DS_MIN_TOTAL_WEIGHT
     from cuvite_tpu.ops.segment import SLAB_NE_MAX
 
@@ -576,8 +481,6 @@ def code_laws() -> dict:
         "index_bits": 32,
         "pack_bits": 31,
         "slab_ne_max": SLAB_NE_MAX,
-        "flat_nv_max": FLAT_NV_MAX,
-        "coalesce_max_nv": _env_max_nv(),
         "ds32_min": DS_MIN_TOTAL_WEIGHT,
     }
 

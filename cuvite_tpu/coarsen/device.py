@@ -20,9 +20,8 @@ with static pow2-padded shapes:
      ``rebuild.renumber_communities`` exactly;
   2. ``device_coarsen_slab`` — relabel both endpoints to dense ids and
      coalesce duplicate (src, dst) pairs through THE segmented-coalesce
-     chokepoint (ops/segment.py::coalesced_runs — packed sort by
-     default, the dense dst-tile engines of kernels/seg_coalesce.py on
-     request; graftlint R013 keeps stray slab sorts out), landing the
+     chokepoint (ops/segment.py::coalesced_runs, a packed sort;
+     graftlint R013 keeps stray slab sorts out), landing the
      coarse graph COMPACTED into a prefix of the SAME slab class: out
      arrays keep the input's [ne_pad] shape, real rows in [0, ne2),
      padding (src == nv_pad, w == 0) after.  Phases whose coarse graph
@@ -80,11 +79,9 @@ def device_renumber(comm, real_mask, *, nv_pad: int):
     return dense_map, jnp.sum(present)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("nv_pad", "accum_dtype", "coalesce"))
+@functools.partial(jax.jit, static_argnames=("nv_pad", "accum_dtype"))
 def device_coarsen_slab(src, dst, w, comm, real_mask, *, nv_pad: int,
-                        accum_dtype=None, dense_map=None, nc=None,
-                        coalesce=None):
+                        accum_dtype=None, dense_map=None, nc=None):
     """Relabel + coalesce the resident edge slab into the next-phase slab.
 
     ``src``: [ne_pad] local vertex ids (pad == nv_pad, sorted to the
@@ -102,15 +99,6 @@ def device_coarsen_slab(src, dst, w, comm, real_mask, *, nv_pad: int,
     ``nc`` (pass both or neither): a precomputed :func:`device_renumber`
     of the SAME ``(comm, real_mask)`` — the fused driver reuses the one
     it already ran for label composition instead of renumbering twice.
-
-    ``coalesce`` (static): the segmented-coalesce engine — 'xla' (the
-    dense bin-accumulate, kernels/seg_coalesce.py; no sorted slab copy) or 'sort' (the packed
-    sort fallback).  None resolves via
-    ``seg_coalesce.coalesce_engine(nv_pad, accum_dtype)`` AT TRACE TIME
-    — callers that want env toggles honored per call (the drivers do)
-    must resolve and pass it explicitly.  Every engine produces the
-    same contract; weights are bit-identical across engines on the
-    exactness domain (see kernels/seg_coalesce.py).
     """
     wdt = w.dtype
     if dense_map is None:
@@ -126,13 +114,8 @@ def device_coarsen_slab(src, dst, w, comm, real_mask, *, nv_pad: int,
                         cdst.astype(dst.dtype))
     w_in = jnp.where(pad, jnp.zeros_like(w), w)
 
-    if coalesce is None:
-        from cuvite_tpu.kernels.seg_coalesce import coalesce_engine
-
-        coalesce = coalesce_engine(nv_pad, accum_dtype)
     src2, dst2, w2, ne2 = seg.coalesced_runs(
-        new_src, new_dst, w_in, nv_pad=nv_pad, accum_dtype=accum_dtype,
-        engine=coalesce)
+        new_src, new_dst, w_in, nv_pad=nv_pad, accum_dtype=accum_dtype)
     w2 = w2.astype(wdt)
     return src2, dst2, w2, dense_map, nc, ne2
 
@@ -172,23 +155,15 @@ def batched_compose_labels(dense_map, labels, comm_all):
 
 
 def batched_coarsen_slab(src, dst, w, comm, real_mask, dense_map, nc, *,
-                         nv_pad: int, accum_dtype=None, coalesce="sort"):
+                         nv_pad: int, accum_dtype=None):
     """[B, ne_pad] lift of :func:`device_coarsen_slab` (precomputed
     per-row ``dense_map``/``nc`` required — the batched driver always
-    has them from the label composition).  ``coalesce`` must be an
-    EXPLICIT engine that lifts over a batch axis: the XLA dense engine,
-    the packed sort, or the msd two-pass sort.  (Not ``'hash'``: its per-row
-    ``lax.cond`` retry would execute BOTH branches under vmap — the
-    batched policy routes hash to 'msd' instead,
-    louvain/batched.py::_batched_coalesce_engine.)"""
-    assert coalesce in ("sort", "xla", "msd"), \
-        f"batched coalesce engine {coalesce!r}: vmap lifts " \
-        "'sort'/'xla'/'msd' only"
+    has them from the label composition)."""
 
     def one(s, d, ww, c, rm, dm, n):
         return device_coarsen_slab(
             s, d, ww, c, rm, nv_pad=nv_pad, accum_dtype=accum_dtype,
-            dense_map=dm, nc=n, coalesce=coalesce)
+            dense_map=dm, nc=n)
 
     return jax.vmap(one)(src, dst, w, comm, real_mask, dense_map, nc)
 

@@ -82,7 +82,7 @@ from cuvite_tpu.louvain.bucketed import DEFAULT_BUCKETS
 # the serving class (4096, 16384) the static geometry costs ~26x ne_pad
 # elements — a few MB — but a pathological nv_pad/ne_pad ratio could
 # inflate it, so eligibility is budget-gated like every other device
-# structure (the CUVITE_HEAVY_ELEMS precedent).
+# structure.
 DEFAULT_REBIN_MAX_ELEMS = 1 << 27
 
 

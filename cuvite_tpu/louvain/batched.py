@@ -99,7 +99,7 @@ from cuvite_tpu.utils.upload import to_device
 
 
 def _phase_body(src, dst, w, comm_all, real_mask, prev_mod, active,
-                constant, threshold, *, nv_pad, accum_dtype, coalesce,
+                constant, threshold, *, nv_pad, accum_dtype,
                 max_iters=MAX_TOTAL_ITERATIONS):
     """One Louvain phase for the whole batch: vmapped fused phase loop +
     gain test + vmapped device coarsening, converged rows masked.
@@ -127,12 +127,12 @@ def _phase_body(src, dst, w, comm_all, real_mask, prev_mod, active,
     return _phase_tail(
         src, dst, w, comm_all, real_mask, prev_mod, active, threshold,
         past, mod, iters, cq, cmoved, covf,
-        nv_pad=nv_pad, accum_dtype=accum_dtype, coalesce=coalesce)
+        nv_pad=nv_pad, accum_dtype=accum_dtype)
 
 
 def _bucketed_phase_body(buckets, heavy, self_loop, perm, src, dst, w,
                          comm_all, real_mask, prev_mod, active, constant,
-                         threshold, *, nv_pad, accum_dtype, coalesce,
+                         threshold, *, nv_pad, accum_dtype,
                          max_iters=MAX_TOTAL_ITERATIONS):
     """The sort-free phase: the per-graph BUCKETED sweep lifted over the
     batch axis (ISSUE 10).  Same contract as :func:`_phase_body`, plus
@@ -144,10 +144,9 @@ def _bucketed_phase_body(buckets, heavy, self_loop, perm, src, dst, w,
     (identity start, on-device convergence check, the degree-bucketed
     dense row formulation of Naim et al., arXiv:1805.10904) — vmapped,
     so per-tenant labels stay bit-identical to a B=1 run.  Engine
-    degradations under vmap: no Pallas row-argmax flags and no opt-in
-    heavy-kernel layout (their grids do not lift over a batch axis; the
-    XLA paths they degrade to are bit-identical, the batched-coalesce
-    precedent), and the heavy residual runs the sorted path on its
+    degradation under vmap: no Pallas row-argmax flags (the kernel grid
+    does not lift over a batch axis; the XLA path it degrades to is
+    bit-identical), and the heavy residual runs the sorted path on its
     (usually 8-slot padding) slab.  The slab itself is swept ONLY for
     the per-row weighted degrees — no per-iteration ne_pad-sized sort.
 
@@ -166,9 +165,7 @@ def _bucketed_phase_body(buckets, heavy, self_loop, perm, src, dst, w,
         vdeg = seg.segment_sum(ww, s, num_segments=nv_pad,
                                sorted_ids=True)
         comm0 = jnp.arange(nv_pad, dtype=jnp.int32)
-        # The trailing None is the heavy-kernel slot of the single-shard
-        # bucketed call convention (sorted heavy path).
-        extra = (bk, hv, sl, vdeg, c, pm, None)
+        extra = (bk, hv, sl, vdeg, c, pm)
         return _run_phase_loop(extra, comm0, th, lower, call=call,
                                max_iters=max_iters)
 
@@ -178,13 +175,12 @@ def _bucketed_phase_body(buckets, heavy, self_loop, perm, src, dst, w,
     return _phase_tail(
         src, dst, w, comm_all, real_mask, prev_mod, active, threshold,
         past, mod, iters, cq, cmoved, covf,
-        nv_pad=nv_pad, accum_dtype=accum_dtype, coalesce=coalesce)
+        nv_pad=nv_pad, accum_dtype=accum_dtype)
 
 
 def _rebinned_phase_body(src, dst, w, comm_all, real_mask, prev_mod,
                          active, constant, threshold, *, nv_pad,
-                         accum_dtype, coalesce,
-                         max_iters=MAX_TOTAL_ITERATIONS):
+                         accum_dtype, max_iters=MAX_TOTAL_ITERATIONS):
     """The sort-free COARSE phase (ISSUE 19): same 9-operand contract as
     :func:`_phase_body`, but the row sweep is the bucketed formulation
     over a plan built ON DEVICE from the coarse slab by
@@ -220,10 +216,7 @@ def _rebinned_phase_body(src, dst, w, comm_all, real_mask, prev_mod,
         vdeg = seg.segment_sum(ww, s, num_segments=nv_pad,
                                sorted_ids=True)
         comm0 = jnp.arange(nv_pad, dtype=jnp.int32)
-        # The trailing None is the heavy-kernel slot of the single-shard
-        # bucketed call convention (sorted heavy path — here the static
-        # 8-slot padding placeholder the re-binner certifies).
-        extra = (bk, hv, sl, vdeg, c, pm, None)
+        extra = (bk, hv, sl, vdeg, c, pm)
         return _run_phase_loop(extra, comm0, th, lower, call=call,
                                max_iters=max_iters)
 
@@ -233,12 +226,12 @@ def _rebinned_phase_body(src, dst, w, comm_all, real_mask, prev_mod,
     return _phase_tail(
         src, dst, w, comm_all, real_mask, prev_mod, active, threshold,
         past, mod, iters, cq, cmoved, covf,
-        nv_pad=nv_pad, accum_dtype=accum_dtype, coalesce=coalesce)
+        nv_pad=nv_pad, accum_dtype=accum_dtype)
 
 
 def _subrow_phase_body(src, dst, w, comm_all, real_mask, prev_mod, active,
                        constants, threshold, *, nv_pad, n_sub, accum_dtype,
-                       coalesce, max_iters=MAX_TOTAL_ITERATIONS):
+                       max_iters=MAX_TOTAL_ITERATIONS):
     """The PACKED phase (ISSUE 20): ``n_sub`` fenced small graphs per
     row, the whole batch through the vmapped sub-row sweep
     (louvain/subrow.py).  Same 9-operand contract as :func:`_phase_body`
@@ -263,12 +256,12 @@ def _subrow_phase_body(src, dst, w, comm_all, real_mask, prev_mod, active,
     return _subrow_phase_tail(
         src, dst, w, comm_all, real_mask, prev_mod, active, threshold,
         past, mod, iters, cq, cmoved, covf,
-        nv_pad=nv_pad, n_sub=n_sub, coalesce=coalesce)
+        nv_pad=nv_pad, n_sub=n_sub)
 
 
 def _subrow_phase_tail(src, dst, w, comm_all, real_mask, prev_mod, active,
                        threshold, past, mod, iters, cq, cmoved, covf, *,
-                       nv_pad, n_sub, coalesce):
+                       nv_pad, n_sub):
     """Phase epilogue of the packed engine: the gain test, coarsening
     and masked exit of :func:`_phase_tail`, all at SUB-row granularity.
     Retired sub-rows' edges are masked to the row sentinel BEFORE the
@@ -308,7 +301,7 @@ def _subrow_phase_tail(src, dst, w, comm_all, real_mask, prev_mod, active,
         nd = jnp.where(pad, jnp.zeros((), d.dtype), cd.astype(d.dtype))
         wi = jnp.where(pad, jnp.zeros_like(ww), ww)
         s2, d2, w2, _ = seg.coalesced_runs(
-            ns, nd, wi, nv_pad=nv_pad, accum_dtype=None, engine=coalesce)
+            ns, nd, wi, nv_pad=nv_pad, accum_dtype=None)
         return s2, d2, w2.astype(wdt)
 
     src2, dst2, w2 = jax.vmap(one)(src_m, dst_m, w_m, past, dmap_cur)
@@ -338,7 +331,7 @@ def _subrow_phase_tail(src, dst, w, comm_all, real_mask, prev_mod, active,
 
 def _phase_tail(src, dst, w, comm_all, real_mask, prev_mod, active,
                 threshold, past, mod, iters, cq, cmoved, covf, *,
-                nv_pad, accum_dtype, coalesce):
+                nv_pad, accum_dtype):
     """Shared phase epilogue (every batched engine): gain test, vmapped
     device coarsening, masked per-row phase exit.  One definition so the
     fused and bucketed phases retire rows and advance slabs
@@ -356,7 +349,7 @@ def _phase_tail(src, dst, w, comm_all, real_mask, prev_mod, active,
     comm_all2 = batched_compose_labels(dmap, past, comm_all)
     src2, dst2, w2, _dm, _nc, ne2 = batched_coarsen_slab(
         src, dst, w, past, real_mask, dmap, nc,
-        nv_pad=nv_pad, accum_dtype=acc, coalesce=coalesce)
+        nv_pad=nv_pad, accum_dtype=acc)
     rm2 = jnp.arange(nv_pad, dtype=jnp.int32)[None, :] < nc[:, None]
 
     # Masked phase exit: a gaining row advances to its coarse slab; a
@@ -403,20 +396,6 @@ def _coarse_class(nv_pad: int, ne_pad: int) -> tuple:
     floored at the serving-coarse minima."""
     return (max(nv_pad // 4, BATCH_COARSE_MIN_NV),
             max(ne_pad // 4, BATCH_COARSE_MIN_NE))
-
-
-def _batched_coalesce_engine(nv_pad: int, adt: str) -> str:
-    """The coalesce engine of a batched phase at one slab class: the
-    env-resolved per-graph policy, with 'hash' downgraded to 'msd': the
-    hash engine's collision retry is a ``lax.cond`` whose
-    branches BOTH execute under vmap, so its fallback path would run
-    for every row of every batch (coarsen/device.py).  One definition
-    for the phase-0 class and the serving-coarse class, so the
-    downgrade rule cannot drift between them."""
-    from cuvite_tpu.kernels.seg_coalesce import coalesce_engine
-
-    eng = coalesce_engine(nv_pad, "ds32" if adt == "ds32" else None)
-    return "msd" if eng == "hash" else eng
 
 
 @functools.partial(jax.jit, static_argnames=("cnv", "cne"))
@@ -466,7 +445,7 @@ def _shrink_subrow_batch(src, dst, w, real_mask, *, n_sub: int,
 _PHASE_CACHE: dict = {}
 
 
-def _get_batched_phase(mesh, nv_pad, accum_dtype, coalesce, max_iters,
+def _get_batched_phase(mesh, nv_pad, accum_dtype, max_iters,
                        engine: str = "fused", n_buckets: int = 0,
                        n_sub: int = 0):
     """The compiled batched-phase program for one ``(mesh, class
@@ -480,7 +459,7 @@ def _get_batched_phase(mesh, nv_pad, accum_dtype, coalesce, max_iters,
     bucketed program is one compile per (class, B, bucket geometry)."""
     key = (
         None if mesh is None else tuple(d.id for d in mesh.devices.flat),
-        nv_pad, accum_dtype, coalesce, max_iters, engine, n_buckets,
+        nv_pad, accum_dtype, max_iters, engine, n_buckets,
         n_sub,
     )
     fn = _PHASE_CACHE.get(key)
@@ -490,15 +469,13 @@ def _get_batched_phase(mesh, nv_pad, accum_dtype, coalesce, max_iters,
     if engine == "subrow":
         body = functools.partial(
             _subrow_phase_body, nv_pad=nv_pad, n_sub=n_sub,
-            accum_dtype=accum_dtype, coalesce=coalesce,
-            max_iters=max_iters)
+            accum_dtype=accum_dtype, max_iters=max_iters)
     else:
         body = functools.partial(
             {"bucketed": _bucketed_phase_body,
              "rebinned": _rebinned_phase_body,
              "fused": _phase_body}[engine],
-            nv_pad=nv_pad, accum_dtype=accum_dtype,
-            coalesce=coalesce, max_iters=max_iters)
+            nv_pad=nv_pad, accum_dtype=accum_dtype, max_iters=max_iters)
     if mesh is None:
         fn = jax.jit(body)
     else:
@@ -660,7 +637,6 @@ class PreparedBatch:
     row_valid: np.ndarray
     # Statics of the compiled program set.
     adt: str
-    coalesce: str
     mesh: object
     engine: str
     n_buckets: int
@@ -705,7 +681,6 @@ def prepare_batch(batch: BatchedSlab, *, mesh="auto", engine: str = "fused",
     nv_pad = batch.nv_pad
     wdt = np.dtype(np.float32)
     adt = _batch_accum_name(batch)
-    eng = _batched_coalesce_engine(nv_pad, adt)
     if mesh == "auto":
         mesh = make_batch_mesh(B)
     bplan = None
@@ -757,7 +732,7 @@ def prepare_batch(batch: BatchedSlab, *, mesh="auto", engine: str = "fused",
         slab_class=batch.slab_class, nv_real=batch.nv_real.copy(),
         ne_real=batch.ne_real.copy(),
         row_valid=np.asarray(batch.row_valid).copy(),
-        adt=adt, coalesce=eng, mesh=mesh, engine=engine,
+        adt=adt, mesh=mesh, engine=engine,
         n_buckets=n_buckets,
         src_d=src_d, dst_d=dst_d, w_d=w_d, rm_d=rm_d, const_d=const_d,
         comm_all_d=comm_all_d, prev_d=prev_d, plan_d=plan_d,
@@ -801,7 +776,6 @@ def prepare_packed(packed: PackedSubRows, *, mesh="auto",
             "tenants with accum_class_of(g, nv_pad=row_nv_pad) before "
             "merging (serve/queue.py does)")
     adt = "float32"
-    eng = _batched_coalesce_engine(nv_pad, adt)
     if mesh == "auto":
         mesh = make_batch_mesh(B)
 
@@ -828,7 +802,7 @@ def prepare_packed(packed: PackedSubRows, *, mesh="auto",
         n_jobs=packed.n_jobs, slab_class=packed.slab_class,
         nv_real=packed.nv_real.copy(), ne_real=packed.ne_real.copy(),
         row_valid=np.asarray(packed.row_valid).copy(),
-        adt=adt, coalesce=eng, mesh=mesh, engine="subrow", n_buckets=0,
+        adt=adt, mesh=mesh, engine="subrow", n_buckets=0,
         src_d=src_d, dst_d=dst_d, w_d=w_d, rm_d=rm_d, const_d=const_d,
         comm_all_d=comm_all_d, prev_d=prev_d,
         pack_s=time.perf_counter() - t0,
@@ -867,9 +841,9 @@ def execute_prepared(prep: PreparedBatch, *, threshold: float = 1.0e-6,
     coarse_class = None
     wdt = np.dtype(np.float32)
     adt = prep.adt
-    eng = prep.coalesce
     mesh = prep.mesh
-    def _coarse_fn(nv, ne, engc):
+
+    def _coarse_fn(nv, ne):
         # Coarse-phase program of the current slab class: under
         # engine='bucketed', device re-binning (ISSUE 19) keeps coarse
         # phases on the sort-free bucketed formulation whenever the
@@ -884,16 +858,16 @@ def execute_prepared(prep: PreparedBatch, *, threshold: float = 1.0e-6,
         if (prep.engine == "bucketed" and device_rebin_enabled()
                 and rebin_eligible(nv, ne)):
             return _get_batched_phase(
-                mesh, nv, adt, engc, MAX_TOTAL_ITERATIONS,
+                mesh, nv, adt, MAX_TOTAL_ITERATIONS,
                 engine="rebinned"), "rebinned"
-        return _get_batched_phase(mesh, nv, adt, engc,
+        return _get_batched_phase(mesh, nv, adt,
                                   MAX_TOTAL_ITERATIONS), "fused"
 
-    phase_fn, coarse_engine = _coarse_fn(nv_pad, prep.ne_pad, eng)
+    phase_fn, coarse_engine = _coarse_fn(nv_pad, prep.ne_pad)
     phase0_fn = None
     if prep.engine == "bucketed":
         phase0_fn = _get_batched_phase(
-            mesh, nv_pad, adt, eng, MAX_TOTAL_ITERATIONS,
+            mesh, nv_pad, adt, MAX_TOTAL_ITERATIONS,
             engine="bucketed", n_buckets=prep.n_buckets)
     src_d, dst_d, w_d = prep.src_d, prep.dst_d, prep.w_d
     rm_d, const_d = prep.rm_d, prep.const_d
@@ -1002,8 +976,7 @@ def execute_prepared(prep: PreparedBatch, *, threshold: float = 1.0e-6,
                     src_d, dst_d, w_d, rm_d, cnv=cnv, cne=cne)
                 cur_nv, cur_ne = cnv, cne
                 coarse_class = (cnv, cne)
-                phase_fn, coarse_engine = _coarse_fn(
-                    cnv, cne, _batched_coalesce_engine(cnv, adt))
+                phase_fn, coarse_engine = _coarse_fn(cnv, cne)
         phase += 1
 
     # THE final label gather: one O(B * nv_pad) transfer for the whole
@@ -1065,7 +1038,7 @@ def _execute_subrow(prep: PreparedBatch, *, threshold: float,
     mesh = prep.mesh
 
     phase_fn = _get_batched_phase(
-        mesh, nv_pad0, adt, prep.coalesce, MAX_TOTAL_ITERATIONS,
+        mesh, nv_pad0, adt, MAX_TOTAL_ITERATIONS,
         engine="subrow", n_sub=n_sub)
     src_d, dst_d, w_d = prep.src_d, prep.dst_d, prep.w_d
     rm_d, const_d = prep.rm_d, prep.const_d
@@ -1147,9 +1120,8 @@ def _execute_subrow(prep: PreparedBatch, *, threshold: float,
                 cur_nv, cur_ne = n_sub * cnv_s, n_sub * cne_s
                 coarse_class = (cur_nv, cur_ne)
                 phase_fn = _get_batched_phase(
-                    mesh, cur_nv, adt,
-                    _batched_coalesce_engine(cur_nv, adt),
-                    MAX_TOTAL_ITERATIONS, engine="subrow", n_sub=n_sub)
+                    mesh, cur_nv, adt, MAX_TOTAL_ITERATIONS,
+                    engine="subrow", n_sub=n_sub)
         phase += 1
 
     comm_all_h, prev_h = jax.device_get((comm_all_d, prev_d))  # graftlint: disable=R010 — the allowlisted final label gather (packed batch)

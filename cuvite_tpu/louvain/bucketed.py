@@ -832,7 +832,7 @@ def bucketed_step(bucket_arrays, heavy_arrays, self_loop, comm, vdeg,
                   constant, *, nv_total, sentinel, accum_dtype=None,
                   axis_name=None, pallas_flags=(), pallas_interpret=False,
                   sparse_plan=None, nshards=1, budget=0, ici_axis=None,
-                  info_comm=None, assemble_perm=None, heavy_kernel=None):
+                  info_comm=None, assemble_perm=None):
     """Full Louvain sweep over one shard using the bucketed engine.
 
     ``assemble_perm`` (phase-static [nv_local] int32, vertex -> index into
@@ -886,15 +886,6 @@ def bucketed_step(bucket_arrays, heavy_arrays, self_loop, comm, vdeg,
     exchange out of the color loop, so later classes see earlier classes'
     ``comm`` updates but iteration-start community info.  Replicated
     exchange only (single-shard, or SPMD via make_sharded_class_step).
-
-    ``heavy_kernel``: optional ``(verts [Hp], dstT [D, Hp], wT [D, Hp])``
-    phase-static layout (kernels/heavy_bincount.build_heavy_layout) —
-    the heavy (> widths[-1] degree) residual then runs the
-    community-range-tile bincount kernel instead of the per-iteration
-    global sort (the ISSUE 8 promotion; single-shard replicated only —
-    the kernel has no attached-size channel for the sparse exchange).
-    Same gain formula, tie-break and counter0 accumulation order as the
-    sorted path: labels are bit-identical on the exactness domain.
     """
     nv_local = comm.shape[0]
     wdt = vdeg.dtype
@@ -953,41 +944,12 @@ def bucketed_step(bucket_arrays, heavy_arrays, self_loop, comm, vdeg,
     # Heavy-vertex current-community weight (also their e_ix source).
     with jax.named_scope("cuvite.heavy"):
         hs, hd, hw = heavy_arrays
-        use_heavy_kernel = heavy_kernel is not None
-        if use_heavy_kernel:
-            # Opt-in heavy path: ONE community-range-tile kernel
-            # pass per iteration — no heavy sort, no per-iteration triples
-            # gather.  Replicated/single-shard only: the kernel consumes the
-            # dense comm_deg table (and the sparse singleton guard needs an
-            # attached-size channel it does not have).
-            assert not use_sparse and axis_name is None, \
-                "heavy_kernel is a single-shard replicated-path layout"
-            from cuvite_tpu.kernels.heavy_bincount import heavy_argmax_pallas
-
-            hk_verts, hk_dT, hk_wT = heavy_kernel
-            safe_hv = jnp.minimum(hk_verts, nv_local - 1)
-            curr_h = jnp.take(comm, safe_hv)
-            vdeg_h = jnp.take(vdeg, safe_hv)
-            # Padding slots (dst == pad id >= nv_local) mask to nv_total: >=
-            # every candidate tile's range, so they are never candidates and
-            # never touch counter0 (w == 0 there anyway).
-            hk_pad = hk_dT >= jnp.asarray(nv_local, hk_dT.dtype)
-            cT = jnp.where(
-                hk_pad, jnp.asarray(nv_total, hk_dT.dtype),
-                jnp.take(comm_ref, jnp.minimum(hk_dT, nv_local - 1)))
-            hk_bc, hk_bg, hk_c0 = heavy_argmax_pallas(
-                cT, hk_wT.astype(wdt), comm_deg, curr_h, vdeg_h,
-                jnp.take(self_loop, safe_hv), own_deg(safe_hv) - vdeg_h,
-                constant, interpret=pallas_interpret)
-            c0_heavy = jnp.zeros((nv_local,), dtype=wdt).at[hk_verts].set(
-                hk_c0, mode="drop")
-        else:
-            ckey_h = jnp.take(comm_ref, hd)
-            csrc_h = jnp.take(comm, jnp.minimum(hs, nv_local - 1))
-            c0_heavy = seg.segment_sum(
-                jnp.where(ckey_h == csrc_h, hw, jnp.zeros_like(hw)), hs,
-                num_segments=nv_local,
-            )
+        ckey_h = jnp.take(comm_ref, hd)
+        csrc_h = jnp.take(comm, jnp.minimum(hs, nv_local - 1))
+        c0_heavy = seg.segment_sum(
+            jnp.where(ckey_h == csrc_h, hw, jnp.zeros_like(hw)), hs,
+            num_segments=nv_local,
+        )
 
     # One pass per bucket: e_ix is row-local (every edge of a bucket vertex
     # lives in its row), so dedup + counter0 + gain + argmax all happen in a
@@ -1081,45 +1043,35 @@ def bucketed_step(bucket_arrays, heavy_arrays, self_loop, comm, vdeg,
 
     # ---- heavy vertices ---------------------------------------------------
     with jax.named_scope("cuvite.heavy"):
-        if use_heavy_kernel:
-            # Kernel results scatter to their vertices; everything else keeps
-            # -inf/sentinel so the merge below is a no-op there.  The kernel's
-            # no-candidate sentinel (int max of the id dtype) IS `sentinel`.
-            hg = jnp.full((nv_local,), neg_inf, dtype=wdt).at[hk_verts].set(
-                hk_bg, mode="drop")
-            hc = jnp.full((nv_local,), sentinel, dtype=vdt).at[hk_verts].set(
-                hk_bc.astype(vdt), mode="drop")
+        # Sort-based candidates on the heavy edges only.
+        if use_sparse:
+            src_s, ckey_s, w_s, ay_s, ts_s = \
+                seg.sort_edges_by_vertex_comm(
+                    hs, ckey_h, hw, jnp.take(env.cdeg_ext, hd),
+                    jnp.take(env.csize_ext, hd),
+                    src_bound=nv_local + 1, key_bound=nv_total)
         else:
-            # Sort-based candidates on the heavy edges only (the historical
-            # path; sparse exchange and oversized layouts stay here).
-            if use_sparse:
-                src_s, ckey_s, w_s, ay_s, ts_s = \
-                    seg.sort_edges_by_vertex_comm(
-                        hs, ckey_h, hw, jnp.take(env.cdeg_ext, hd),
-                        jnp.take(env.csize_ext, hd),
-                        src_bound=nv_local + 1, key_bound=nv_total)
-            else:
-                src_s, ckey_s, w_s = seg.sort_edges_by_vertex_comm(
-                    hs, ckey_h, hw, src_bound=nv_local + 1,
-                    key_bound=nv_total)
-            starts = seg.run_starts(src_s, ckey_s)
-            eiy, _ = seg.run_totals(w_s, starts)
-            i_s = jnp.minimum(src_s, nv_local - 1)
-            comm_i = jnp.take(comm, i_s)
-            valid = starts & (src_s < nv_local) & (ckey_s != comm_i)
-            k_i = jnp.take(vdeg, i_s)
-            a_y = ay_s if use_sparse else jnp.take(comm_deg, ckey_s)
-            a_x = own_deg(i_s) - k_i
-            gain = 2.0 * (eiy - jnp.take(eix, i_s)) \
-                - 2.0 * k_i * (a_y - a_x) * constant
-            gain = jnp.where(valid, gain, neg_inf)
-            hg = seg.segment_max(gain, src_s, num_segments=nv_local,
-                                 sorted_ids=True)
-            at_best = valid & (gain == jnp.take(hg, i_s))
-            cand_c = jnp.where(at_best, ckey_s,
-                               jnp.full_like(ckey_s, sentinel))
-            hc = seg.segment_min(cand_c, src_s, num_segments=nv_local,
-                                 sorted_ids=True)
+            src_s, ckey_s, w_s = seg.sort_edges_by_vertex_comm(
+                hs, ckey_h, hw, src_bound=nv_local + 1,
+                key_bound=nv_total)
+        starts = seg.run_starts(src_s, ckey_s)
+        eiy, _ = seg.run_totals(w_s, starts)
+        i_s = jnp.minimum(src_s, nv_local - 1)
+        comm_i = jnp.take(comm, i_s)
+        valid = starts & (src_s < nv_local) & (ckey_s != comm_i)
+        k_i = jnp.take(vdeg, i_s)
+        a_y = ay_s if use_sparse else jnp.take(comm_deg, ckey_s)
+        a_x = own_deg(i_s) - k_i
+        gain = 2.0 * (eiy - jnp.take(eix, i_s)) \
+            - 2.0 * k_i * (a_y - a_x) * constant
+        gain = jnp.where(valid, gain, neg_inf)
+        hg = seg.segment_max(gain, src_s, num_segments=nv_local,
+                             sorted_ids=True)
+        at_best = valid & (gain == jnp.take(hg, i_s))
+        cand_c = jnp.where(at_best, ckey_s,
+                           jnp.full_like(ckey_s, sentinel))
+        hc = seg.segment_min(cand_c, src_s, num_segments=nv_local,
+                             sorted_ids=True)
         heavy_better = hg > best_gain
         best_gain = jnp.where(heavy_better, hg, best_gain)
         best_c = jnp.where(heavy_better, hc, best_c)

@@ -222,13 +222,13 @@ def _get_step(mesh, nv_total: int, accum_dtype) -> object:
                      "pallas_interpret"),
 )
 def _bucketed_jit(bucket_arrays, heavy_arrays, self_loop, comm, vdeg,
-                  constant, assemble_perm=None, heavy_kernel=None, *,
+                  constant, assemble_perm=None, *,
                   nv_total, sentinel, accum_dtype, pallas_flags=(),
                   pallas_interpret=False):
     call = _bucketed_call(nv_total, sentinel, accum_dtype, pallas_flags,
                           pallas_interpret)
     return call(comm, (bucket_arrays, heavy_arrays, self_loop, vdeg,
-                       constant, assemble_perm, heavy_kernel))
+                       constant, assemble_perm))
 
 
 @functools.partial(
@@ -444,15 +444,12 @@ def _phase_sync(labels, *rest):
 def _bucketed_call(nv_total, sentinel, accum_dtype, pallas_flags=(),
                    pallas_interpret=False):
     def call(comm, extra):
-        # The trailing heavy_kernel slot is None (sorted heavy path) or
-        # the (verts, dstT, wT) layout of the opt-in heavy kernel —
-        # pytree structure, so each engagement state traces separately.
-        buckets, heavy, self_loop, vdeg, constant, perm, hk = extra
+        buckets, heavy, self_loop, vdeg, constant, perm = extra
         return bucketed_step(
             buckets, heavy, self_loop, comm, vdeg, constant,
             nv_total=nv_total, sentinel=sentinel, accum_dtype=accum_dtype,
             pallas_flags=pallas_flags, pallas_interpret=pallas_interpret,
-            assemble_perm=perm, heavy_kernel=hk,
+            assemble_perm=perm,
         )
 
     return call
@@ -832,10 +829,10 @@ class PhaseRunner:
             # small tail phase; above it the class ceiling pads the plan
             # 10-20x over what the phase holds, so the geometry is sized
             # from the coarse graph's degree histogram (its CSR offsets,
-            # O(V)), the host plan's shapes.  The pallas / heavy-kernel
-            # / coloring paths need the host plan's data-dependent
-            # layouts, and ineligible classes (possible heavy residual,
-            # element budget) keep the host oracle.
+            # O(V)), the host plan's shapes.  The pallas and coloring
+            # paths need the host plan's data-dependent layouts, and
+            # ineligible classes (possible heavy residual, element
+            # budget) keep the host oracle.
             src_np = np.asarray(sh.src)
             ne_floor = 16384
             ne_class = max(next_pow2(max(len(src_np), 1)), ne_floor)
@@ -880,8 +877,6 @@ class PhaseRunner:
                                           geometry=geom)
                     jax.block_until_ready(perm_dev)
                 flags = (False,) * len(buckets)
-                hk_dev = None
-                self._heavy_kernel = None
             else:
                 with tracer.stage("bucket"):
                     plan = BucketPlan.build(
@@ -889,19 +884,7 @@ class PhaseRunner:
                         np.asarray(sh.w), nv_local=dg.nv_pad, base=0,
                     )
                 use_pallas = engine == "pallas" and not class_sched
-                # Opt-in heavy-class kernel policy, decided up front: it
-                # engages on the plain bucketed engine too, and a
-                # run that executes ANY Pallas kernel must carry coverage
-                # accounting (the engage-with-coverage convention).
-                from cuvite_tpu.kernels.heavy_bincount import (
-                    build_heavy_layout,
-                    heavy_kernel_enabled,
-                )
-
-                hk_wanted = (plan.has_heavy and not class_sched
-                             and heavy_kernel_enabled())
-                want_cov = use_pallas or hk_wanted
-                if want_cov:
+                if use_pallas:
                     # Per-bucket kernel-coverage accounting (VERDICT r3 weak
                     # #4: a pallas bench must say how much of the edge mass the
                     # kernel actually covers vs the XLA paths).  O(V): the
@@ -914,11 +897,10 @@ class PhaseRunner:
                 flags = []
                 verts_np = []   # padded host verts, for the assembly perm
                 for b in plan.buckets:
-                    if want_cov:
+                    if use_pallas:
                         rv = b.verts[b.verts < dg.nv_pad]
                         cov.append((b.width, int(deg_all[rv].sum()),
-                                    use_pallas
-                                    and b.width <= PALLAS_MAX_WIDTH))
+                                    b.width <= PALLAS_MAX_WIDTH))
                     if use_pallas and b.width <= PALLAS_MAX_WIDTH:
                         # Kernel layout: transposed [D, Nb], Nb a multiple of
                         # the 128-lane tile (pad rows with dropped sentinels).
@@ -948,61 +930,16 @@ class PhaseRunner:
                         verts_np.append(b.verts)
                 buckets = tuple(buckets)
                 flags = tuple(flags)
-                # Opt-in heavy-class kernel: replace the per-iteration
-                # heavy SORT with the community-range-tile bincount kernel
-                # whenever the phase has a heavy residual, the policy says
-                # on (CUVITE_HEAVY_KERNEL=1; interpret mode off the TPU —
-                # how tier-1 pins parity on CPU) and the [D, H] layout fits
-                # its element budget.  Class-scheduled phases sweep per-class plans (the
-                # main plan never runs), so the layout would be dead weight.
-                hk_dev = None
-                if hk_wanted:
-                    lay = build_heavy_layout(
-                        np.asarray(plan.heavy_src),
-                        np.asarray(plan.heavy_dst),
-                        np.asarray(plan.heavy_w),
-                        nv_local=dg.nv_pad, pad_id=nv_total)
-                    if lay is None:
-                        warnings.warn(
-                            "heavy-class kernel: the [D, H] hub layout "
-                            "exceeds CUVITE_HEAVY_ELEMS; this phase's "
-                            "heavy residual degrades to the sorted path",
-                            stacklevel=2)
-                    else:
-                        hv_np, dT_np, wT_np = lay
-                        hk_dev = (
-                            _up(hv_np, vdt),
-                            _up(aligned_copy(dT_np.astype(vdt,
-                                                          copy=False))),
-                            _up(aligned_copy(wT_np.astype(wdt,
-                                                          copy=False))),
-                        )
-                self._heavy_kernel = hk_dev
-                if want_cov:
+                if use_pallas:
                     n_heavy = int(deg_all.sum()) - sum(c[1] for c in cov)
                     if n_heavy:
-                        # width 0 = heavy class; kernelized when the opt-in
-                        # heavy kernel engaged for this phase.
-                        cov.append((0, n_heavy, hk_dev is not None))
-                    # The low-coverage warning is a pallas-engine contract
-                    # (XLA classes are its FALLBACK); under plain bucketed
-                    # the XLA classes are the engine and only the heavy
-                    # kernel's share is reported.
-                    self._record_pallas_coverage(cov, warn=use_pallas)
-                if hk_dev is not None:
-                    # The [D, Hp] layout REPLACES the heavy triples in the
-                    # step (bucketed_step's kernel branch never reads them),
-                    # and the non-class path never runs the triples-based
-                    # mod pass — uploading them anyway would double the
-                    # heavy residual's HBM footprint.  Minimal all-padding
-                    # placeholders keep the call signature.
-                    heavy = (_up(np.full(8, dg.nv_pad, dtype=np.int64), vdt),
-                             _up(np.zeros(8, dtype=np.int64), vdt),
-                             _up(np.zeros(8, dtype=np.float64), wdt))
-                else:
-                    heavy = (_up(plan.heavy_src, vdt),
-                             _up(plan.heavy_dst, vdt),
-                             _up(plan.heavy_w, wdt))
+                        # width 0 = the heavy class: its sorted residual
+                        # is never kernelised.
+                        cov.append((0, n_heavy, False))
+                    self._record_pallas_coverage(cov)
+                heavy = (_up(plan.heavy_src, vdt),
+                         _up(plan.heavy_dst, vdt),
+                         _up(plan.heavy_w, wdt))
                 self_loop = _up(plan.self_loop, wdt)
                 with tracer.stage("bucket"):
                     perm_np = build_assemble_perm(verts_np, dg.nv_pad)
@@ -1012,7 +949,7 @@ class PhaseRunner:
             def _step(src_, dst_, w_, comm, vdeg_, constant):
                 return _bucketed_jit(
                     buckets, heavy, self_loop, comm, vdeg_, constant,
-                    perm_dev, hk_dev,
+                    perm_dev,
                     nv_total=nv_total, sentinel=sentinel, accum_dtype=adt_np,
                     pallas_flags=flags, pallas_interpret=interp,
                 )
@@ -1020,7 +957,6 @@ class PhaseRunner:
             self._step = _step
             self._call = _bucketed_call(nv_total, sentinel, adt_np, flags,
                                         interp)
-            self._hk_slot = True  # _extra carries a heavy_kernel slot
             self._bucket_extra = (buckets, heavy, self_loop, perm_dev)
             self.src = self.dst = self.w = None
             if color_local is not None and n_color_classes > 0:
@@ -1099,11 +1035,6 @@ class PhaseRunner:
             b, h, sl = self._bucket_extra[:3]
             self._extra = (b, h, sl, self.vdeg, self.constant) \
                 + tuple(self._bucket_extra[3:])
-            if getattr(self, "_hk_slot", False):
-                # Single-shard bucketed call convention: the trailing
-                # extra slot is the heavy-kernel layout (None = sorted
-                # heavy path).
-                self._extra = self._extra + (self._heavy_kernel,)
         else:
             self._extra = (self.src, self.dst, self.w, self.vdeg,
                            self.constant)
@@ -1139,25 +1070,19 @@ class PhaseRunner:
         if self._class_plans is not None:
             tracer.track("plans", *jax.tree_util.tree_leaves(
                 self._class_plans))
-        if getattr(self, "_heavy_kernel", None) is not None:
-            tracer.track("plans", *jax.tree_util.tree_leaves(
-                self._heavy_kernel))
 
-    def _record_pallas_coverage(self, cov, warn: bool = True) -> None:
+    def _record_pallas_coverage(self, cov) -> None:
         """Per-width kernel-coverage accounting (VERDICT r3 weak #4): a
         pallas bench must say how much of the edge mass the kernel actually
         covers vs the XLA paths.  ``cov`` is a list of (width, n_edges,
         kernelized) with width 0 standing for the heavy class; shared by
         the single-shard and SPMD upload paths so the report means the
-        same thing on any mesh.  ``warn=False``: the bucketed engine with
-        the opt-in heavy kernel engaged reports coverage too (ISSUE 8 —
-        any run executing a Pallas kernel must carry the accounting), but
-        its XLA classes are the engine, not a fallback to warn about."""
+        same thing on any mesh."""
         total = max(sum(c[1] for c in cov), 1)
         kernelized = sum(c[1] for c in cov if c[2])
         self.pallas_coverage = kernelized / total
         self.pallas_cov_detail = cov
-        if warn and self.pallas_coverage < 0.5:
+        if self.pallas_coverage < 0.5:
             warnings.warn(
                 f"engine='pallas': only "
                 f"{100 * self.pallas_coverage:.0f}% of edges are in "
@@ -1465,7 +1390,6 @@ def _run_fused(graph, *, threshold, threshold_cycling, one_phase, balanced,
         device_compose_labels,
         device_renumber,
     )
-    from cuvite_tpu.kernels.seg_coalesce import coalesce_engine
     from cuvite_tpu.louvain.fused import fused_louvain
 
     t_start = time.perf_counter()
@@ -1642,7 +1566,6 @@ def _run_fused(graph, *, threshold, threshold_cycling, one_phase, balanced,
                 # pow2 class of the next level.
                 dmap, nc_d = renumber_d  # same (labels_d, real_mask_d)
                 acc = adt if adt == "ds32" else None
-                eng = coalesce_engine(nv_pad, acc)
                 ne_in = real_ne
                 # Nested stage: coalesce_s (the relabel+coalesce slice,
                 # incl. its ne2 scalar sync) SPLITS OUT of coarsen_s so
@@ -1652,11 +1575,9 @@ def _run_fused(graph, *, threshold, threshold_cycling, one_phase, balanced,
                         device_coarsen_slab(
                             src_d, dst_d, w_d, labels_d, real_mask_d,
                             nv_pad=nv_pad, accum_dtype=acc,
-                            dense_map=dmap, nc=nc_d, coalesce=eng)
+                            dense_map=dmap, nc=nc_d)
                     real_nv, real_ne = nc, int(ne2_d)
                 tracer.count("coalesce_edges", ne_in)
-                if eng != "sort":
-                    tracer.count("coalesce_dense_edges", ne_in)
                 src_d, dst_d, w_d, nv_pad, ne_pad = maybe_shrink_to_class(
                     src_d, dst_d, w_d, nc=real_nv, ne2=real_ne,
                     nv_pad=nv_pad, ne_pad=ne_pad)
@@ -1966,7 +1887,7 @@ def louvain_phases(
     tot_iters = 0
     # engine='pallas' kernel-coverage accounting, traversed-edge weighted
     # across phases (coarse phases sweep less mass but more often).
-    cov_num = cov_den = cov_pending = 0
+    cov_num = cov_den = 0
     width_hits: dict = {}
     # Phase-1 exchange-plan digest (ISSUE 18): the full-scale graph's
     # per-device table/ghost bytes — the number the bench `exchange`
@@ -2234,14 +2155,7 @@ def louvain_phases(
         if exchange_stats is None and multi_mesh:
             exchange_stats = dict(runner.xplan_stats or
                                   {"mode": phase_exchange})
-        if getattr(runner, "pallas_coverage", None) is not None:
-            if engine != "pallas" and cov_den == 0:
-                # Bucketed run, first kernel engagement: the phases
-                # already processed WITHOUT coverage count as
-                # non-kernelized mass, or the run-level fraction would
-                # overstate itself (same rule as the class-schedule
-                # case below).
-                cov_den += cov_pending
+        if phase_cov is not None:
             for w, n, k in runner.pallas_cov_detail:
                 t = n * iters
                 cov_den += t
@@ -2255,21 +2169,13 @@ def louvain_phases(
                 print(f"pallas kernel coverage: "
                       f"{100 * runner.pallas_coverage:.1f}% of edges "
                       f"(per-width, * = kernel: {det})")
-        elif engine == "pallas" or cov_den:
+        elif engine == "pallas":
             # Class-scheduled phases (coloring/ordering — typically phase
             # 0, the bulk of the run's edge mass) sweep the XLA per-class
             # plans, never the kernel: their traversed mass counts as
             # NON-kernelized, or the run-level coverage would report only
-            # the later plain phases and overstate itself.  Same rule for
-            # a bucketed run whose heavy kernel engaged earlier (cov_den
-            # nonzero) but whose coarser phases have no heavy residual.
+            # the later plain phases and overstate itself.
             cov_den += g_ne * iters
-        else:
-            # No coverage recorded yet: remember this phase's mass so a
-            # LATER heavy-kernel engagement (bucketed engine) folds it
-            # into the denominator.  Engines that never engage leave
-            # cov_den at 0 and report no coverage at all.
-            cov_pending += g_ne * iters
         # The loop's f32 modularity decided convergence; the REPORTED value
         # is recomputed once per phase with f64-class accuracy
         # (louvain/precise.py) — the analog of the reference's double
@@ -2363,28 +2269,20 @@ def louvain_phases(
                         nc, cs, cd, weights=cw, symmetrize=False,
                         policy=dg.graph.policy)
                 elif dev_transition:
-                    from cuvite_tpu.kernels.seg_coalesce import (
-                        coalesce_engine,
-                    )
-
                     acc = (runner.accum_name
                            if runner.accum_name == "ds32" else None)
-                    eng = coalesce_engine(dg.nv_pad, acc)
                     with tracer.stage("coalesce"):
                         src2, dst2, w2, _dm, _nc_d, ne2_d = \
                             device_coarsen_slab(
                                 runner.src, runner.dst, runner.w,
                                 runner.labels_dev, runner.real_mask_dev,
-                                nv_pad=dg.nv_pad, accum_dtype=acc,
-                                coalesce=eng)
+                                nv_pad=dg.nv_pad, accum_dtype=acc)
                         # The one scalar-per-phase host sync (nc is
                         # already on the host from the renumber above):
                         # decides whether the coarse graph fits a
                         # smaller pow2 slab class.
                         ne2 = int(ne2_d)
                     tracer.count("coalesce_edges", g_ne)
-                    if eng != "sort":
-                        tracer.count("coalesce_dense_edges", g_ne)
                     pol = dg.graph.policy
                     tw2 = dg.graph.total_edge_weight_twice()
                     src2, dst2, w2, new_nv_pad, new_ne_pad = \

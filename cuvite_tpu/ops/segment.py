@@ -165,63 +165,35 @@ def sort_edges_by_vertex_comm(src, ckey, w, *extras, src_bound=None,
     return jax.lax.sort((src, ckey, w) + extras, num_keys=2)
 
 
-def sort_edges_msd(src, ckey, w, *, nv_pad):
-    """Stable (src, ckey) sort for slab classes whose packed key exceeds
-    31 bits: an MSD src-partition as TWO stable int32 single-key sorts,
-    replacing the variadic two-operand comparator that
-    :func:`sort_edges_by_vertex_comm` degrades to once
-    kbits + sbits > 31 (the nv_pad >= 2^16 comparator tax, BASELINE
-    round-10).
+def coalesced_runs(src, ckey, w, *, nv_pad, accum_dtype=None):
+    """Segmented coalesce of an edge slab by (src, ckey): one output row
+    per distinct real (src, ckey) pair, rows in ascending (src, ckey)
+    order COMPACTED into the slab prefix, duplicate weights summed.
 
-    Pass 1 sorts by the int32 key ``(src_low << kbits) | ckey`` where
-    ``src_low`` keeps the low ``31 - kbits`` bits of src; pass 2 sorts
-    the result STABLY by ``src_hi = src >> (31 - kbits)`` alone.  Stable
-    composition: pass 2 preserves pass 1's (src_low, ckey) order within
-    equal src_hi, so the final order is lexicographic
-    (src_hi, src_low, ckey) == (src, ckey) — bit-identical to the
-    packed/variadic paths, including run order for ds32 pair sums.
-    Padding rows (src == nv_pad, a pow2) have src_low == 0 and the
-    maximal src_hi, so they still sort to the tail.
+    Same (src, ckey, w) operand convention as
+    :func:`sort_edges_by_vertex_comm` — real ids < ``nv_pad`` (pow2),
+    padding rows carry src == nv_pad and w == 0 — but the contract is the
+    COALESCED result, not a sorted copy.  THE sanctioned coalesce
+    chokepoint (graftlint R013 allows no other full-slab sort in coarsen/
+    or kernels/): stable sort via :func:`sort_edges_by_vertex_comm`
+    (src_bound = nv_pad + 1, key_bound = nv_pad), run detection, run sums
+    in ``accum_dtype`` (None = weight dtype; ``'ds32'`` = double-single
+    pairs collapsed to f32 once), emit at run-last positions.  Run
+    PRESENCE decides the emitted rows, so real zero-weight edges survive.
 
-    Classes that fit the single int32 pack delegate to the packed sort
-    (one pass beats two); ckey spaces needing >= 31 bits on their own
-    (nv_pad >= 2^31 — beyond every slab class) fall back to the
-    variadic comparator.
+    Returns ``(src_c, ckey_c, w_c, n)``: [ne_pad]-shaped arrays with real
+    rows in [0, n) and padding (src == nv_pad, ckey == 0, w == 0) after.
     """
-    kbits = max(nv_pad - 1, 1).bit_length()
-    sbits = nv_pad.bit_length()  # src_bound = nv_pad + 1 (padding rows)
-    if kbits + sbits <= 31:
-        return sort_edges_by_vertex_comm(
-            src, ckey, w, src_bound=nv_pad + 1, key_bound=nv_pad)
-    s_low = 31 - kbits
-    if s_low <= 0:
-        return jax.lax.sort((src, ckey, w), num_keys=2)
-    low_mask = (1 << s_low) - 1
-    key1 = (((src.astype(jnp.int32) & low_mask) << kbits)  # graftlint: width-ok=src field masked to s_low = 31 - kbits bits, so key1 < 2^(s_low + kbits) = 2^31 by construction
-            | ckey.astype(jnp.int32))
-    key1_s, src_1, w_1 = jax.lax.sort(
-        (key1, src.astype(jnp.int32), w), num_keys=1)
-    ckey_1 = key1_s & ((1 << kbits) - 1)
-    hi = src_1 >> s_low
-    _, src_s, ckey_s, w_s = jax.lax.sort(
-        (hi, src_1, ckey_1, w_1), num_keys=1)
-    return (src_s.astype(src.dtype), ckey_s.astype(ckey.dtype), w_s)
-
-
-def _runs_from_sorted(src_s, ckey_s, w_s, *, nv_pad, accum_dtype):
-    """Run detection + run sums + compacted emission over a slab already
-    in stable ascending (src, ckey) order — the shared tail of every
-    SORTING coalesce engine ('sort', 'msd', and the hash engine's
-    collision fallback), so their outputs are bit-identical by
-    construction, ds32 pair sums included (equal sorted order => equal
-    run segmentation => equal pair arithmetic)."""
-    ne_pad = src_s.shape[0]
+    ne_pad = src.shape[0]
     if ne_pad > SLAB_NE_MAX:
         raise ValueError(
-            f"_runs_from_sorted: slab has {ne_pad} rows, over SLAB_NE_MAX "
+            f"coalesced_runs: slab has {ne_pad} rows, over SLAB_NE_MAX "
             f"= {SLAB_NE_MAX}: the int32 run-id/compaction cumsums "
-            f"would overflow (wrong labels, not a crash) — shard the "
-            f"slab below the ceiling first")
+            "would overflow (wrong labels, not a crash) — shard the "
+            "slab below the ceiling first")
+    # Dense ids are < nv_pad; padding src == nv_pad sorts to the tail.
+    src_s, ckey_s, w_s = sort_edges_by_vertex_comm(
+        src, ckey, w, src_bound=nv_pad + 1, key_bound=nv_pad)
     wdt = w_s.dtype
     starts = run_starts(src_s, ckey_s)
     run_id = jnp.cumsum(starts.astype(jnp.int32)) - 1
@@ -256,122 +228,6 @@ def _runs_from_sorted(src_s, ckey_s, w_s, *, nv_pad, accum_dtype):
         ckey_s, mode="drop")
     w_c = jnp.zeros((ne_pad,), wdt).at[slot].set(run_w, mode="drop")
     return src_c, ckey_c, w_c, n
-
-
-def coalesced_runs(src, ckey, w, *, nv_pad, accum_dtype=None,
-                   engine="sort"):
-    """Segmented coalesce of an edge slab by (src, ckey): one output row
-    per distinct real (src, ckey) pair, rows in ascending (src, ckey)
-    order COMPACTED into the slab prefix, duplicate weights summed.
-
-    The ``sort_edges_by_vertex_comm``-shaped entry point of ISSUE 8: same
-    (src, ckey, w) operand convention — real ids < ``nv_pad`` (pow2),
-    padding rows carry src == nv_pad and w == 0 — but the contract is the
-    COALESCED result, not a sorted copy, which frees the engine choice:
-
-    * ``engine='xla'`` — the dense bin-accumulate
-      (cuvite_tpu/kernels/seg_coalesce.py): no sorted copy of the slab is
-      ever materialized.  Static eligibility (nv_pad within the
-      accumulator budget, no ds32) is the CALLER's job via
-      ``seg_coalesce.coalesce_engine`` — passing an ineligible class here
-      is a bug, not a fallback.
-    * ``engine='sort'`` — THE sanctioned packed-sort fallback chokepoint
-      (graftlint R013 allows no other full-slab sort in coarsen/ or
-      kernels/): stable sort via :func:`sort_edges_by_vertex_comm`
-      (src_bound = nv_pad + 1, key_bound = nv_pad), run detection, run
-      sums in ``accum_dtype`` (None = weight dtype; ``'ds32'`` =
-      double-single pairs collapsed to f32 once), emit at run-last
-      positions.  This is bit-for-bit the historical
-      device_coarsen_slab coalesce.
-    * ``engine='msd'`` — same contract, but the stable (src, ckey) order
-      comes from :func:`sort_edges_msd`: two int32 single-key passes for
-      the classes where kbits + sbits > 31 degrades the packed sort to
-      the variadic comparator (nv_pad >= 2^16).  Shares the run-sum /
-      emission tail with 'sort', so it is bit-identical in every mode,
-      ds32 included — the drop-in big-class engine.
-    * ``engine='hash'`` — hash-slot coalesce
-      (kernels/seg_coalesce.py::hash_accumulate): K static slots per
-      src, scatter-accumulated in one O(ne) pass, with DEVICE-side
-      collision detection; a colliding slab falls back to the
-      'msd'-sorted tail inside ``lax.cond`` (no host sync, still
-      bit-identical to the sort engines).  Weight sums on the collision-
-      free path are in slab (scatter) order — the dense engines'
-      exactness domain — so ``accum_dtype`` must be None
-      (``coalesce_engine`` routes explicit accumulators to 'msd').
-
-    Returns ``(src_c, ckey_c, w_c, n)``: [ne_pad]-shaped arrays with real
-    rows in [0, n) and padding (src == nv_pad, ckey == 0, w == 0) after.
-    Dense engines (and the hash engine's collision-free path) sum
-    duplicates in slab order, the sorting engines in sorted order —
-    bit-identical wherever run sums are exactly representable
-    (unit/dyadic weights; the documented exactness domain, see
-    kernels/seg_coalesce.py).  ds32 must use a sorting engine
-    ('sort' or 'msd').
-    """
-    ne_pad = src.shape[0]
-    if ne_pad > SLAB_NE_MAX:
-        raise ValueError(
-            f"coalesced_runs: slab has {ne_pad} rows, over SLAB_NE_MAX "
-            f"= {SLAB_NE_MAX}: the int32 run-id/compaction cumsums "
-            "would overflow (wrong labels, not a crash) — shard the "
-            "slab below the ceiling first")
-    if engine == "xla":
-        # The dense accumulators sum in the weight dtype only: a caller
-        # that requested ANY explicit accumulator (ds32 pairs or a wider
-        # plain dtype) must take the sort path — silently narrowing the
-        # requested accumulation would diverge from the sort engine
-        # outside the exactness domain.  coalesce_engine() enforces the
-        # same rule at policy level.
-        assert accum_dtype is None, \
-            f"accum_dtype={accum_dtype!r} needs the sort engine (dense " \
-            "engines accumulate in the weight dtype only)"
-        from cuvite_tpu.kernels.seg_coalesce import coalesce_slab
-
-        return coalesce_slab(src, ckey, w, nv_pad=nv_pad)
-
-    if engine == "hash":
-        # Hash-slot tables sum in the weight dtype (slab order): an
-        # explicit accumulator must take a sorting engine —
-        # coalesce_engine routes it to 'msd' before it gets here.
-        assert accum_dtype is None, \
-            f"accum_dtype={accum_dtype!r} needs a sorting engine (the " \
-            "hash tables accumulate in the weight dtype only)"
-        from cuvite_tpu.kernels.seg_coalesce import (
-            hash_accumulate, hash_emit, hash_slots,
-        )
-
-        k = hash_slots(nv_pad, ne_pad)
-        wsum, cnt, dmin, dmax = hash_accumulate(
-            src, ckey, w, nv_pad=nv_pad, k=k)
-        # A slot holding two DISTINCT ckeys cannot emit (dmin carries one
-        # ckey, wsum both weights): detect ON DEVICE and retry the whole
-        # slab through the msd-sorted tail — same (src, ckey) order as
-        # the sort engine, so the retry is bit-identical to it.
-        collision = jnp.any((cnt > 0) & (dmin != dmax))
-
-        def _retry_sorted(_):
-            src_s, ckey_s, w_s = sort_edges_msd(src, ckey, w,
-                                                nv_pad=nv_pad)
-            return _runs_from_sorted(src_s, ckey_s, w_s, nv_pad=nv_pad,
-                                     accum_dtype=None)
-
-        def _emit_hash(_):
-            return hash_emit(wsum, cnt, dmin, nv_pad=nv_pad,
-                             ne_pad=ne_pad, k=k, src_dtype=src.dtype,
-                             ckey_dtype=ckey.dtype)
-
-        return jax.lax.cond(collision, _retry_sorted, _emit_hash, 0)
-
-    if engine == "msd":
-        src_s, ckey_s, w_s = sort_edges_msd(src, ckey, w, nv_pad=nv_pad)
-    else:
-        # Sanctioned sort fallback: stable (src, ckey) order through the
-        # packed-key machinery; dense ids are < nv_pad, padding
-        # src == nv_pad sorts to the tail.
-        src_s, ckey_s, w_s = sort_edges_by_vertex_comm(
-            src, ckey, w, src_bound=nv_pad + 1, key_bound=nv_pad)
-    return _runs_from_sorted(src_s, ckey_s, w_s, nv_pad=nv_pad,
-                             accum_dtype=accum_dtype)
 
 
 def run_starts(src_s, ckey_s):

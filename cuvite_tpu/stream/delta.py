@@ -20,7 +20,7 @@ chokepoint:
     w -> 0 — exactly a padding row); inserts are masked-appended into
     the slab's padding headroom at traced offset ``ne``; then the whole
     slab re-canonicalizes through the segmented-coalesce chokepoint
-    (ops/segment.py::coalesced_runs, sort engine), whose output
+    (ops/segment.py::coalesced_runs, a packed sort), whose output
     contract — ascending (src, dst), duplicates summed, compacted,
     sentinel padding after — is bit-identical to what
     ``DistGraph.build`` derives from ``Graph.from_edges`` on the
@@ -257,8 +257,7 @@ def apply_delta_slab(src, dst, w, ins_src, ins_dst, ins_w, del_src,
 
     # --- re-canonicalize through the coalesce chokepoint ------------------
     src2, dst2, w2, ne2 = seg.coalesced_runs(
-        src, dst, w, nv_pad=nv_pad, accum_dtype=accum_dtype,
-        engine="sort")
+        src, dst, w, nv_pad=nv_pad, accum_dtype=accum_dtype)
     return src2, dst2, w2.astype(wdt), ne2, del_w, n_del_hit
 
 

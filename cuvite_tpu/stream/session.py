@@ -291,8 +291,7 @@ class StreamSession:
         acc = adt if adt == "ds32" else None
         csrc, cdst, cw, _dm, _nc, ne2_d = device_coarsen_slab(
             self.src, self.dst, self.w, labels_d, real_mask,
-            nv_pad=nv_pad, accum_dtype=acc, dense_map=dmap, nc=nc_d,
-            coalesce="sort")
+            nv_pad=nv_pad, accum_dtype=acc, dense_map=dmap, nc=nc_d)
         nc, ne2, mod0, iters0 = jax.device_get(  # graftlint: disable=R010 — phase-scalar sync, O(1), the streaming analog of the fused driver's per-call stat fetch
             (nc_d, ne2_d, mod0_d, iters0_d))
         nc, ne2, iters0 = int(nc), int(ne2), int(iters0)

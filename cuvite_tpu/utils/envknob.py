@@ -1,5 +1,5 @@
 """Shared integer env-knob parser for the kernel budget/eligibility
-knobs (CUVITE_SEG_COALESCE_MAX_NV, CUVITE_HEAVY_ELEMS, ...).
+knobs (CUVITE_REBIN_MAX_ELEMS, CUVITE_SCHED_BUDGET).
 
 One definition so the parse/warn/default behavior cannot drift between
 copies: accepts 0x/0b prefixes (``int(raw, 0)``), warns loudly on

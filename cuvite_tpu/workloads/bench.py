@@ -147,17 +147,6 @@ def validate_record(rec: dict) -> list:
         if not isinstance(rec["hbm_peak_by_buffer"], dict):
             problems.append("hbm_peak_by_buffer must be a dict of "
                             "category -> peak nbytes")
-        ck = rec.get("coalesce_kernel")
-        if ck is not None and not (isinstance(ck, (int, float))
-                                   and 0.0 <= ck <= 1.0):
-            # Optional (device-coarsening runs only): the edge-weighted
-            # fraction of inter-phase coalesces that ran a dense
-            # seg_coalesce engine instead of the packed-sort fallback
-            # (ISSUE 8) — the honesty label tools/perf_regress.py needs
-            # next to a coalesce_s number.
-            problems.append(
-                f"coalesce_kernel must be a fraction in [0, 1], got "
-                f"{ck!r}")
         rd = rec.get("rebin_device")
         if rd is not None and not (isinstance(rd, (int, float))
                                    and 0.0 <= rd <= 1.0):
@@ -563,14 +552,6 @@ def run_bench(
         if scale is not None:
             out["scale"] = scale
         tr_counters = (tr.counters if tr is not None else {})
-        co_total = tr_counters.get("coalesce_edges", 0)
-        if co_total:
-            # Edge-weighted dense-engine coverage of the inter-phase
-            # coalesce (ISSUE 8): 0.0 = every coalesce took the
-            # packed-sort fallback (the honest default until the chip
-            # A/B promotes a dense engine).
-            out["coalesce_kernel"] = round(
-                tr_counters.get("coalesce_dense_edges", 0) / co_total, 4)
         rb_total = tr_counters.get("rebin_phases", 0)
         if rb_total:
             # Device-rebin coverage of the coarse bucketed phases

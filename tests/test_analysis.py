@@ -399,8 +399,8 @@ import jax.numpy as jnp
 from cuvite_tpu.ops import segment as seg
 
 def coalesce(src, dst, w, nv_pad):
-    # routed through the sanctioned fallback chokepoint
-    return seg.coalesced_runs(src, dst, w, nv_pad=nv_pad, engine="sort")
+    # routed through the sanctioned coalesce chokepoint
+    return seg.coalesced_runs(src, dst, w, nv_pad=nv_pad)
 
 def tiny_row_sort(row):
     # a genuinely non-slab sort, justified inline

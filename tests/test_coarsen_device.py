@@ -110,6 +110,46 @@ def test_device_matches_host_bitwise_unit_weights(rmat10, gapped, accum):
     assert np.array_equal(np.asarray(dmap)[comm_old], dense)
 
 
+@pytest.mark.parametrize("accum", [None, "ds32"])
+def test_batched_coarsen_matches_per_graph_host(accum):
+    """The serving path's coarsen: batched_coarsen_slab over B graphs of
+    one slab class equals, row by row, the host coarsen_graph of each
+    graph under its own labelling."""
+    from cuvite_tpu.coarsen.device import (
+        batched_coarsen_slab,
+        batched_renumber,
+    )
+
+    rng = np.random.default_rng(17)
+    graphs = [generate_rmat(9, edge_factor=8, seed=s) for s in (1, 2, 3)]
+    dgs = [DistGraph.build(g, 1) for g in graphs]
+    nv_pad, ne_pad = dgs[0].nv_pad, dgs[0].ne_pad
+    assert all((d.nv_pad, d.ne_pad) == (nv_pad, ne_pad) for d in dgs)
+    labs = [_random_padded_labels(g, nv_pad, rng, gapped=(i == 1))
+            for i, g in enumerate(graphs)]
+
+    def stack(f):
+        return jnp.asarray(np.stack([f(d, lab) for d, lab in zip(dgs, labs)]))
+
+    src = stack(lambda d, _l: np.asarray(d.shards[0].src))
+    dst = stack(lambda d, _l: np.asarray(d.shards[0].dst))
+    w = stack(lambda d, _l: np.asarray(d.shards[0].w))
+    comm = stack(lambda d, lab: lab.astype(np.int32))
+    mask = stack(lambda d, _l: d.vertex_mask())
+    dmap, nc = batched_renumber(comm, mask, nv_pad=nv_pad)
+    src2, dst2, w2, _dm, nc2, ne2 = jax.device_get(batched_coarsen_slab(
+        src, dst, w, comm, mask, dmap, nc, nv_pad=nv_pad,
+        accum_dtype=accum))
+    for b, (g, lab) in enumerate(zip(graphs, labs)):
+        gh, _dense, nc_h = _host_coarse(g, lab)
+        n = int(ne2[b])
+        assert int(nc2[b]) == nc_h and n == gh.num_edges
+        assert np.array_equal(src2[b, :n], gh.sources())
+        assert np.array_equal(dst2[b, :n], gh.tails)
+        assert np.array_equal(w2[b, :n], gh.weights)
+        assert (src2[b, n:] == nv_pad).all() and (w2[b, n:] == 0).all()
+
+
 def test_device_renumber_matches_np_unique_on_gaps(rmat10):
     dg = DistGraph.build(rmat10, 1)
     rng = np.random.default_rng(11)
@@ -197,11 +237,25 @@ def test_shrink_slab_prefix_and_sentinel():
 # End-to-end: device transition == host transition, and the guards
 
 
-def test_sort_engine_device_vs_host_full_run(rmat10, monkeypatch):
+@pytest.fixture(scope="module")
+def dyadic_graph():
+    """A weighted graph in the exactness domain: 1/8-multiple weights
+    and self-loops, so device and host coalesces agree bit for bit."""
+    rng = np.random.default_rng(21)
+    nv, ne = 1500, 6000
+    src = rng.integers(0, nv, ne)
+    dst = np.where(rng.random(ne) < 0.05, src, rng.integers(0, nv, ne))
+    w = rng.integers(1, 32, ne).astype(np.float64) / 8.0
+    return Graph.from_edges(nv, src, dst, weights=w)
+
+
+@pytest.mark.parametrize("graph", ["rmat10", "dyadic_graph"])
+def test_sort_engine_device_vs_host_full_run(graph, monkeypatch, request):
+    g = request.getfixturevalue(graph)
     monkeypatch.setenv("CUVITE_DEVICE_COARSEN", "0")
-    r0 = louvain_phases(rmat10, engine="sort")
+    r0 = louvain_phases(g, engine="sort")
     monkeypatch.delenv("CUVITE_DEVICE_COARSEN")
-    r1 = louvain_phases(rmat10, engine="sort")
+    r1 = louvain_phases(g, engine="sort")
     assert len(r0.phases) == len(r1.phases) >= 3
     assert r0.total_iterations == r1.total_iterations
     assert r0.modularity == r1.modularity  # both use the device ds pass

@@ -93,138 +93,160 @@ def test_row_argmax_pallas_no_candidates():
     assert np.allclose(np.asarray(c0), width)
 
 
+# ---------------------------------------------------------------------------
+# The heavy class (degree > widths[-1]): bucketed_step's sorted residual,
+# fed rows the quadratic all-pairs path scores too.  Each row r is vertex
+# r with every slot in the heavy residual and no degree class, so the
+# step's targets and Q come from the sorted heavy path alone.
+
+
+def _heavy_step(dst, wmat, comm, vdeg, sl, constant):
+    """bucketed_step over a heavy residual holding row r's slots as
+    vertex r's edges (padding src == nv, w == 0)."""
+    import functools
+
+    import jax
+
+    from cuvite_tpu.louvain.bucketed import bucketed_step
+
+    n_rows, width = dst.shape
+    nv = comm.shape[0]
+    n = n_rows * width
+    npad = 1 << max(n - 1, 1).bit_length()
+    hs = np.full(npad, nv, np.int32)
+    hd = np.zeros(npad, np.int32)
+    hw = np.zeros(npad, np.float32)
+    hs[:n] = np.repeat(np.arange(n_rows, dtype=np.int32), width)
+    hd[:n] = dst.ravel()
+    hw[:n] = wmat.ravel()
+    step = jax.jit(functools.partial(bucketed_step, nv_total=nv,
+                                     sentinel=SENTINEL))
+    t, q, _n, _ovf = step((), tuple(jnp.asarray(x) for x in (hs, hd, hw)),
+                          jnp.asarray(sl), jnp.asarray(comm),
+                          jnp.asarray(vdeg), jnp.asarray(constant))
+    return np.asarray(t), float(q)
+
+
+def _heavy_oracle(dst, wmat, comm, vdeg, sl, constant):
+    """The step's targets and Q from the quadratic _row_argmax over the
+    same rows: move on a strictly positive gain, ties to the smaller id,
+    the singleton guard; vertices without edges stay."""
+    n_rows = dst.shape[0]
+    nv = comm.shape[0]
+    comm_deg = np.bincount(comm, weights=vdeg, minlength=nv).astype(
+        np.float32)
+    size = np.bincount(comm, minlength=nv)
+    cmat = comm[dst]
+    curr = comm[:n_rows]
+    ref = _row_argmax(
+        jnp.asarray(cmat), jnp.asarray(wmat), jnp.asarray(comm_deg[cmat]),
+        None, jnp.asarray(curr), jnp.asarray(vdeg[:n_rows]),
+        jnp.asarray(sl[:n_rows]),
+        jnp.asarray(comm_deg[curr] - vdeg[:n_rows]),
+        jnp.asarray(constant), SENTINEL)
+    best_c = np.minimum(np.asarray(ref.best_c), nv - 1)
+    guard = (size[best_c] == 1) & (size[curr] == 1) & (best_c > curr)
+    move = (np.asarray(ref.best_gain) > 0) & ~guard
+    target = comm.copy()
+    target[:n_rows] = np.where(move, best_c, curr)
+    c = np.float64(constant)
+    q = (np.asarray(ref.counter0, np.float64).sum() * c
+         - np.square(comm_deg.astype(np.float64) * c).sum())
+    return target, q
+
+
+def _heavy_rows(n_rows, width, nv, n_comm, seed, w_lo=1):
+    """Random hub rows over ``nv`` vertices in ``n_comm`` communities:
+    1/16-multiple weights (every f32 sum exact in any order, so the
+    sorted and all-pairs aggregations agree bit for bit), half the rows
+    with a self-loop slot, self-loop weights consistent with the rows."""
+    rng = np.random.default_rng(seed)
+    comm = rng.integers(0, n_comm, nv).astype(np.int32)
+    dst = rng.integers(0, nv, (n_rows, width)).astype(np.int32)
+    dst[: n_rows // 2, 0] = np.arange(n_rows // 2)
+    wmat = (rng.integers(w_lo, 32, (n_rows, width)) / 16.0).astype(
+        np.float32)
+    vdeg = (rng.integers(1, 64, nv) / 4.0).astype(np.float32)
+    sl = np.zeros(nv, np.float32)
+    sl[:n_rows] = np.where(dst == np.arange(n_rows)[:, None], wmat,
+                           0.0).sum(axis=1)
+    return dst, wmat, comm, vdeg, sl
+
+
 @pytest.mark.parametrize("seed", [0, 5])
 @pytest.mark.parametrize("constant", [None, np.float32(0.3)])
-def test_heavy_bincount_matches_quadratic_oracle(seed, constant):
-    """Heavy-class community-range-tile kernel (heavy_bincount.py) vs the
-    quadratic XLA fallback on the same rows: identical best_c/best_gain/
-    counter0 bit-for-bit (1/16-multiple weights make f32 sums exact in any
-    order, so the matmul-bincount and the all-pairs aggregation agree;
-    the non-dyadic constant=0.3 case additionally pins the gain's operand
-    association to the XLA path's)."""
-    from cuvite_tpu.kernels.heavy_bincount import heavy_argmax_pallas
-
-    n_rows, width, nv = 64, 512, 500
-    nv_ceil, c_tile, d_chunk = 512, 128, 128
-    cmat, wmat, curr, vdeg, sl, comm_deg, _const_dyadic = _bucket_case(
-        n_rows, width, nv, seed)
-    constant = _const_dyadic if constant is None else constant
-    is_cc = cmat == curr[:, None]
-    counter0 = np.sum(np.where(is_cc, wmat, 0.0), axis=1).astype(np.float32)
-    ay = comm_deg[cmat]
-    ax = comm_deg[curr] - vdeg
-    ref = _row_argmax(
-        jnp.asarray(cmat), jnp.asarray(wmat), jnp.asarray(ay), None,
-        jnp.asarray(curr), jnp.asarray(vdeg), jnp.asarray(sl),
-        jnp.asarray(ax), jnp.asarray(constant), SENTINEL,
-    )
-    comm_deg_pad = np.zeros(nv_ceil, dtype=np.float32)
-    comm_deg_pad[:nv] = comm_deg
-    bc, bg, c0 = heavy_argmax_pallas(
-        jnp.asarray(np.ascontiguousarray(cmat.T)),
-        jnp.asarray(np.ascontiguousarray(wmat.T)),
-        jnp.asarray(comm_deg_pad),
-        jnp.asarray(curr), jnp.asarray(vdeg), jnp.asarray(sl),
-        jnp.asarray(ax), jnp.asarray(constant),
-        c_tile=c_tile, d_chunk=d_chunk, interpret=True,
-    )
-    assert np.array_equal(np.asarray(c0), counter0)
-    assert np.array_equal(np.asarray(bg), np.asarray(ref.best_gain))
-    assert np.array_equal(np.asarray(bc), np.asarray(ref.best_c))
+def test_heavy_residual_matches_quadratic_oracle(seed, constant):
+    """The sorted heavy residual of bucketed_step vs the quadratic
+    all-pairs path on the same rows: identical targets, Q from the same
+    counter0.  The non-dyadic constant=0.3 case also pins the gain's
+    operand association to the all-pairs path's."""
+    n_rows, width, nv = 64, 512, 512
+    case = _heavy_rows(n_rows, width, nv, n_comm=200, seed=seed)
+    constant = np.float32(1.0 / 1024) if constant is None else constant
+    got, q = _heavy_step(*case, constant)
+    want, q_ref = _heavy_oracle(*case, constant)
+    assert np.array_equal(got, want)
+    assert (got[:n_rows] != case[2][:n_rows]).sum() >= n_rows // 4
+    assert q == pytest.approx(q_ref, rel=1e-6, abs=1e-6)
 
 
-def test_heavy_bincount_zero_weight_edges_are_candidates():
+def test_heavy_residual_zero_weight_edges_are_candidates():
     """A community reached only by a w=0 edge is still a valid move target
-    (same invariant as the XLA paths: 'No w>0 filter').  Its gain
-    -2*eix - 2*vdeg*const*(ay-ax) can win when ay < ax."""
-    from cuvite_tpu.kernels.heavy_bincount import heavy_argmax_pallas
-
-    n_rows, width, nv = 16, 128, 120
-    nv_ceil, c_tile, d_chunk = 128, 128, 128
-    rng = np.random.default_rng(9)
-    cmat = rng.integers(0, nv, size=(n_rows, width)).astype(np.int32)
-    wmat = (rng.integers(0, 4, size=(n_rows, width)) / 16.0).astype(
-        np.float32)  # ~1/4 of edges have weight 0
-    curr = rng.integers(0, nv, size=n_rows).astype(np.int32)
-    vdeg = np.maximum(wmat.sum(axis=1), 0.25).astype(np.float32)
-    sl = np.zeros(n_rows, dtype=np.float32)
-    comm_deg = (rng.integers(1, 64, size=nv) / 8.0).astype(np.float32)
-    ay = comm_deg[cmat]
-    ax = comm_deg[curr] - vdeg
+    (same invariant as the degree classes: 'No w>0 filter'), and equal
+    gains go to the smaller community id."""
+    n_rows, width, nv = 16, 128, 128
+    case = _heavy_rows(n_rows, width, nv, n_comm=60, seed=9, w_lo=0)
+    assert (case[1] == 0).any()
     constant = np.float32(1.0 / 16.0)
-    ref = _row_argmax(
-        jnp.asarray(cmat), jnp.asarray(wmat), jnp.asarray(ay), None,
-        jnp.asarray(curr), jnp.asarray(vdeg), jnp.asarray(sl),
-        jnp.asarray(ax), jnp.asarray(constant), SENTINEL,
-    )
-    cdp = np.zeros(nv_ceil, dtype=np.float32)
-    cdp[:nv] = comm_deg
-    bc, bg, c0 = heavy_argmax_pallas(
-        jnp.asarray(np.ascontiguousarray(cmat.T)),
-        jnp.asarray(np.ascontiguousarray(wmat.T)),
-        jnp.asarray(cdp),
-        jnp.asarray(curr), jnp.asarray(vdeg), jnp.asarray(sl),
-        jnp.asarray(ax), jnp.asarray(constant),
-        c_tile=c_tile, d_chunk=d_chunk, interpret=True,
-    )
-    assert np.array_equal(np.asarray(bg), np.asarray(ref.best_gain))
-    assert np.array_equal(np.asarray(bc), np.asarray(ref.best_c))
+    got, q = _heavy_step(*case, constant)
+    want, q_ref = _heavy_oracle(*case, constant)
+    assert np.array_equal(got, want)
+    assert q == pytest.approx(q_ref, rel=1e-6, abs=1e-6)
 
-    # Constructed row where a community reached ONLY by a w=0 edge WINS:
-    # pins valid = (cnt > 0), not (wagg > 0) — the old rule returns
-    # community 2 here.  curr=0, no edges into it (eix=0); community 1
-    # via w=0 (tiny comm_deg -> positive gain), community 2 via w=0.5
-    # (huge comm_deg -> negative gain).
-    one = np.full((1, 128), nv_ceil, dtype=np.int32)
-    onew = np.zeros((1, 128), dtype=np.float32)
-    one[0, 0], onew[0, 0] = 1, 0.0
-    one[0, 1], onew[0, 1] = 2, 0.5
-    cd1 = np.ones(nv_ceil, dtype=np.float32)
-    cd1[1], cd1[2] = 0.125, 40.0
-    bc1, bg1, c01 = heavy_argmax_pallas(
-        jnp.asarray(one.T.copy()), jnp.asarray(onew.T.copy()),
-        jnp.asarray(cd1),
-        jnp.asarray(np.array([0], np.int32)),
-        jnp.asarray(np.array([0.5], np.float32)),
-        jnp.asarray(np.array([0.0], np.float32)),
-        jnp.asarray(np.array([0.5], np.float32)),  # ax = cd[0] - vdeg
-        jnp.asarray(np.float32(1 / 16)),
-        c_tile=c_tile, d_chunk=d_chunk, interpret=True,
-    )
-    assert int(bc1[0]) == 1, "w=0-only community must be the argmax"
-    assert float(bg1[0]) == 2 * 0.5 * (1 / 16) * (0.5 - 0.125)
-    assert float(c01[0]) == 0.0
+    # Constructed rows.  Vertices 0 and 1 share community 0 with vertex
+    # 4.  Vertex 0 has a w=0 edge into community 2 (degree 0.125,
+    # positive gain) and a w=0.5 edge into community 3 (degree 40,
+    # negative gain): the w=0-only community must win — valid means
+    # present, not weighted.  Vertex 1 has equal-weight edges into the
+    # singleton communities 5 and 6 of equal degree: the tie goes to 5.
+    comm = np.array([0, 0, 2, 3, 0, 5, 6, 7], np.int32)
+    vdeg = np.array([0.5, 0.5, 0.125, 40.0, 0.5, 2.0, 2.0, 1.0],
+                    np.float32)
+    dst = np.array([[2, 3], [5, 6]], np.int32)
+    wmat = np.array([[0.0, 0.5], [0.25, 0.25]], np.float32)
+    sl = np.zeros(8, np.float32)
+    got, _q = _heavy_step(dst, wmat, comm, vdeg, sl, constant)
+    want, _ = _heavy_oracle(dst, wmat, comm, vdeg, sl, constant)
+    assert np.array_equal(got, want)
+    assert got[0] == 2, "w=0-only community must be the argmax"
+    assert got[1] == 5, "equal gains go to the smaller community id"
 
 
-def test_heavy_bincount_padding_and_no_candidates():
-    """Padded slots (c = nv_ceil, w = 0) never contribute; rows whose
-    neighbors all sit in the current community return the sentinel."""
-    from cuvite_tpu.kernels.heavy_bincount import heavy_argmax_pallas
-
-    n_rows, width = 8, 256
-    nv, nv_ceil, c_tile, d_chunk = 100, 128, 128, 128
+def test_heavy_residual_padding_and_no_candidates():
+    """Padded slots (src == nv, w = 0) never contribute; rows whose
+    neighbors all sit in the current community stay, and their weight
+    is all counter0 (visible in Q)."""
+    n_rows, width, nv = 8, 256, 128
     rng = np.random.default_rng(2)
-    curr = rng.integers(0, nv, size=n_rows).astype(np.int32)
-    cmat = np.full((n_rows, width), nv_ceil, dtype=np.int32)  # all padding
-    wmat = np.zeros((n_rows, width), dtype=np.float32)
-    # First half of the slots: real edges into the CURRENT community only.
-    cmat[:, : width // 2] = curr[:, None]
-    wmat[:, : width // 2] = 0.5
-    vdeg = np.ones(n_rows, dtype=np.float32)
-    sl = np.zeros(n_rows, dtype=np.float32)
-    comm_deg = np.ones(nv_ceil, dtype=np.float32)
-    ax = comm_deg[curr] - vdeg
-    bc, bg, c0 = heavy_argmax_pallas(
-        jnp.asarray(np.ascontiguousarray(cmat.T)),
-        jnp.asarray(np.ascontiguousarray(wmat.T)),
-        jnp.asarray(comm_deg),
-        jnp.asarray(curr), jnp.asarray(vdeg), jnp.asarray(sl),
-        jnp.asarray(ax), jnp.asarray(np.float32(0.01)),
-        c_tile=c_tile, d_chunk=d_chunk, interpret=True,
-    )
-    assert np.all(np.asarray(bc) == SENTINEL)
-    assert np.all(np.isneginf(np.asarray(bg)))
-    assert np.allclose(np.asarray(c0), 0.5 * (width // 2))
+    comm = (np.arange(nv) % 16).astype(np.int32)
+    # Every slot of row r points at a vertex of r's own community.
+    dst = (comm[:n_rows, None]
+           + 16 * rng.integers(0, nv // 16, (n_rows, width))).astype(
+        np.int32)
+    wmat = np.full((n_rows, width), 0.5, np.float32)
+    vdeg = np.ones(nv, np.float32)
+    sl = np.zeros(nv, np.float32)
+    sl[:n_rows] = np.where(dst == np.arange(n_rows)[:, None], wmat,
+                           0.0).sum(axis=1)
+    constant = np.float32(0.01)
+    got, q = _heavy_step(dst, wmat, comm, vdeg, sl, constant)
+    want, q_ref = _heavy_oracle(dst, wmat, comm, vdeg, sl, constant)
+    assert np.array_equal(got, comm) and np.array_equal(want, comm)
+    counter0 = 0.5 * width * n_rows
+    deg = np.bincount(comm, weights=vdeg, minlength=nv)
+    assert q == pytest.approx(
+        counter0 * 0.01 - np.square(deg * 0.01).sum(), rel=1e-6)
+    assert q == pytest.approx(q_ref, rel=1e-6)
 
 
 def test_pallas_engine_end_to_end(karate):
@@ -249,47 +271,7 @@ def test_pallas_engine_rmat():
 
 
 # ---------------------------------------------------------------------------
-# ISSUE 8: the heavy-class kernel promotion — layout builder, policy, and
-# the compiled-path (jitted driver, interpret kernel) parity pin.
-
-
-def test_build_heavy_layout_contract():
-    from cuvite_tpu.kernels.heavy_bincount import build_heavy_layout
-
-    nv_local, pad_id = 64, 4096
-    # CSR-ordered padded triples: vertex 3 (4 edges), vertex 7 (2 edges).
-    hs = np.array([3, 3, 3, 3, 7, 7, 64, 64], np.int64)
-    hd = np.array([10, 11, 12, 13, 20, 21, 0, 0], np.int64)
-    hw = np.array([1.0, 2.0, 3.0, 4.0, 5.0, 6.0, 0, 0], np.float32)
-    verts, dT, wT = build_heavy_layout(hs, hd, hw, nv_local=nv_local,
-                                       pad_id=pad_id, d_chunk=8)
-    assert verts.shape == (8,) and dT.shape == wT.shape == (8, 8)
-    assert list(verts[:2]) == [3, 7] and (verts[2:] == nv_local).all()
-    assert list(dT[:4, 0]) == [10, 11, 12, 13]
-    assert list(wT[:2, 1]) == [5.0, 6.0]
-    # Padding slots: dst == pad_id (never a candidate), w == 0.
-    assert (dT[4:, 0] == pad_id).all() and (wT[2:, 1] == 0).all()
-    assert (dT[:, 2:] == pad_id).all()
-    # Element budget: an over-budget hub set degrades to None.
-    assert build_heavy_layout(hs, hd, hw, nv_local=nv_local,
-                              pad_id=pad_id, d_chunk=8,
-                              max_elems=16) is None
-    # No heavy edges at all -> None.
-    empty = np.full(8, nv_local, np.int64)
-    assert build_heavy_layout(empty, hd, hw, nv_local=nv_local,
-                              pad_id=pad_id) is None
-
-
-def test_heavy_kernel_policy(monkeypatch):
-    from cuvite_tpu.kernels.heavy_bincount import heavy_kernel_enabled
-
-    monkeypatch.delenv("CUVITE_HEAVY_KERNEL", raising=False)
-    # Opt-in on every backend: the sorted path won on the chip (PR 21).
-    assert heavy_kernel_enabled() is False
-    monkeypatch.setenv("CUVITE_HEAVY_KERNEL", "0")
-    assert heavy_kernel_enabled() is False
-    monkeypatch.setenv("CUVITE_HEAVY_KERNEL", "1")   # forced (interpret)
-    assert heavy_kernel_enabled() is True
+# The heavy class through the whole driver.
 
 
 @pytest.fixture(scope="module")
@@ -307,45 +289,47 @@ def hub_graph():
     return Graph.from_edges(nv, src, dst)
 
 
-# pallas arm ~29 s under the CPU interpreter; the kernel's bit-identity
-# stays tier-1 through the bucketed arm + the unit-level kernel tests.
+# pallas arm ~29 s under the CPU interpreter; the heavy residual's
+# parity stays tier-1 through the bucketed arm.
 @pytest.mark.parametrize(
     "engine",
     ["bucketed", pytest.param("pallas", marks=pytest.mark.slow)])
-def test_heavy_kernel_full_run_bit_identical(hub_graph, engine,
-                                             monkeypatch):
-    """The opt-in heavy path (CUVITE_HEAVY_KERNEL=1 runs the kernel in
-    interpret mode on CPU — the same jitted driver path the chip runs
-    compiled) must cluster bit-identically to the sorted heavy
-    path it replaces."""
+def test_heavy_residual_full_run_matches_sort(hub_graph, engine):
+    """A run whose hub takes the sorted heavy residual clusters
+    bit-identically to the edge-slab sort engine: same phases,
+    iterations per phase and labels.  Q agrees to float64 rounding: the
+    sort engine evaluates it on the device (double-single), the
+    bucketed engine on the host (float64)."""
     from cuvite_tpu.louvain.driver import louvain_phases
 
-    monkeypatch.setenv("CUVITE_HEAVY_KERNEL", "0")
-    r0 = louvain_phases(hub_graph, engine=engine)
-    monkeypatch.setenv("CUVITE_HEAVY_KERNEL", "1")
+    r0 = louvain_phases(hub_graph, engine="sort")
     r1 = louvain_phases(hub_graph, engine=engine)
     assert len(r0.phases) == len(r1.phases) >= 2
-    assert r0.total_iterations == r1.total_iterations
-    assert r0.modularity == r1.modularity
+    assert [p.iterations for p in r0.phases] \
+        == [p.iterations for p in r1.phases]
     assert np.array_equal(r0.communities, r1.communities)
+    assert r1.modularity == pytest.approx(r0.modularity, abs=1e-12)
+    if engine == "bucketed":
+        # No Pallas kernel runs, so the result carries no coverage.
+        assert r1.pallas_coverage is None and r1.pallas_width_hits is None
     if engine == "pallas":
-        # Coverage honesty: with the heavy kernel engaged the heavy
-        # residual (width 0) counts as kernelized; without it, not.
-        assert r1.pallas_coverage > r0.pallas_coverage
-        assert 0 in r1.pallas_width_hits
+        # Coverage honesty: the heavy residual (width 0) is never
+        # kernelised.
+        assert r1.pallas_coverage < 1.0
+        assert 0 not in r1.pallas_width_hits
 
 
-def test_heavy_kernel_budget_degrade_keeps_sorted_path(hub_graph,
-                                                       monkeypatch):
-    """An over-budget hub layout must degrade loudly to the sorted path
-    and still produce the identical clustering (the PALLAS_MAX_WIDTH
-    degrade-with-coverage pattern)."""
-    from cuvite_tpu.louvain.driver import louvain_phases
+def test_pallas_coverage_counts_heavy_residual_as_xla():
+    """The per-phase coverage record: width 0 stands for the heavy
+    class, whose sorted residual is never kernelised, so its edges count
+    in the denominator only; coverage below one half warns."""
+    from types import SimpleNamespace
 
-    monkeypatch.setenv("CUVITE_HEAVY_KERNEL", "0")
-    r0 = louvain_phases(hub_graph, engine="bucketed")
-    monkeypatch.setenv("CUVITE_HEAVY_KERNEL", "1")
-    monkeypatch.setenv("CUVITE_HEAVY_ELEMS", "64")
-    with pytest.warns(UserWarning, match="CUVITE_HEAVY_ELEMS"):
-        r1 = louvain_phases(hub_graph, engine="bucketed")
-    assert np.array_equal(r0.communities, r1.communities)
+    from cuvite_tpu.louvain.driver import PhaseRunner
+
+    rec = SimpleNamespace()
+    cov = [(8, 100, True), (4096, 150, False), (0, 150, False)]
+    with pytest.warns(UserWarning, match="kernel-covered"):
+        PhaseRunner._record_pallas_coverage(rec, cov)
+    assert rec.pallas_coverage == 0.25
+    assert rec.pallas_cov_detail == cov

@@ -160,3 +160,66 @@ def test_packed_sort_debug_bounds_guard(monkeypatch):
         src_bound=4, key_bound=4)
     assert [int(x) for x in out[0]] == [0, 1, 2]
     assert [int(x) for x in out[1]] == [2, 1, 0]
+
+
+def run_bucketed_step(graph: Graph, comm: np.ndarray):
+    """One bucketed_step sweep over the default degree-class ladder
+    (degree classes, the sorted heavy residual, the assembly perm)."""
+    import functools
+
+    import jax
+
+    from cuvite_tpu.louvain.bucketed import (
+        BucketPlan,
+        bucketed_step,
+        build_assemble_perm,
+    )
+
+    dg = DistGraph.build(graph, 1)
+    sh = dg.shards[0]
+    plan = BucketPlan.build(np.asarray(sh.src), np.asarray(sh.dst),
+                            np.asarray(sh.w), nv_local=dg.nv_pad, base=0)
+    vdt, wdt = np.int32, np.float32
+    buckets = tuple((jnp.asarray(b.verts.astype(vdt)),
+                     jnp.asarray(b.dst.astype(vdt)),
+                     jnp.asarray(b.w.astype(wdt))) for b in plan.buckets)
+    heavy = tuple(jnp.asarray(a.astype(t)) for a, t in zip(
+        (plan.heavy_src, plan.heavy_dst, plan.heavy_w), (vdt, vdt, wdt)))
+    perm = build_assemble_perm([b.verts for b in plan.buckets], dg.nv_pad)
+    nvt = dg.total_padded_vertices
+    comm_pad = np.arange(nvt, dtype=vdt)
+    comm_pad[dg.old_to_pad] = dg.old_to_pad[comm]
+    step = jax.jit(functools.partial(bucketed_step, nv_total=nvt,
+                                     sentinel=np.iinfo(vdt).max))
+    t, q, _n, _ = step(
+        buckets, heavy, jnp.asarray(plan.self_loop.astype(wdt)),
+        jnp.asarray(comm_pad),
+        jnp.asarray(dg.padded_weighted_degrees().astype(wdt)),
+        jnp.asarray(1.0 / graph.total_edge_weight_twice(), dtype=wdt),
+        assemble_perm=jnp.asarray(perm))
+    t = np.asarray(t)
+    return dg.pad_to_old[t[dg.old_to_pad]], float(q), plan.has_heavy
+
+
+@pytest.mark.parametrize("hub_degree", [8192, 8193, 16384])
+def test_bucketed_hub_at_heavy_class_boundary_matches_oracle(hub_degree):
+    """A hub at the top degree class (8192), one past it (8193: the
+    sorted heavy residual) and twice it: every sweep's targets equal the
+    dict-based oracle's."""
+    rng = np.random.default_rng(hub_degree)
+    nv = 20000
+    hub_dst = rng.choice(np.arange(1, nv), size=hub_degree, replace=False)
+    bg = rng.integers(1, nv, (2, 12000))
+    graph = Graph.from_edges(
+        nv, np.concatenate([np.zeros(hub_degree, np.int64), bg[0]]),
+        np.concatenate([hub_dst, bg[1]]))
+    assert int(graph.degrees()[0]) == hub_degree
+    comm = np.arange(nv, dtype=np.int64)
+    for it in range(3):
+        expected, q_exp = oracle_step(graph, comm)
+        got, q_got, has_heavy = run_bucketed_step(graph, comm)
+        assert has_heavy == (hub_degree > 8192)
+        np.testing.assert_array_equal(
+            got, expected, err_msg=f"iteration {it} targets diverge")
+        assert q_got == pytest.approx(q_exp, abs=1e-5)
+        comm = expected

@@ -1,5 +1,5 @@
 """Device re-binning (ISSUE 19): coarsen/rebin.py + the driver/batched
-integration, and the msd/hash big-class coalesce engines.
+integration, and the coalesce at the 31-bit packing boundary.
 
 The host ``BucketPlan.build`` is the bit-identity oracle: the device
 plan builder must reproduce its buckets (verts/dst/w prefix per kept
@@ -488,7 +488,7 @@ def test_batched_second_batch_zero_fresh_compiles(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# msd / hash coalesce engines vs the float64 oracle (tentpole b)
+# The coalesce at the packing boundary vs the float64 oracle
 
 
 def _chokepoint_slab(nv_pad, ne_pad, seed):
@@ -531,60 +531,14 @@ def _assert_matches_oracle(out, src, dst, w, nv_pad):
     assert (src_c[n:] == nv_pad).all()
 
 
-@pytest.mark.parametrize("engine", ["msd", "hash"])
 @pytest.mark.parametrize("nv_pad", [1 << 15, 1 << 16],
                          ids=["widest-legal-pack", "first-ineligible"])
-def test_bigclass_engines_match_oracle(engine, nv_pad):
-    """The parity pair at the packing boundary: nv_pad = 2^15 is the
-    widest legal 31-bit pack (msd delegates to it), 2^16 the first
-    class past it (msd runs its two passes; the sort arm degrades to
-    the variadic comparator)."""
+def test_bigclass_engines_match_oracle(nv_pad):
+    """The coalesce at the packing boundary: nv_pad = 2^15 is the
+    widest legal 31-bit pack, 2^16 the first class past it (the sort
+    degrades to the variadic comparator)."""
     ne_pad = 8192
     src, dst, w = _chokepoint_slab(nv_pad, ne_pad, seed=nv_pad)
     arrs = tuple(jnp.asarray(x) for x in (src, dst, w))
-    out = coalesced_runs(*arrs, nv_pad=nv_pad, engine=engine)
+    out = coalesced_runs(*arrs, nv_pad=nv_pad)
     _assert_matches_oracle(out, src, dst, w, nv_pad)
-    ref = jax.device_get(coalesced_runs(*arrs, nv_pad=nv_pad,
-                                        engine="sort"))
-    got = jax.device_get(out)
-    for r, g, name in zip(ref, got, ("src", "ckey", "w", "n")):
-        assert np.array_equal(np.asarray(r), np.asarray(g)), name
-
-
-@pytest.mark.parametrize("engine", ["msd", "hash"])
-def test_bigclass_engines_forced_x64_identical(engine):
-    """Under jax_enable_x64 the sort arm packs one int64 key; msd/hash
-    keep their int32 formulations — all three must agree bit-for-bit
-    at the first ineligible width."""
-    nv_pad, ne_pad = 1 << 16, 8192
-    src, dst, w = _chokepoint_slab(nv_pad, ne_pad, seed=97)
-    arrs = tuple(jnp.asarray(x) for x in (src, dst, w))
-    base = jax.device_get(coalesced_runs(*arrs, nv_pad=nv_pad,
-                                         engine=engine))
-    prior = jax.config.jax_enable_x64
-    try:
-        jax.config.update("jax_enable_x64", True)
-        forced = jax.device_get(coalesced_runs(*arrs, nv_pad=nv_pad,
-                                               engine="sort"))
-    finally:
-        jax.config.update("jax_enable_x64", prior)
-    for b, f, name in zip(base, forced, ("src", "ckey", "w", "n")):
-        assert np.array_equal(np.asarray(b), np.asarray(f)), name
-
-
-def test_hash_collision_retry_path():
-    """A deliberately tiny table forces collisions: the device-side
-    detector must fire and the sorted retry must still produce the
-    exact coalesce."""
-    nv_pad, ne_pad = 1 << 16, 4096
-    src, dst, w = _chokepoint_slab(nv_pad, ne_pad, seed=5)
-    import os
-
-    os.environ["CUVITE_HASH_SLOTS"] = "2"
-    try:
-        out = coalesced_runs(jnp.asarray(src), jnp.asarray(dst),
-                             jnp.asarray(w), nv_pad=nv_pad,
-                             engine="hash")
-        _assert_matches_oracle(out, src, dst, w, nv_pad)
-    finally:
-        del os.environ["CUVITE_HASH_SLOTS"]

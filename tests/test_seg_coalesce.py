@@ -1,13 +1,12 @@
-"""Segmented-coalesce engines (ISSUE 8): kernels/seg_coalesce.py +
-ops/segment.coalesced_runs + the device_coarsen_slab dispatch.
+"""The segmented coalesce (ops/segment.coalesced_runs) and its consumer
+in the phase transition, coarsen/device.py::device_coarsen_slab.
 
-The packed-sort path is the bit-parity oracle: the dense dst-tile
-engine (the XLA scatter bin-accumulate) must reproduce its compacted (src, dst, w) prefix BIT-for-bit —
-offsets/tails always (run presence is exact in every mode), weights on
-the documented exactness domain (unit/dyadic run sums).  The
-packed-sort key-width contract of ops/segment.py is pinned at its
-edges here too (the widest legal 31-bit packing, the first ineligible
-width, and the CUVITE_DEBUG_BOUNDS violation callback).
+A numpy lexsort + run-sum oracle pins the compacted (src, dst, w)
+prefix BIT-for-bit — offsets/tails always (run presence is exact),
+weights on the documented exactness domain (unit/dyadic run sums).  The
+packed-sort key-width contract of ops/segment.py is pinned at its edges
+here too (the widest legal 31-bit packing, the first ineligible width,
+and the CUVITE_DEBUG_BOUNDS violation callback).
 """
 
 import numpy as np
@@ -17,7 +16,6 @@ import jax
 import jax.numpy as jnp
 
 import cuvite_tpu.ops.segment as seg
-from cuvite_tpu.kernels.seg_coalesce import coalesce_engine
 from cuvite_tpu.ops.segment import coalesced_runs
 
 def _slab(nv_pad, ne_pad, seed, gapped=False, self_loops=True,
@@ -44,40 +42,80 @@ def _slab(nv_pad, ne_pad, seed, gapped=False, self_loops=True,
     return tuple(jnp.asarray(x) for x in (src, dst, w))
 
 
+def _lexsort_oracle(src, dst, w, nv_pad):
+    """np.lexsort by (src, dst) over the real rows, one row per run at
+    the run's first position, run sums in float64 cast once."""
+    src, dst, w = (np.asarray(x) for x in (src, dst, w))
+    real = src < nv_pad
+    s, d, ww = src[real], dst[real], w[real].astype(np.float64)
+    order = np.lexsort((d, s))
+    s, d, ww = s[order], d[order], ww[order]
+    start = np.ones(len(s), bool)
+    start[1:] = (s[1:] != s[:-1]) | (d[1:] != d[:-1])
+    idx = np.flatnonzero(start)
+    sums = np.add.reduceat(ww, idx) if len(idx) else ww
+    return s[idx], d[idx], sums.astype(np.float32)
+
+
 @pytest.mark.parametrize("nv_pad,ne_pad,gapped", [
-    # ≥3 slab classes; gapped (sparse) id spaces on the floor class only
-    # — id sparsity is engine-invariant, one class covers it.
-    # [floor-gapped]/[wide-slab] are tier-2 (slow): the identity they
-    # pin is class-shape-invariant and [floor] keeps it in tier-1 at a
-    # third of the wall; gapped-id handling stays covered in tier-1 by
-    # the sticky-union/concheck gapped scenarios.
+    # ≥3 slab classes; gapped (sparse) id spaces on the floor class only.
+    # [floor-gapped]/[wide-slab] are tier-2 (slow): the contract they pin
+    # is class-shape-invariant and [floor] keeps it in tier-1 at a third
+    # of the wall; gapped-id handling stays covered in tier-1 by the
+    # sticky-union/concheck gapped scenarios.
     (4096, 16384, False),
     pytest.param(4096, 16384, True, marks=pytest.mark.slow),
     pytest.param(4096, 65536, False, marks=pytest.mark.slow),
     (1024, 16384, False),
 ], ids=["floor", "floor-gapped", "wide-slab", "narrow-nv"])
-def test_dense_engines_bit_identical_to_sort(nv_pad, ne_pad, gapped):
+def test_sort_coalesce_matches_lexsort_oracle(nv_pad, ne_pad, gapped):
     arrs = _slab(nv_pad, ne_pad, seed=nv_pad + ne_pad, gapped=gapped)
-    ref = jax.device_get(coalesced_runs(*arrs, nv_pad=nv_pad,
-                                        engine="sort"))
-    got = jax.device_get(coalesced_runs(*arrs, nv_pad=nv_pad,
-                                        engine="xla"))
-    for r, g, name in zip(ref, got, ("src", "dst", "w", "n")):
-        assert np.array_equal(r, g), name
-    # Tail sentinel contract: padding after the compacted prefix.
-    src_c, dst_c, w_c, n = ref
+    src_c, dst_c, w_c, n = jax.device_get(
+        coalesced_runs(*arrs, nv_pad=nv_pad))
+    s_ref, d_ref, w_ref = _lexsort_oracle(*arrs, nv_pad)
     n = int(n)
+    assert n == len(s_ref)
+    assert np.array_equal(src_c[:n], s_ref)
+    assert np.array_equal(dst_c[:n], d_ref)
+    assert np.array_equal(w_c[:n], w_ref)
+    # Tail sentinel contract: padding after the compacted prefix.
     assert (src_c[n:] == nv_pad).all()
     assert (dst_c[n:] == 0).all()
     assert (w_c[n:] == 0).all()
-    # The prefix is strictly (src, dst)-sorted: distinct packed keys.
-    keys = src_c[:n].astype(np.int64) * nv_pad + dst_c[:n]
-    assert (np.diff(keys) > 0).all()
+
+
+@pytest.mark.parametrize("case", ["all-padding", "one-run", "no-padding"])
+def test_coalesce_degenerate_slabs(case):
+    """The slabs at the edges of the contract: nothing real (n == 0,
+    all sentinel), every real row one (src, dst) pair (one summed run),
+    and a slab with no padding row at all."""
+    nv_pad, ne_pad = 1024, 4096
+    rng = np.random.default_rng(11)
+    src = np.full(ne_pad, nv_pad, np.int32)
+    dst = np.zeros(ne_pad, np.int32)
+    w = np.zeros(ne_pad, np.float32)
+    if case == "one-run":
+        src[:999], dst[:999] = 7, 3
+        w[:999] = rng.integers(1, 8, 999) / 4.0
+    elif case == "no-padding":
+        src[:] = rng.integers(0, nv_pad, ne_pad)
+        dst[:] = rng.integers(0, nv_pad, ne_pad)
+        w[:] = rng.integers(1, 8, ne_pad) / 4.0
+    arrs = tuple(jnp.asarray(x) for x in (src, dst, w))
+    src_c, dst_c, w_c, n = jax.device_get(
+        coalesced_runs(*arrs, nv_pad=nv_pad))
+    s_ref, d_ref, w_ref = _lexsort_oracle(src, dst, w, nv_pad)
+    n = int(n)
+    assert n == len(s_ref) == {"all-padding": 0, "one-run": 1}.get(case, n)
+    assert np.array_equal(src_c[:n], s_ref)
+    assert np.array_equal(dst_c[:n], d_ref)
+    assert np.array_equal(w_c[:n], w_ref)
+    assert (src_c[n:] == nv_pad).all() and (w_c[n:] == 0).all()
 
 
 def test_zero_weight_runs_emitted_by_presence():
-    """A real zero-weight edge is a run (presence, not weight) in every
-    engine — dropping it would change the coarse offsets."""
+    """A real zero-weight edge is a run (presence, not weight) —
+    dropping it would change the coarse offsets."""
     nv_pad, ne_pad = 1024, 16384
     src = np.full(ne_pad, nv_pad, np.int32)
     dst = np.zeros(ne_pad, np.int32)
@@ -86,17 +124,19 @@ def test_zero_weight_runs_emitted_by_presence():
     dst[:3] = [6, 8, 10]
     w[:3] = [1.0, 0.0, 2.0]  # the (7, 8) run weighs exactly 0
     arrs = tuple(jnp.asarray(x) for x in (src, dst, w))
-    for engine in ("sort", "xla"):
-        src_c, dst_c, w_c, n = jax.device_get(
-            coalesced_runs(*arrs, nv_pad=nv_pad, engine=engine))
-        assert int(n) == 3, engine
-        assert list(src_c[:3]) == [5, 7, 9] and w_c[1] == 0.0, engine
+    src_c, dst_c, w_c, n = jax.device_get(
+        coalesced_runs(*arrs, nv_pad=nv_pad))
+    assert int(n) == 3
+    assert list(src_c[:3]) == [5, 7, 9] and w_c[1] == 0.0
 
 
-def test_device_coarsen_slab_dense_vs_sort_bitwise(two_cliques):
-    """Through the real consumer: device_coarsen_slab with the dense
-    engines produces the identical 6-tuple (slab, dense_map, nc, ne2)."""
-    from cuvite_tpu.coarsen.device import device_coarsen_slab
+def test_device_coarsen_slab_precomputed_renumber_matches_host(two_cliques):
+    """Through the real consumer: device_coarsen_slab handed a
+    precomputed renumber (the fused driver's call) returns the identical
+    6-tuple as the self-renumbering call, and its coarse graph is the
+    host coarsen_graph's."""
+    from cuvite_tpu.coarsen.device import device_coarsen_slab, device_renumber
+    from cuvite_tpu.coarsen.rebuild import coarsen_graph, renumber_communities
     from cuvite_tpu.core.distgraph import DistGraph
 
     dg = DistGraph.build(two_cliques, 1)
@@ -104,69 +144,39 @@ def test_device_coarsen_slab_dense_vs_sort_bitwise(two_cliques):
     lab = np.arange(dg.nv_pad, dtype=np.int64)
     lab[:5] = 0
     lab[5:10] = 5
+    comm = jnp.asarray(lab.astype(np.asarray(sh.src).dtype))
+    mask = jnp.asarray(dg.vertex_mask())
     args = (jnp.asarray(np.asarray(sh.src)), jnp.asarray(np.asarray(sh.dst)),
-            jnp.asarray(np.asarray(sh.w)),
-            jnp.asarray(lab.astype(np.asarray(sh.src).dtype)),
-            jnp.asarray(dg.vertex_mask()))
-    ref = jax.device_get(device_coarsen_slab(*args, nv_pad=dg.nv_pad,
-                                             coalesce="sort"))
+            jnp.asarray(np.asarray(sh.w)), comm, mask)
+    ref = jax.device_get(device_coarsen_slab(*args, nv_pad=dg.nv_pad))
+    dmap, nc = device_renumber(comm, mask, nv_pad=dg.nv_pad)
     got = jax.device_get(device_coarsen_slab(*args, nv_pad=dg.nv_pad,
-                                             coalesce="xla"))
+                                             dense_map=dmap, nc=nc))
     for r, g in zip(ref, got):
         assert np.array_equal(r, g)
-
-
-def test_coalesce_engine_policy(monkeypatch):
-    monkeypatch.delenv("CUVITE_SEG_COALESCE", raising=False)
-    # Default: the packed sort stays the workhorse until the staged chip
-    # A/B promotes a dense engine (measured rationale in the module).
-    assert coalesce_engine(4096) == "sort"
-    monkeypatch.setenv("CUVITE_SEG_COALESCE", "xla")
-    assert coalesce_engine(4096) == "xla"
-    # ds32 run sums need the sorted pair arithmetic — degrade in every
-    # mode.
-    assert coalesce_engine(4096, seg.DS_ACCUM) == "sort"
-    # Domain over the accumulator budget (nv_pad > MAX_NV) -> degrade.
-    assert coalesce_engine(1 << 16) == "sort"
-    monkeypatch.setenv("CUVITE_SEG_COALESCE_MAX_NV", "1024")
-    assert coalesce_engine(4096) == "sort"
-    assert coalesce_engine(1024) == "xla"
-    monkeypatch.delenv("CUVITE_SEG_COALESCE_MAX_NV")
-    # The Pallas mode is gone: it warns and keeps the default.
-    monkeypatch.setenv("CUVITE_SEG_COALESCE", "pallas")
-    with pytest.warns(UserWarning, match="unrecognized"):
-        assert coalesce_engine(4096) == "sort"
-    monkeypatch.setenv("CUVITE_SEG_COALESCE", "0")
-    assert coalesce_engine(1024) == "sort"
-    # A typo'd pin warns and keeps the default instead of silently
-    # measuring the wrong engine.
-    monkeypatch.setenv("CUVITE_SEG_COALESCE", "sorr")
-    with pytest.warns(UserWarning, match="unrecognized"):
-        assert coalesce_engine(1024) == "sort"
-
-
-def test_coalesced_runs_rejects_ds32_on_dense():
-    arrs = _slab(1024, 16384, seed=1)
-    with pytest.raises(AssertionError, match="ds32"):
-        coalesced_runs(*arrs, nv_pad=1024, accum_dtype=seg.DS_ACCUM,
-                       engine="xla")
+    src2, dst2, w2, _dm, nc2, ne2 = got
+    dense, nc_h = renumber_communities(lab[dg.old_to_pad])
+    gh = coarsen_graph(two_cliques, dense, nc_h)
+    assert int(nc2) == nc_h and int(ne2) == gh.num_edges
+    assert np.array_equal(src2[:int(ne2)], gh.sources())
+    assert np.array_equal(dst2[:int(ne2)], gh.tails)
+    assert np.array_equal(w2[:int(ne2)], gh.weights)
 
 
 def test_ds32_sort_fallback_matches_plain_on_exact_domain():
-    """ds32 always rides the sort path; on dyadic weights its collapsed
-    run sums equal the plain f32 path bit-for-bit."""
+    """On dyadic weights the ds32 pair sums, collapsed once, equal the
+    plain f32 run sums bit-for-bit."""
     arrs = _slab(1024, 16384, seed=9)
-    a = jax.device_get(coalesced_runs(*arrs, nv_pad=1024, engine="sort"))
-    b = jax.device_get(coalesced_runs(*arrs, nv_pad=1024, engine="sort",
+    a = jax.device_get(coalesced_runs(*arrs, nv_pad=1024))
+    b = jax.device_get(coalesced_runs(*arrs, nv_pad=1024,
                                       accum_dtype=seg.DS_ACCUM))
     for x, y in zip(a, b):
         assert np.array_equal(x, y)
 
 
 # ---------------------------------------------------------------------------
-# Full-run integration: the sort engine's device transition with a dense
-# coalesce forced must cluster bit-identically, with zero fresh compiles
-# on phases 2+ and the same per-phase sync count as the default path.
+# Full-run integration: the sort engine's device transition compiles its
+# coalesce once per slab class and adds no device syncs to a phase.
 
 
 @pytest.fixture(scope="module")
@@ -178,83 +188,31 @@ def rmat10():
     return g
 
 
-def test_sort_engine_dense_coalesce_full_run_identical(rmat10, monkeypatch):
-    from cuvite_tpu.louvain.driver import louvain_phases
-
-    monkeypatch.delenv("CUVITE_SEG_COALESCE", raising=False)
-    r0 = louvain_phases(rmat10, engine="sort")
-    monkeypatch.setenv("CUVITE_SEG_COALESCE", "xla")
-    r1 = louvain_phases(rmat10, engine="sort")
-    assert len(r0.phases) == len(r1.phases) >= 3
-    assert r0.total_iterations == r1.total_iterations
-    assert r0.modularity == r1.modularity
-    assert np.array_equal(r0.communities, r1.communities)
-
-
-def test_fused_dense_coalesce_full_run_identical(rmat10, monkeypatch):
+def test_coalesce_compiles_once_per_slab_class(rmat10, monkeypatch):
+    """Every phase of this run shares the floor slab class, so the
+    device transition's relabel+coalesce program serves every phase
+    from at most one new compiled entry."""
     import cuvite_tpu.louvain.driver as drv
-    from cuvite_tpu.louvain.driver import louvain_phases
 
-    # Force the one-call-per-phase multilevel path so device_coarsen_slab
-    # actually runs between fused calls.
-    monkeypatch.setattr(drv, "FUSED_SHRINK_EDGES", 1 << 10)
-    monkeypatch.delenv("CUVITE_SEG_COALESCE", raising=False)
-    r0 = louvain_phases(rmat10, engine="fused")
-    monkeypatch.setenv("CUVITE_SEG_COALESCE", "xla")
-    r1 = louvain_phases(rmat10, engine="fused")
-    assert len(r0.phases) == len(r1.phases) >= 3
-    assert np.array_equal(r0.communities, r1.communities)
+    calls = []
+    orig = drv.device_coarsen_slab
 
+    def spy(*a, **k):
+        calls.append(k["nv_pad"])
+        return orig(*a, **k)
 
-def test_dense_coalesce_zero_fresh_compiles_after_phase1(
-        rmat10, monkeypatch):
-    """The dense path must keep the tentpole compile contract: same pow2
-    class across phases => all compiles in phases 0-1, none after."""
-    import logging
-
-    from cuvite_tpu.louvain.driver import louvain_phases
-    from cuvite_tpu.utils.trace import Tracer
-
-    monkeypatch.setenv("CUVITE_SEG_COALESCE", "xla")
-    compiles = []
-
-    class _Grab(logging.Handler):
-        def emit(self, record):
-            if "Compiling" in record.getMessage():
-                compiles.append(record.getMessage())
-
-    import contextlib
-
-    class _Probe(Tracer):
-        def __init__(self):
-            super().__init__(enabled=True)
-            self.marks = []
-
-        @contextlib.contextmanager
-        def stage(self, name):
-            if name == "iterate":
-                self.marks.append(len(compiles))
-            with super().stage(name):
-                yield
-
-    probe = _Probe()
-    handler = _Grab(level=logging.WARNING)
-    logger = logging.getLogger("jax")
-    logger.addHandler(handler)
-    jax.config.update("jax_log_compiles", True)
-    try:
-        res = louvain_phases(rmat10, engine="sort", tracer=probe)
-    finally:
-        jax.config.update("jax_log_compiles", False)
-        logger.removeHandler(handler)
-    assert len(res.phases) >= 3 and len(probe.marks) >= 3
-    fresh_after_phase1 = len(compiles) - probe.marks[2]
-    assert fresh_after_phase1 == 0, compiles[probe.marks[2]:][:4]
+    monkeypatch.setattr(drv, "device_coarsen_slab", spy)
+    before = orig._cache_size()
+    res = drv.louvain_phases(rmat10, engine="sort")
+    assert len(res.phases) >= 3 and len(calls) >= 3
+    assert len(set(calls)) == 1
+    assert orig._cache_size() - before <= 1
 
 
-def test_dense_coalesce_adds_no_device_syncs(rmat10, monkeypatch):
-    """One sync per phase stays one sync per phase: forcing the dense
-    coalesce must not change the run's jax.device_get call count."""
+def test_device_transition_adds_no_device_syncs(rmat10, monkeypatch):
+    """One sync per phase stays one sync per phase: the device
+    transition makes as many jax.device_get calls as the host transition
+    (CUVITE_DEVICE_COARSEN=0), and clusters identically."""
     from cuvite_tpu.louvain.driver import louvain_phases
 
     def run_counting():
@@ -272,37 +230,30 @@ def test_dense_coalesce_adds_no_device_syncs(rmat10, monkeypatch):
             monkeypatch.setattr(jax, "device_get", orig)
         return len(calls), res
 
-    monkeypatch.delenv("CUVITE_SEG_COALESCE", raising=False)
+    monkeypatch.delenv("CUVITE_DEVICE_COARSEN", raising=False)
     n0, r0 = run_counting()
-    monkeypatch.setenv("CUVITE_SEG_COALESCE", "xla")
+    monkeypatch.setenv("CUVITE_DEVICE_COARSEN", "0")
     n1, r1 = run_counting()
     assert np.array_equal(r0.communities, r1.communities)
-    assert n0 == n1
+    assert n0 == n1 >= len(r0.phases)
 
 
-def test_coalesce_stage_and_coverage_counters(rmat10, monkeypatch):
-    """coalesce_s splits out of coarsen_s (schema v4) and the coverage
-    counters say which engine ran: 0 dense edges by default, all of
-    them with the dense engine forced."""
+def test_coalesce_stage_and_edge_counter(rmat10):
+    """coalesce_s splits out of coarsen_s (schema v4), and the
+    coalesce_edges counter sums the edges of every coarsened phase."""
     from cuvite_tpu.louvain.driver import louvain_phases
     from cuvite_tpu.utils.trace import Tracer
 
-    monkeypatch.delenv("CUVITE_SEG_COALESCE", raising=False)
     tr = Tracer()
-    louvain_phases(rmat10, engine="sort", tracer=tr)
+    res = louvain_phases(rmat10, engine="sort", tracer=tr)
     bd = tr.breakdown()
     assert "coalesce_s" in bd and 0 < bd["coalesce_s"] <= bd["coarsen_s"]
-    assert tr.counters.get("coalesce_edges", 0) > 0
-    assert tr.counters.get("coalesce_dense_edges", 0) == 0
-    tr2 = Tracer()
-    monkeypatch.setenv("CUVITE_SEG_COALESCE", "xla")
-    louvain_phases(rmat10, engine="sort", tracer=tr2)
-    assert tr2.counters["coalesce_dense_edges"] \
-        == tr2.counters["coalesce_edges"] > 0
+    assert tr.counters["coalesce_edges"] \
+        == sum(p.num_edges for p in res.phases) > 0
 
 
 # ---------------------------------------------------------------------------
-# Packed-sort key-width contract (ops/segment.py): the fallback
+# Packed-sort key-width contract (ops/segment.py): the coalesce
 # chokepoint's edges, pinned (ISSUE 8 satellite).
 
 
@@ -377,10 +328,10 @@ def test_packed_sort_bound_violation_callback(bad, monkeypatch):
 
 # ---------------------------------------------------------------------------
 # ISSUE 16: the boundary trio generalized from the bare sort to the
-# coalesce CHOKEPOINT (coalesced_runs engine='sort' rides the packed
-# sort at src_bound = nv_pad + 1, key_bound = nv_pad, so nv_pad = 2^15
-# is the widest int32 packing and 2^16 the first ineligible width),
-# plus the heavy-layout elems budget and the tier-6 raise-guards.
+# coalesce CHOKEPOINT (coalesced_runs rides the packed sort at
+# src_bound = nv_pad + 1, key_bound = nv_pad, so nv_pad = 2^15 is the
+# widest int32 packing and 2^16 the first ineligible width), plus the
+# tier-6 raise-guard.
 
 
 def _chokepoint_slab(nv_pad, ne_pad, seed):
@@ -402,7 +353,7 @@ def _chokepoint_slab(nv_pad, ne_pad, seed):
 def _coalesce_oracle(src, ckey, w, nv_pad):
     """Sorted-unique real (src, ckey) pairs with summed weights, in
     float64 (the dyadic inputs make every f32 partial sum exact, so the
-    engine must match BIT-for-bit after the cast)."""
+    coalesce must match BIT-for-bit after the cast)."""
     src, ckey, w = (np.asarray(x) for x in (src, ckey, w))
     real = src < nv_pad
     keys = src[real].astype(np.int64) * nv_pad + ckey[real]
@@ -434,7 +385,7 @@ def test_coalesce_chokepoint_widest_legal_31bit_packing():
     nv_pad, ne_pad = 1 << 15, 8192
     src, dst, w = _chokepoint_slab(nv_pad, ne_pad, seed=31)
     out = coalesced_runs(jnp.asarray(src), jnp.asarray(dst),
-                         jnp.asarray(w), nv_pad=nv_pad, engine="sort")
+                         jnp.asarray(w), nv_pad=nv_pad)
     _assert_coalesce_matches_oracle(out, src, dst, w, nv_pad)
 
 
@@ -444,7 +395,7 @@ def test_coalesce_chokepoint_first_ineligible_width():
     nv_pad, ne_pad = 1 << 16, 8192
     src, dst, w = _chokepoint_slab(nv_pad, ne_pad, seed=32)
     out = coalesced_runs(jnp.asarray(src), jnp.asarray(dst),
-                         jnp.asarray(w), nv_pad=nv_pad, engine="sort")
+                         jnp.asarray(w), nv_pad=nv_pad)
     _assert_coalesce_matches_oracle(out, src, dst, w, nv_pad)
 
 
@@ -456,13 +407,11 @@ def test_coalesce_chokepoint_forced_64_bit_identical():
     nv_pad, ne_pad = 1 << 16, 8192
     src, dst, w = _chokepoint_slab(nv_pad, ne_pad, seed=33)
     arrs = tuple(jnp.asarray(x) for x in (src, dst, w))
-    base = jax.device_get(coalesced_runs(*arrs, nv_pad=nv_pad,
-                                         engine="sort"))
+    base = jax.device_get(coalesced_runs(*arrs, nv_pad=nv_pad))
     prior = jax.config.jax_enable_x64
     try:
         jax.config.update("jax_enable_x64", True)
-        forced = jax.device_get(coalesced_runs(*arrs, nv_pad=nv_pad,
-                                               engine="sort"))
+        forced = jax.device_get(coalesced_runs(*arrs, nv_pad=nv_pad))
     finally:
         jax.config.update("jax_enable_x64", prior)
     for b, f, name in zip(base, forced, ("src", "ckey", "w", "n")):
@@ -474,8 +423,7 @@ def test_slab_ne_max_raise_guard():
     fails LOUD (the int32 run-id cumsums would wrap silently)."""
     def probe(ne):
         jax.eval_shape(
-            lambda s, c, w: coalesced_runs(s, c, w, nv_pad=1 << 12,
-                                           engine="sort"),
+            lambda s, c, w: coalesced_runs(s, c, w, nv_pad=1 << 12),
             jax.ShapeDtypeStruct((ne,), jnp.int32),
             jax.ShapeDtypeStruct((ne,), jnp.int32),
             jax.ShapeDtypeStruct((ne,), jnp.float32))
@@ -488,42 +436,3 @@ def test_slab_ne_max_raise_guard():
             seg.run_totals,
             jax.ShapeDtypeStruct((seg.SLAB_NE_MAX * 2,), jnp.float32),
             jax.ShapeDtypeStruct((seg.SLAB_NE_MAX * 2,), jnp.bool_))
-
-
-def test_flat_nv_max_raise_guard():
-    """seg_coalesce_xla's flat (src << kbits) | dst key: FLAT_NV_MAX
-    traces, one doubling past raises (the key would wrap int32)."""
-    from cuvite_tpu.kernels.seg_coalesce import (FLAT_NV_MAX,
-                                                 seg_coalesce_xla)
-
-    def probe(nv):
-        jax.eval_shape(
-            lambda s, d, w: seg_coalesce_xla(s, d, w, nv_pad=nv),
-            jax.ShapeDtypeStruct((4096,), jnp.int32),
-            jax.ShapeDtypeStruct((4096,), jnp.int32),
-            jax.ShapeDtypeStruct((4096,), jnp.float32))
-
-    probe(FLAT_NV_MAX)
-    with pytest.raises(ValueError, match="FLAT_NV_MAX"):
-        probe(FLAT_NV_MAX * 2)
-
-
-def test_heavy_layout_elems_budget_boundary():
-    """build_heavy_layout's eligibility boundary: a layout landing
-    exactly ON max_elems is returned; one element past degrades to None
-    (the caller keeps the sorted path, with coverage accounting)."""
-    from cuvite_tpu.kernels.heavy_bincount import build_heavy_layout
-
-    nv_local = 16
-    src = np.repeat(np.arange(8, dtype=np.int32), 8)   # 8 hubs, deg 8
-    dst = np.tile(np.arange(8, dtype=np.int32), 8)
-    w = np.ones(64, np.float32)
-    # H = 8 -> Hp = 8; counts.max() = 8, d_chunk = 8 -> D = 8: 64 elems.
-    at = build_heavy_layout(src, dst, w, nv_local=nv_local,
-                            pad_id=nv_local, d_chunk=8, max_elems=64)
-    assert at is not None
-    verts, dstT, wT = at
-    assert verts.shape == (8,) and dstT.shape == (8, 8)
-    past = build_heavy_layout(src, dst, w, nv_local=nv_local,
-                              pad_id=nv_local, d_chunk=8, max_elems=63)
-    assert past is None

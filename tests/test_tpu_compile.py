@@ -1,12 +1,12 @@
-"""Ahead-of-time compiles of the Pallas kernels for a described TPU v5e.
+"""Ahead-of-time compiles for a described TPU v5e.
 
 Interpret mode cannot show what the chip's compiler refuses (block
 shapes off the (8, 128) tiling, a dynamic_slice of a loaded value,
-scoped-VMEM overflow).  These tests compile each kernel of the main path
-at real widths for a ``v5e:2x2`` topology that is described, not
-attached, and check that the kernel is really in the program
-(``tpu_custom_call``).  Nothing runs, so they say nothing about results
-or times.
+scoped-VMEM overflow).  These tests compile the Pallas row kernel at
+real widths, and the bucketed step with a heavy residual at hub width,
+for a ``v5e:2x2`` topology that is described, not attached; a kernel
+must really be in its program (``tpu_custom_call``).  Nothing runs, so
+they say nothing about results or times.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and each xdist
@@ -19,7 +19,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from cuvite_tpu.kernels.heavy_bincount import heavy_argmax_pallas
 from cuvite_tpu.kernels.row_argmax import row_argmax_pallas
 
 SENTINEL = 2**31 - 1
@@ -55,14 +54,24 @@ def _assert_kernel(compiled):
 
 
 def test_heavy_argmax_compiles_at_hub_width(one_chip):
-    """128 hubs of D = 16384 neighbor slots against a 2^20 community
-    range: lane-aligned [d_chunk, 128] tiles, refs read by pl.ds."""
-    D, H, nv_ceil = 16384, 128, 1 << 20
+    """The bucketed step whose heavy residual holds 8 hubs of 16384
+    neighbor slots (2^17 edges) over 2^15 vertices: the sorted heavy
+    path (one packed-key sort of the residual, run sums, segment
+    argmax) compiles for the chip."""
+    import functools
+
+    from cuvite_tpu.louvain.bucketed import bucketed_step
+
+    nv, ne_h = 1 << 15, 8 * 16384
     f32, i32 = jnp.float32, jnp.int32
-    args = ([_spec(one_chip, (D, H), i32), _spec(one_chip, (D, H), f32),
-             _spec(one_chip, (nv_ceil,), f32), _spec(one_chip, (H,), i32)]
-            + [_spec(one_chip, (H,), f32)] * 3 + [_spec(one_chip, (), f32)])
-    _assert_kernel(heavy_argmax_pallas.lower(*args).compile())
+    heavy = (_spec(one_chip, (ne_h,), i32), _spec(one_chip, (ne_h,), i32),
+             _spec(one_chip, (ne_h,), f32))
+    step = jax.jit(functools.partial(bucketed_step, nv_total=nv,
+                                     sentinel=SENTINEL))
+    compiled = step.lower(
+        (), heavy, _spec(one_chip, (nv,), f32), _spec(one_chip, (nv,), i32),
+        _spec(one_chip, (nv,), f32), _spec(one_chip, (), f32)).compile()
+    assert "sort" in compiled.as_text()
 
 
 @pytest.mark.parametrize("with_size", [False, True],

@@ -207,7 +207,6 @@ def test_w002_boundary_probes_green_under_code_laws():
     assert any(nk == 2 for nk, _dt, _nd in facts["sort_one_past"])
     assert (1, "int64", 1) in facts["sort_forced_64"]
     assert facts["slab_one_past"] == "raised"
-    assert facts["flat_one_past"] == "raised"
     assert facts["accum"] == {"below": "float32", "at": "ds32",
                               "by_addends": "ds32"}
 
@@ -331,10 +330,9 @@ def test_width_audit_cli_inventory_subprocess():
     assert out.returncode == 0, out.stderr
     inv = json.loads(out.stdout)
     assert all(e["reason"] for e in inv)
-    # The two deliberate 32-bit sites of this tree are in the closed
-    # inventory: the dense flat-key domain and the per-vertex n_moved.
+    # The deliberate 32-bit site of this tree is in the closed
+    # inventory: the per-vertex n_moved.
     rels = {e["rel"] for e in inv}
-    assert "cuvite_tpu/kernels/seg_coalesce.py" in rels
     assert "cuvite_tpu/louvain/step.py" in rels
 
 

@@ -459,8 +459,7 @@ def test_validate_record_rejects_unchecked_nonzero_compiles():
                stages={"coarsen_s": -1.0, "upload_s": 0.0,
                        "iterate_s": 1.0})
     assert any("coarsen_s" in p for p in validate_record(bad))
-    # ISSUE 8: coalesce_s is a required stage key; the optional
-    # coalesce_kernel coverage must be a fraction when present.
+    # ISSUE 8: coalesce_s is a required stage key.
     noco = dict(rec, compile_guard={"checked": True, "new_compiles": 0},
                 stages={"coarsen_s": 0.0, "upload_s": 0.0,
                         "iterate_s": 1.0})
@@ -479,9 +478,6 @@ def test_validate_record_rejects_unchecked_nonzero_compiles():
     assert validate_record(pal_ok) == []
     pal_bad = dict(pal_ok, pallas_coverage=1.7)
     assert any("pallas_coverage" in p for p in validate_record(pal_bad))
-    ck_bad = dict(ok, coalesce_kernel=2.0)
-    assert any("coalesce_kernel" in p for p in validate_record(ck_bad))
-    assert validate_record(dict(ok, coalesce_kernel=0.0)) == []
     # Schema v4: the telemetry fields are REQUIRED and type-checked; a
     # pre-v4 record (no schema field) is rejected outright.
     v3 = dict(ok)

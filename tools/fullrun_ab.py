@@ -45,12 +45,7 @@ def main():
           f"TEPS={v/1e6:.2f}M", flush=True)
     bd = tr.breakdown()
     stages = " ".join(f"{k}={bd[k]:.2f}" for k in sorted(bd))
-    co_tot = tr.counters.get("coalesce_edges", 0)
-    co_dense = tr.counters.get("coalesce_dense_edges", 0)
     print(f"# stages: {stages}", flush=True)
-    if co_tot:
-        print(f"# coalesce_kernel={co_dense / co_tot:.4f} "
-              f"({co_dense:g}/{co_tot:g} edges dense)", flush=True)
     for p in res.phases:
         print(f"#   phase ne={p.num_edges} it={p.iterations} "
               f"t={p.seconds:.2f}s", flush=True)

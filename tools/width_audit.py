@@ -11,9 +11,8 @@ spy pins the invariant), and grades:
   * W002 — every eligibility predicate actually selecting its
     fallback at the boundary: the packed int32 sort at
     kbits+sbits == 31 vs the lexicographic comparator one past (and
-    the int64 pack under forced x64), coalesce_engine's nv ceiling
-    and ds32 degrade, the SLAB_NE_MAX / FLAT_NV_MAX raise-guards, the
-    DS_MIN_TOTAL_WEIGHT ds32 cutover;
+    the int64 pack under forced x64), the SLAB_NE_MAX raise-guard,
+    the DS_MIN_TOTAL_WEIGHT ds32 cutover;
   * W003 — audit integrity: crashed entries, a budget manifest that
     drifted from the code constants or the registry's declared max
     workload, or a nonzero live-buffer delta all fail CLOSED.
