@@ -1622,7 +1622,8 @@ def _run_fused(graph, *, threshold, threshold_cycling, one_phase, balanced,
                 dgq, np.asarray(labels_d),  # graftlint: disable=R010 — final labels, O(V), re-used on device by the ds pass
                 device_slab=(src_d, dst_d, w_d))
         else:
-            final_q = phase_modularity(dg, np.asarray(labels_d))  # graftlint: disable=R010 — host-compaction fallback path
+            final_q = phase_modularity(dg, np.asarray(labels_d),  # graftlint: disable=R010 — host-compaction fallback path
+                                       tracer=tracer)
     else:
         final_q = -1.0
     return LouvainResult(
@@ -2188,7 +2189,8 @@ def louvain_phases(
                 curr_mod = dg.modularity(comm_pad)
             else:
                 curr_mod = phase_modularity(
-                    dg, comm_pad, device_slab=_runner_slab(runner))
+                    dg, comm_pad, device_slab=_runner_slab(runner),
+                    tracer=tracer)
         t2 = time.perf_counter()
         tot_iters += iters
         tracer.count("traversed_edges", g_ne * iters)
@@ -2337,7 +2339,8 @@ def louvain_phases(
                         curr_mod = dg.modularity(comm_pad)
                     else:
                         curr_mod = phase_modularity(
-                            dg, comm_pad, device_slab=_runner_slab(runner))
+                            dg, comm_pad, device_slab=_runner_slab(runner),
+                            tracer=tracer)
                 tot_iters += iters
                 comm_old = comm_pad[dg.old_to_pad]
                 final_gained = (curr_mod - prev_mod) > 1.0e-6

@@ -16,8 +16,13 @@ Two execution paths, chosen by where the edge slab already lives:
   transients are O(E); callers must not upload a second slab copy just for
   this (the bucketed engine deliberately keeps no slab on device).
 - host (default): the phase-end assignment is already host-side, so the
-  f64 numpy oracle (evaluate/modularity.py) computes the identical value
-  with zero device memory — O(E) host work once per phase.
+  value is computed there in f64 with zero device memory — O(E) host work
+  once per phase, while the device waits.  At ``native.MIN_NATIVE_EDGES``
+  edges and above, one fused native pass over the CSR sums the terms
+  (``native.modularity_terms``) and Q is finished with the oracle's own
+  expression: bit-identical to the numpy oracle (evaluate/modularity.py)
+  for integer-weight graphs, equal to f64 rounding otherwise.  Below it,
+  or without the library, the numpy oracle itself runs.
 """
 
 from __future__ import annotations
@@ -28,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from cuvite_tpu import native
 from cuvite_tpu.ops import exactsum as ds
 
 
@@ -65,13 +71,16 @@ def _precise_mod_device(src, dst, w, comm, c_hi, c_lo, *, nv_pad):
     return q[0], q[1]
 
 
-def phase_modularity(dg, comm_pad: np.ndarray, device_slab=None) -> float:
+def phase_modularity(dg, comm_pad: np.ndarray, device_slab=None,
+                     tracer=None) -> float:
     """Precise modularity of ``comm_pad`` (padded-space labels) for the
     DistGraph's underlying graph, as a python float with f64-class accuracy.
 
     ``device_slab``: optional (src, dst, w) jax arrays ALREADY resident on
     device (single-shard layout) — the ds pass then runs on device with no
-    O(E) upload.  Without it the host f64 oracle is used.
+    O(E) upload.  Without it the host f64 pass is used; ``tracer`` then
+    counts the edges the fused native pass summed
+    (``evaluate_native_edges``).
     """
     g = dg.graph
     if device_slab is not None and dg.nshards == 1:
@@ -84,8 +93,19 @@ def phase_modularity(dg, comm_pad: np.ndarray, device_slab=None) -> float:
             nv_pad=dg.nv_pad,
         )
         return ds.ds_to_f64(q)
-    # Assignment is on host at phase end; f64 numpy oracle.
+    # Assignment is on host at phase end.  One shard's old_to_pad is the
+    # identity, so its labels are a view of the padded ones.
+    comm_pad = np.asarray(comm_pad)
+    comm_old = (comm_pad[:g.num_vertices] if dg.nshards == 1
+                else comm_pad[dg.old_to_pad])
+    if g.num_edges >= native.MIN_NATIVE_EDGES and native.available():
+        nc = int(comm_old.max()) + 1
+        e_in, two_m, a_c = native.modularity_terms(
+            g.offsets, g.tails, g.weights, comm_old, nc)
+        if tracer is not None:
+            tracer.count("evaluate_native_edges", g.num_edges)
+        # The oracle's own expression over the same (exact) terms.
+        return float(e_in / two_m - np.square(a_c / two_m).sum())
     from cuvite_tpu.evaluate.modularity import modularity
 
-    comm_old = np.asarray(comm_pad)[dg.old_to_pad]
     return modularity(g, comm_old)

@@ -3,9 +3,11 @@
 The native library accelerates the host-side data layer — CSR construction,
 R-MAT generation, Vite binary I/O — the role the reference fills with its
 C++/MPI loader and generator (/root/reference/distgraph.cpp).  Every entry
-point has a bit-identical pure-numpy fallback in the rest of the package, so
-the library is an accelerator, never a requirement: ``available()`` gates
-every use.
+point has a pure-numpy fallback in the rest of the package, so the library
+is an accelerator, never a requirement: ``available()`` gates every use.
+The fallbacks are bit-identical, except for the per-phase modularity terms
+(``modularity_terms``), which sum in another order than the numpy oracle:
+bit-identical for integer-weight graphs, equal to f64 rounding otherwise.
 
 Build: implicitly on first use, from the committed source
 ``native/cuvite_native.cpp`` (disable with CUVITE_NO_NATIVE=1).  The
@@ -30,7 +32,7 @@ _LIB = None  # None = not tried; False = unavailable; else CDLL
 
 # Minimum element count for routing through the native library; below this
 # the ctypes/copy overhead outweighs the win.  Shared by every dispatch
-# site (Graph.from_edges, read/write_vite).
+# site (Graph.from_edges, read/write_vite, phase_modularity).
 MIN_NATIVE_EDGES = 1 << 16
 
 
@@ -128,6 +130,10 @@ def _bind(lib: ctypes.CDLL) -> None:
                                ctypes.c_int]
     lib.cv_weighted_degrees.restype = None
     lib.cv_weighted_degrees.argtypes = [i64, p_i64, vp, ctypes.c_int, p_f64]
+    lib.cv_phase_modularity.restype = ctypes.c_int
+    lib.cv_phase_modularity.argtypes = [i64, i64, p_i64, vp, vp, vp,
+                                        ctypes.c_int, ctypes.c_int,
+                                        ctypes.c_int, p_f64, p_f64]
 
 
 def _load():
@@ -420,6 +426,42 @@ def weighted_degrees(offsets: np.ndarray, weights: np.ndarray) -> np.ndarray:
     lib.cv_weighted_degrees(len(offsets) - 1, offsets, _vp(weights),
                             int(weights.dtype == np.float64), out)
     return out
+
+
+def modularity_terms(offsets: np.ndarray, tails: np.ndarray,
+                     weights: np.ndarray, comm: np.ndarray, nc: int):
+    """One pass over the CSR: ``(e_in, two_m, a_c[nc])`` in f64, the sums
+    evaluate/modularity.py::modularity builds from its O(E) temporaries
+    (see cv_phase_modularity).  ``comm`` labels each row in [0, nc).
+    int32/int64 ids and labels and f32/f64 weights are read in place."""
+    lib = _load()
+    assert lib is not None
+    offsets = np.ascontiguousarray(offsets, dtype=np.int64)
+    nv = len(offsets) - 1
+    tails = np.ascontiguousarray(tails)
+    if tails.dtype not in (np.int32, np.int64):
+        tails = tails.astype(np.int64)
+    weights = np.ascontiguousarray(weights)
+    if weights.dtype not in (np.float32, np.float64):
+        weights = weights.astype(np.float64)
+    comm = np.ascontiguousarray(comm)
+    if comm.dtype not in (np.int32, np.int64):
+        comm = comm.astype(np.int64)
+    if len(comm) != nv or len(tails) != len(weights) \
+            or int(offsets[-1]) != len(tails):
+        raise ValueError(
+            f"modularity_terms: {len(comm)} labels, {len(tails)} tails and "
+            f"{len(weights)} weights do not fit a CSR of {nv} rows and "
+            f"{int(offsets[-1])} slots")
+    terms = np.empty(2, dtype=np.float64)
+    a_c = np.empty(nc, dtype=np.float64)
+    rc = lib.cv_phase_modularity(
+        nv, nc, offsets, _vp(tails), _vp(weights), _vp(comm),
+        int(tails.dtype == np.int64), int(weights.dtype == np.float64),
+        int(comm.dtype == np.int64), terms, a_c)
+    if rc != 0:
+        raise ValueError(f"modularity_terms: a label lies outside [0, {nc})")
+    return terms[0], terms[1], a_c
 
 
 def plan_scan(src, dst, w, nv: int, base: int):

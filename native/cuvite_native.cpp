@@ -11,7 +11,11 @@
 // Design constraints:
 //  * bit-deterministic: every routine produces output identical to the
 //    pure-numpy fallback in cuvite_tpu (tested in tests/test_native.py),
-//    so a run is reproducible with or without the native library.
+//    so a run is reproducible with or without the native library.  The
+//    one exception is cv_phase_modularity, which sums in another order
+//    than the numpy oracle: bit-identical for integer-weight graphs
+//    (every sum exact), equal to f64 rounding otherwise, and the same
+//    bits for any thread count either way (tests/test_precise.py).
 //  * OpenMP where it pays (per-row sorts, deinterleaving); serial where
 //    determinism of float accumulation order matters.
 //  * C ABI only — bound from Python via ctypes, no pybind11.
@@ -566,6 +570,85 @@ extern "C" void cv_weighted_degrees(int64_t nv, const int64_t* offsets,
     }
     out[v] = a;
   }
+}
+
+// Phase modularity terms in one pass over the CSR: the f64 sums of
+// evaluate/modularity.py::modularity without its O(E) temporaries (the
+// expanded sources, two label gathers, an f64 weight copy, a compress).
+//   e_in   = sum of w over slots whose endpoints share a label;
+//   two_m  = sum of w;
+//   a_c[c] = sum of the weighted degrees of the vertices labelled c.
+// Rows are cut into MOD_BLOCKS blocks of about equal edge mass, a number
+// fixed here and not by the team size: each block sums its rows in CSR
+// order, and the block partials are added in block order, so the result
+// is the same bits for any OpenMP thread count (the radix_sort_pairs
+// convention).  a_c is scattered serially, in vertex order, from the
+// per-vertex degrees.  With integer weights every sum is exact, so the
+// terms equal the oracle's bit for bit; otherwise they agree to f64
+// rounding.  Returns -1 when a label lies outside [0, nc).
+constexpr int MOD_BLOCKS = 256;
+
+template <typename I, typename W, typename L>
+static int phase_modularity_impl(int64_t nv, int64_t nc,
+                                 const int64_t* offsets, const I* tails,
+                                 const W* w, const L* comm, double* terms,
+                                 double* a_c) {
+  for (int64_t v = 0; v < nv; ++v)
+    if (comm[v] < 0 || (int64_t)comm[v] >= nc) return -1;
+  const int64_t ne = offsets[nv];
+  // Block b covers rows [row0[b], row0[b + 1]): the first row whose edges
+  // start at or past b/MOD_BLOCKS of the slab.
+  std::vector<int64_t> row0(MOD_BLOCKS + 1);
+  for (int b = 0; b <= MOD_BLOCKS; ++b) {
+    const int64_t target = ne * b / MOD_BLOCKS;
+    row0[b] = std::lower_bound(offsets, offsets + nv, target) - offsets;
+  }
+  row0[MOD_BLOCKS] = nv;
+  std::vector<double> deg(nv), ein(MOD_BLOCKS), wsum(MOD_BLOCKS);
+#pragma omp parallel for schedule(dynamic, 1)
+  for (int b = 0; b < MOD_BLOCKS; ++b) {
+    double e = 0.0, s = 0.0;
+    for (int64_t u = row0[b]; u < row0[b + 1]; ++u) {
+      const L cu = comm[u];
+      double d = 0.0;
+      for (int64_t k = offsets[u]; k < offsets[u + 1]; ++k) {
+        const double wk = (double)w[k];
+        d += wk;
+        e += comm[tails[k]] == cu ? wk : 0.0;
+      }
+      deg[u] = d;
+      s += d;
+    }
+    ein[b] = e;
+    wsum[b] = s;
+  }
+  double e_in = 0.0, two_m = 0.0;
+  for (int b = 0; b < MOD_BLOCKS; ++b) {
+    e_in += ein[b];
+    two_m += wsum[b];
+  }
+  std::fill(a_c, a_c + nc, 0.0);
+  for (int64_t v = 0; v < nv; ++v) a_c[comm[v]] += deg[v];
+  terms[0] = e_in;
+  terms[1] = two_m;
+  return 0;
+}
+
+extern "C" int cv_phase_modularity(int64_t nv, int64_t nc,
+                                   const int64_t* offsets, const void* tails,
+                                   const void* w, const void* comm, int id64,
+                                   int w64, int c64, double* terms,
+                                   double* a_c) {
+#define CV_MOD(I_, W_)                                                     \
+  (c64 ? phase_modularity_impl(nv, nc, offsets, (const I_*)tails,          \
+                               (const W_*)w, (const int64_t*)comm, terms, \
+                               a_c)                                        \
+       : phase_modularity_impl(nv, nc, offsets, (const I_*)tails,          \
+                               (const W_*)w, (const int32_t*)comm, terms, \
+                               a_c))
+  if (id64) return w64 ? CV_MOD(int64_t, double) : CV_MOD(int64_t, float);
+  return w64 ? CV_MOD(int32_t, double) : CV_MOD(int32_t, float);
+#undef CV_MOD
 }
 
 extern "C" {

@@ -3,9 +3,12 @@
 import numpy as np
 import pytest
 
+from cuvite_tpu import native
 from cuvite_tpu.core.graph import Graph
 from cuvite_tpu.evaluate.modularity import modularity as modularity_oracle
+from cuvite_tpu.io.generate import generate_rmat
 from cuvite_tpu.louvain.driver import louvain_phases, threshold_for_phase
+from cuvite_tpu.utils.trace import Tracer
 
 
 def test_threshold_schedule():
@@ -143,3 +146,36 @@ def test_sort_coloring_opt_out_keeps_legacy_schedule(karate, monkeypatch):
         res = louvain_phases(karate, engine="sort", coloring=4)
     q = modularity_oracle(karate, res.communities)
     assert q >= 0.38
+
+
+@pytest.mark.skipif(not native.available(),
+                    reason="native library unavailable")
+def test_evaluate_native_edges_counts_the_fused_attempts(monkeypatch):
+    """A bucketed run above the native threshold: the counter sums the
+    edges of every phase attempt evaluated by the fused pass, and the
+    labels and reported Q equal a run without the native library."""
+
+    class AttemptTracer(Tracer):
+        def __init__(self):
+            super().__init__()
+            self.attempt_edges = []
+
+        def begin_span(self, name, **attrs):
+            if name == "phase":
+                self.attempt_edges.append(attrs["ne"])
+            return super().begin_span(name, **attrs)
+
+    g = generate_rmat(13, edge_factor=8, seed=3)
+    tr = AttemptTracer()
+    res = louvain_phases(g, engine="bucketed", tracer=tr)
+    assert tr.attempt_edges[0] == g.num_edges >= native.MIN_NATIVE_EDGES
+    want = sum(ne for ne in tr.attempt_edges if ne >= native.MIN_NATIVE_EDGES)
+    assert tr.counters["evaluate_native_edges"] == want
+
+    monkeypatch.setenv("CUVITE_NO_NATIVE", "1")
+    monkeypatch.setattr(native, "_LIB", None)
+    tr0 = Tracer()
+    res0 = louvain_phases(g, engine="bucketed", tracer=tr0)
+    assert "evaluate_native_edges" not in tr0.counters
+    assert float(res.modularity).hex() == float(res0.modularity).hex()
+    np.testing.assert_array_equal(res.communities, res0.communities)
